@@ -101,6 +101,8 @@ def bench_backbone(scale=0.04, size="small", hidden=128, k=16,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         # CI-sized: tiny graph, but the REAL depth/width points of the
         # acceptance bar (depth 15, hidden 128) with the memory + parity

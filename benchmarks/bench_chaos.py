@@ -158,6 +158,8 @@ def bench_chaos(n_per_class: int = 8, max_batch: int = 4, hidden: int = 64,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         # CI-sized run: tiny classes, small stream
         r = bench_chaos(n_per_class=4, max_batch=2, hidden=32,

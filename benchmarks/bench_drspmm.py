@@ -69,8 +69,13 @@ def _count_pallas(jaxpr) -> int:
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             n += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _count_pallas(sub)
+        elif eqn.primitive.name == "cond":
+            # one branch runs — e.g. the native/interpreted pair of
+            # kernels.drspmm.run_pallas, of which the lowering keeps one
+            n += max(_count_pallas(b.jaxpr) for b in eqn.params["branches"])
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += _count_pallas(sub)
     return n
 
 
@@ -358,42 +363,29 @@ def bench_sharded(scale=0.08, size="medium", dim=64, k=16,
     """Mesh-sharded mega-dispatch (DESIGN.md §12) vs the single-device plan
     path, per shard count.
 
-    XLA's device count locks at the first jax import, so every shard count
-    runs in a child interpreter with
-    ``--xla_force_host_platform_device_count=n`` (the tests/_multidev.py
-    pattern); the child prints one ``SHARDED_RESULT`` JSON line this parent
-    collects.  Wall-clock follows the repo convention — the xla family on
-    CPU (Pallas interpret-mode is not wall-clock-representative, see
-    ``bench()``); each leg additionally records the per-device arena
-    footprint (owned slabs + halo tables) against full-graph replication.
-    ``smoke=True`` makes the child assert numeric parity with
-    ``drspmm_multi`` AND that every shard's footprint stays strictly below
-    replicating the whole super-arena — the reason sharding exists.
+    On an accelerator every shard count runs in this process over
+    ``jax.devices()[:n]`` with the default backend (one process holds the
+    chips; a child could not get them).  On CPU, XLA's device count locks at
+    the first jax import, so each shard count runs in a child interpreter
+    with ``--xla_force_host_platform_device_count=n`` (the tests/_multidev.py
+    pattern) on the xla family — Pallas interpret-mode is not
+    wall-clock-representative — and prints one ``SHARDED_RESULT`` JSON line
+    this parent collects.  Each leg records the per-device arena footprint
+    (owned slabs + halo tables) against full-graph replication.
+    ``smoke=True`` asserts numeric parity with ``drspmm_multi`` AND that
+    every shard's footprint stays strictly below replicating the whole
+    super-arena — the reason sharding exists.
     """
-    import json
-    import os
-    import subprocess
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(root, "src")
+    on_chip = jax.default_backend() != "cpu"
     entries = []
     for n in device_counts:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                                   if env.get("PYTHONPATH") else "")
-        flags = [t for t in env.get("XLA_FLAGS", "").split()
-                 if not t.startswith("--xla_force_host_platform_device_count")]
-        env["XLA_FLAGS"] = " ".join(
-            flags + [f"--xla_force_host_platform_device_count={n}"])
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_drspmm",
-             "--_sharded-child", str(n), str(scale), size, str(dim),
-             str(k), str(iters), str(int(smoke))],
-            env=env, cwd=root, capture_output=True, text=True, timeout=1200)
-        assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-4000:])
-        line = [ln for ln in r.stdout.splitlines()
-                if ln.startswith("SHARDED_RESULT ")][-1]
-        res = json.loads(line[len("SHARDED_RESULT "):])
+        if on_chip:
+            if n > jax.device_count():
+                continue
+            res = _sharded_leg(n, scale, size, dim, k, iters, smoke,
+                               ops.DEFAULT_BACKEND)
+        else:
+            res = _sharded_child(n, scale, size, dim, k, iters, smoke)
         emit(f"sharded_fwd/{size}/n{n}/d{dim}/k{k}", res["sharded_fwd_us"],
              f"vs_single={res['single_fwd_us'] / res['sharded_fwd_us']:.2f}x;"
              f"shard_bytes={res['max_shard_bytes']}"
@@ -408,14 +400,39 @@ def bench_sharded(scale=0.08, size="medium", dim=64, k=16,
     return entries
 
 
-def _bench_sharded_child(n, scale, size, dim, k, iters, smoke):
-    """Child half of :func:`bench_sharded` — runs under a forced n-device
-    XLA runtime and prints one ``SHARDED_RESULT`` JSON line."""
+def _sharded_child(n, scale, size, dim, k, iters, smoke) -> dict:
+    """Run one CPU leg under a forced n-device XLA runtime in a child."""
     import json
+    import os
+    import subprocess
 
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src = os.path.join(root, "src")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
+                               if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = [t for t in env.get("XLA_FLAGS", "").split()
+             if not t.startswith("--xla_force_host_platform_device_count")]
+    env["XLA_FLAGS"] = " ".join(
+        flags + [f"--xla_force_host_platform_device_count={n}"])
+    r = subprocess.run(
+        [sys.executable, "-m", "benchmarks.bench_drspmm",
+         "--_sharded-child", str(n), str(scale), size, str(dim),
+         str(k), str(iters), str(int(smoke))],
+        env=env, cwd=root, capture_output=True, text=True, timeout=1200)
+    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-4000:])
+    line = [ln for ln in r.stdout.splitlines()
+            if ln.startswith("SHARDED_RESULT ")][-1]
+    return json.loads(line[len("SHARDED_RESULT "):])
+
+
+def _sharded_leg(n, scale, size, dim, k, iters, smoke, backend) -> dict:
+    """One shard count: sharded vs single-device timings, footprint and
+    (``smoke``) parity, over the first ``n`` devices."""
     from repro.graphs.circuit import relation_plan_of, sharded_plan_of
 
-    assert jax.device_count() == n, (jax.device_count(), n)
+    assert jax.device_count() >= n, (jax.device_count(), n)
     rng = np.random.default_rng(0)
     g = generate_design(1, size, scale=scale)[0]
     plan = relation_plan_of(g)
@@ -427,7 +444,7 @@ def _bench_sharded_child(n, scale, size, dim, k, iters, smoke):
 
     def call(op, p, vc, vn):
         return op(p, {"cell": (vc, cc.idx), "net": (vn, cn.idx)}, dim,
-                  backend="xla_fused")
+                  backend=backend)
 
     def grad_call(op, p):
         return lambda vc, vn: jax.grad(
@@ -455,20 +472,24 @@ def _bench_sharded_child(n, scale, size, dim, k, iters, smoke):
                                        atol=atol, rtol=1e-5,
                                        err_msg=f"sharded parity {et}")
         assert hs["max_shard_bytes"] < hs["full_arena_bytes"], hs
-    print("SHARDED_RESULT " + json.dumps(dict(
-        n_shards=n, n_cell=g.n_cell, n_net=g.n_net,
-        max_shard_bytes=hs["max_shard_bytes"],
-        full_arena_bytes=hs["full_arena_bytes"],
-        total_halo_rows=hs["total_halo_rows"], halo_pad=hs["halo_pad"],
-        **stats)))
+    return dict(n_shards=n, n_cell=g.n_cell, n_net=g.n_net,
+                backend=backend, platform=jax.devices()[0].platform,
+                max_shard_bytes=hs["max_shard_bytes"],
+                full_arena_bytes=hs["full_arena_bytes"],
+                total_halo_rows=hs["total_halo_rows"], halo_pad=hs["halo_pad"],
+                **stats)
 
 
 if __name__ == "__main__":
     if "--_sharded-child" in sys.argv:
+        import json
         a = sys.argv[sys.argv.index("--_sharded-child") + 1:]
-        _bench_sharded_child(int(a[0]), float(a[1]), a[2], int(a[3]),
-                             int(a[4]), int(a[5]), bool(int(a[6])))
+        print("SHARDED_RESULT " + json.dumps(_sharded_leg(
+            int(a[0]), float(a[1]), a[2], int(a[3]), int(a[4]), int(a[5]),
+            bool(int(a[6])), "xla_fused")))
         sys.exit(0)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         # CI-sized run: tiny graph, fused-vs-bucketed + plan-vs-serial
         # comparisons (fixed-weight, learnable, and hetero-layer legs),

@@ -40,4 +40,6 @@ def bench():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bench()

@@ -77,4 +77,6 @@ def bench(scale=0.08):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bench()

@@ -60,4 +60,6 @@ def bench(scale=0.05, epochs=6):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bench()

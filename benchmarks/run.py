@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated benchmark names")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale = 0.04 if args.fast else 0.08
     epochs = 2 if args.fast else 6
 

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.graphs.ell import ROW_BLOCK
-from repro.kernels.drspmm import INTERPRET
+from repro.kernels.drspmm import run_pallas
 
 N_ITERS = 64
 
@@ -65,20 +65,18 @@ def _drelu_kernel(x_ref, out_ref, *, k: int):
 def drelu_pallas(x: jax.Array, k: int, *, block_rows: int = ROW_BLOCK,
                  interpret: bool | None = None) -> jax.Array:
     """Dense D-ReLU via the binary-search kernel.  x (N, D)."""
-    if interpret is None:
-        interpret = INTERPRET
     n, d = x.shape
     if k >= d:
         return x
     br = min(block_rows, n)
     pad = (-n) % br
     xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
-    out = pl.pallas_call(
+    out = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_drelu_kernel, k=k),
         grid=((n + pad) // br,),
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, d), x.dtype),
-        interpret=interpret,
-    )(xp)
+        interpret=interp,
+    )(xp), interpret)
     return out[:n] if pad else out

@@ -5,36 +5,45 @@ Backward (Alg. 2):  dV[j, t]  += w_ij * dY[i, X_idx[j, t]]           over i∈N(
 
 Layout / TPU mapping
 --------------------
-Two execution strategies share the same math:
+Every sparse executor here is ONE chunk-walk kernel over an arena of
+uniform (BR, Ec) neighbour chunks (DESIGN.md §1):
 
-* **Per-bucket** (reference): one ``pallas_call`` per degree bucket (see
-  graphs/ell.py): the grid walks row-blocks of that bucket's ELL slab; the
-  slab width E is the bucket's max degree, so short rows never pay evil-row
-  padding — the paper's dynamic warp partitioning expressed structurally.
-* **Fused** (default hot path, DESIGN.md §1): ALL buckets in ONE
-  ``pallas_call``.  The :class:`~repro.graphs.ell.FusedELL` arena stores
-  uniform (BR, Ec) neighbor chunks; the grid walks chunks, and a
-  scalar-prefetch metadata table routes each chunk's accumulation into its
-  output row-block (grouped-matmul revisit pattern — consecutive grid steps
-  hit the same output block, so the block stays VMEM-resident and no atomics
-  or host-side combines are needed).
+* **Fused** (default hot path): the :class:`~repro.graphs.ell.FusedELL`
+  arena holds ALL degree buckets; a scalar-prefetch metadata table routes
+  each chunk's accumulation into its output row-block (grouped-matmul
+  revisit pattern — consecutive grid steps hit the same output block, so it
+  stays VMEM-resident and no atomics or host-side combines are needed).
+* **Per-bucket** (reference): one launch per degree bucket, the bucket's
+  (R, E) ELL slab read as an arena of R/BR chunks of width E, one per
+  row-block — the paper's dynamic warp partitioning expressed structurally.
 
-Shared kernel-body idioms:
+Kernel-body idioms, all in a form Mosaic lowers (DESIGN.md §1.4):
 
-* Neighbors are processed in **E-chunks**: one ``(BR, Ec·k) × (BR, Ec·k, D)``
-  MXU contraction per chunk instead of a serial per-neighbor einsum.
-* The scatter of k CBSR values into a D-wide accumulator is computed as a
-  one-hot contraction ``vals · onehot(idx)`` so it maps onto the MXU instead
-  of a serial scatter (TPUs have no fast in-kernel scatter).
+* **Row gathers by DMA.**  The gathered operand (the CBSR slab, dY, or a
+  dense x) stays in HBM; each grid step reads its chunk's BR·Ec neighbour ids
+  from an SMEM block and issues one row DMA per id into a VMEM scratch, all
+  in flight at once, then drains them.  No VMEM block grows with the number
+  of source rows.  A DMA row must span whole 128-lane tiles, so operands are
+  lane-padded first; the CBSR operand travels as one int32 row table
+  (value bits | indices | zero padding), one DMA per neighbour.
+* **Scatter by compare-select.**  The k CBSR values of a gathered row are
+  densified into a (rows, D-tile) slice with one lane-iota compare-select per
+  kept position (TPUs have no fast in-kernel scatter).
+* **Neighbour sum on the MXU.**  A (BR, BR·Ec) selection matrix carrying the
+  chunk's edge weights contracts the gathered rows into the row-block.
+* **SSpMM sampling by compare-and-reduce.**  dV[j, t] = g[j, idx[j, t]] is a
+  lane-iota compare and a lane reduction per kept position.
 * **D-tiling**: when the embedding dim exceeds ``D_TILE`` (128, one MXU
-  lane-width) and divides evenly, the grid gains a D-tile dimension and each
-  step materializes only a (…, D_TILE) slice of the one-hot — ``hidden >
-  128`` no longer forces whole-array VMEM residency of the accumulator.
-* Accumulation is fp32 in VMEM regardless of input dtype.
+  lane-width) and divides evenly, the forward grid gains a D-tile dimension
+  and each step densifies only a (…, D_TILE) slice.
+* Accumulation is fp32 in VMEM regardless of input dtype; contractions run
+  at ``Precision.HIGHEST`` so the chip agrees with the f32 oracle.
 
-Validated with ``interpret=True`` on CPU against kernels/ref.py; on real TPU
-the same code lowers via Mosaic (jnp.take of rows lowers to dynamic gathers
-along the sublane dimension).
+Where the kernels run natively and where they are interpreted is decided
+when the enclosing program is lowered for its platform (:func:`run_pallas`),
+not by the host the code was traced on.  Validated with the interpreter on
+CPU against kernels/ref.py, and compiled for TPU v5e at full-scale shapes
+by tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -46,15 +55,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.graphs.ell import (ELLBucket, FusedELL, ROW_BLOCK, EDGE_CHUNK,
-                              _round_up)
+from repro.graphs.ell import ELLBucket, FusedELL, ROW_BLOCK, _round_up
 
-# CPU has no Mosaic backend: interpret the kernel bodies.  On TPU this flips
-# to False automatically and the kernels compile natively.
-INTERPRET = jax.default_backend() != "tpu"
-
-# One MXU lane-width: D-tiling granularity for wide embeddings.
+# One MXU lane-width: D-tiling granularity for wide embeddings, and the
+# lane-tile width a DMA row must span.
 D_TILE = 128
+LANES = 128
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def run_pallas(call, interpret: bool | None = None):
+    """``call(interpret)`` — a ``pallas_call`` closure — natively on TPU and
+    interpreted on every other platform.
+
+    The branch is chosen when the enclosing program is lowered
+    (``lax.platform_dependent``), from the platform it is lowered for: a jit
+    traced on a CPU host and compiled for a described TPU takes the native
+    branch, exactly like a run on the chip, and a TPU program never
+    interprets.  An explicit ``interpret`` pins the branch."""
+    if interpret is not None:
+        return call(interpret)
+    return jax.lax.platform_dependent(tpu=lambda: call(False),
+                                      default=lambda: call(True))
 
 
 def _d_tiling(dim: int) -> tuple:
@@ -64,227 +87,281 @@ def _d_tiling(dim: int) -> tuple:
     return dim, 1
 
 
-def _chunked_reduce(nbr, w, contrib, acc, chunk: int):
-    """acc + Σ_chunks contrib(nbr_chunk, w_chunk) with O(1) trace size.
+def _lane_pad(x: jax.Array) -> jax.Array:
+    """(N, W) → (N, W rounded up to whole lane tiles), zero-filled."""
+    w = x.shape[1]
+    wp = _round_up(max(w, 1), LANES)
+    return x if w == wp else jnp.pad(x, ((0, 0), (0, wp - w)))
 
-    Full chunks run under a fori_loop with dynamic slices (one traced body
-    regardless of slab width — evil-row buckets don't inflate the jaxpr);
-    the partial tail chunk, whose width is static, is added unrolled."""
-    e_width = nbr.shape[1]
-    n_full, rem = divmod(e_width, chunk)
-    if n_full:
-        def body(ci, a):
-            nb = jax.lax.dynamic_slice_in_dim(nbr, ci * chunk, chunk, axis=1)
-            wc = jax.lax.dynamic_slice_in_dim(w, ci * chunk, chunk, axis=1)
-            return a + contrib(nb, wc)
-        acc = jax.lax.fori_loop(0, n_full, body, acc)
-    if rem:
-        acc = acc + contrib(nbr[:, n_full * chunk:], w[:, n_full * chunk:])
-    return acc
+
+def _cbsr_rows(x_vals: jax.Array, x_idx: jax.Array) -> jax.Array:
+    """CBSR operand as one lane-padded int32 row table: f32 value bits in
+    lanes [0, k), column indices in [k, 2k) — one DMA fetches both."""
+    bits = jax.lax.bitcast_convert_type(x_vals.astype(jnp.float32), jnp.int32)
+    return _lane_pad(jnp.concatenate([bits, x_idx.astype(jnp.int32)], axis=1))
 
 
 # ---------------------------------------------------------------------------
-# per-bucket forward (reference path)
+# kernel-body helpers
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(nbr_ref, w_ref, xv_ref, xi_ref, out_ref, *, d_tile: int,
-                chunk: int):
-    """One row-block: aggregate E neighbors' CBSR rows into (BR, DT) output.
+def _gather_rows(src_hbm, ids_ref, rows_ref, sem):
+    """rows_ref[r] = src_hbm[ids_ref[0, 0, r]] for every r: one row DMA per
+    id, all started before the first wait (same-size copies on one
+    semaphore, so any descriptor of that size drains one)."""
+    def start(r, carry):
+        pltpu.make_async_copy(src_hbm.at[pl.ds(ids_ref[0, 0, r], 1)],
+                              rows_ref.at[pl.ds(r, 1)], sem).start()
+        return carry
 
-    The neighbor axis is walked in Ec-chunks; each chunk is one
-    (BR, Ec·k) × (BR, Ec·k, DT) one-hot contraction on the MXU.
-    """
-    nbr = nbr_ref[...]            # (BR, E) int32
-    w = w_ref[...]                # (BR, E)
-    xv = xv_ref[...]              # (N, k)
-    xi = xi_ref[...]              # (N, k) int32
-    br, e_width = nbr.shape
-    k = xv.shape[1]
+    def wait(r, carry):
+        pltpu.make_async_copy(src_hbm.at[pl.ds(0, 1)],
+                              rows_ref.at[pl.ds(0, 1)], sem).wait()
+        return carry
 
-    d_base = pl.program_id(1) * d_tile
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (1, 1, d_tile), 2) + d_base
-
-    def contrib(nb, wc):                              # (BR, Ec) chunk
-        ec = nb.shape[1]
-        flat = nb.reshape(-1)
-        v = jnp.take(xv, flat, axis=0).reshape(br, ec, k)
-        col = jnp.take(xi, flat, axis=0).reshape(br, ec * k)
-        vw = (v.astype(jnp.float32)
-              * wc.astype(jnp.float32)[..., None]).reshape(br, ec * k)
-        onehot = (col[:, :, None] == iota_d).astype(jnp.float32)
-        return jnp.einsum("bm,bmd->bd", vw, onehot)
-
-    acc = _chunked_reduce(nbr, w, contrib,
-                          jnp.zeros((br, d_tile), jnp.float32), chunk)
-    out_ref[...] = acc.astype(out_ref.dtype)
+    n = rows_ref.shape[0]
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
 
 
-def drspmm_fwd_bucket(bucket: ELLBucket, x_vals: jax.Array, x_idx: jax.Array,
-                      dim: int, *, interpret: bool | None = None) -> jax.Array:
-    """Y_bucket (R, dim) for one degree bucket (rows still bucket-local)."""
-    if interpret is None:
-        interpret = INTERPRET
-    r, e = bucket.nbr.shape
-    n, k = x_vals.shape
-    br = min(ROW_BLOCK, r)
-    dt, ndt = _d_tiling(dim)
-    grid = (r // br, ndt)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, d_tile=dt, chunk=EDGE_CHUNK),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, e), lambda i, j: (i, 0)),       # nbr row-block
-            pl.BlockSpec((br, e), lambda i, j: (i, 0)),       # w   row-block
-            pl.BlockSpec((n, k), lambda i, j: (0, 0)),        # x_vals (whole)
-            pl.BlockSpec((n, k), lambda i, j: (0, 0)),        # x_idx  (whole)
-        ],
-        out_specs=pl.BlockSpec((br, dt), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, dim), x_vals.dtype),
-        interpret=interpret,
-    )(bucket.nbr, bucket.w, x_vals, x_idx)
+def _row_select(w_row, br: int, ec: int):
+    """(BR, BR·Ec) matrix with w[b·Ec + e] at (b, b·Ec + e), zero elsewhere:
+    contracting it against the chunk's gathered rows is the weighted
+    per-destination neighbour sum."""
+    n = br * ec
+    col = jax.lax.broadcasted_iota(jnp.int32, (br, n), 1)
+    lo = jax.lax.broadcasted_iota(jnp.int32, (br, n), 0) * ec
+    return jnp.where((col >= lo) & (col < lo + ec),
+                     w_row.astype(jnp.float32), 0.0)
 
 
-# ---------------------------------------------------------------------------
-# per-bucket backward (SSpMM): gradients sampled at the forward's CBSR indices
-# ---------------------------------------------------------------------------
-
-def _bwd_kernel(tnbr_ref, tw_ref, gy_ref, xi_ref, out_ref, *, chunk: int):
-    """One source-row-block: dV[j, t] = Σ_i w_ij · dY[i, idx[j, t]].
-
-    ``tnbr``/``tw`` come from the *transposed* ELL packing, so each source row
-    j is owned by exactly one grid cell — accumulation is a private VMEM
-    reduction, no atomics (DESIGN.md §2).  Targets are gathered Ec at a time.
-    """
-    tnbr = tnbr_ref[...]          # (BR, E) target ids i ∈ N(j)
-    tw = tw_ref[...]              # (BR, E)
-    gy = gy_ref[...]              # (M, D)
-    xi = xi_ref[...]              # (BR, k) — this block's CBSR indices
-    br, e_width = tnbr.shape
-    k = xi.shape[1]
-
-    def contrib(ic, wc):                              # (BR, Ec) chunk
-        ec = ic.shape[1]
-        g = jnp.take(gy, ic.reshape(-1), axis=0).reshape(br, ec, -1)
-        idx = jnp.broadcast_to(xi[:, None, :], (br, ec, k))
-        sampled = jnp.take_along_axis(g, idx, axis=2)  # (BR, Ec, k) — SSpMM
-        return jnp.einsum("be,bek->bk", wc.astype(jnp.float32),
-                          sampled.astype(jnp.float32))
-
-    acc = _chunked_reduce(tnbr, tw, contrib,
-                          jnp.zeros((br, k), jnp.float32), chunk)
-    out_ref[...] = acc.astype(out_ref.dtype)
+def _densify(vals, idx, k: int, d_base, d_tile: int):
+    """(R, k) CBSR values/indices → dense (R, d_tile) slice of columns
+    [d_base, d_base + d_tile): one compare-select per kept position."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], d_tile),
+                                    1) + d_base
+    out = jnp.zeros((vals.shape[0], d_tile), jnp.float32)
+    for t in range(k):
+        out = out + jnp.where(idx[:, t:t + 1] == iota, vals[:, t:t + 1], 0.0)
+    return out
 
 
-def drspmm_bwd_bucket(bucket: ELLBucket, gy: jax.Array, xi_rows: jax.Array,
-                      *, interpret: bool | None = None) -> jax.Array:
-    """dV_bucket (R, k) for one transposed-ELL bucket.
+def _sample(g, idx, k: int):
+    """out[r, t] = g[r, idx[r, t]] — the SSpMM gather at each row's own CBSR
+    indices, as a lane-iota compare and a lane reduction per position."""
+    r, d = g.shape
+    iota_d = jax.lax.broadcasted_iota(jnp.int32, (r, d), 1)
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
+    out = jnp.zeros((r, k), jnp.float32)
+    for t in range(k):
+        col = jnp.sum(jnp.where(iota_d == idx[:, t:t + 1], g, 0.0), axis=1,
+                      keepdims=True)
+        out = jnp.where(iota_k == t, col, out)
+    return out
 
-    ``xi_rows`` is x_idx gathered at this bucket's source rows, shape (R, k).
-    """
-    if interpret is None:
-        interpret = INTERPRET
-    r, e = bucket.nbr.shape
-    m, d = gy.shape
-    k = xi_rows.shape[1]
-    br = min(ROW_BLOCK, r)
-    grid = (r // br,)
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=EDGE_CHUNK),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, e), lambda i: (i, 0)),
-            pl.BlockSpec((br, e), lambda i: (i, 0)),
-            pl.BlockSpec((m, d), lambda i: (0, 0)),           # dY (whole)
-            pl.BlockSpec((br, k), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, k), gy.dtype),
-        interpret=interpret,
-    )(bucket.nbr, bucket.w, gy, xi_rows)
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=_HI)
 
 
 # ---------------------------------------------------------------------------
-# per-bucket dense-operand SpMM kernel (baseline, cuSPARSE-analogue) — same
-# bucketed ELL traversal but the operand is a full (N, D) matrix; lets
-# benchmarks compare the CBSR gather traffic (N·k) against the dense gather
-# traffic (N·D) under identical scheduling.
-# ---------------------------------------------------------------------------
-
-def _dense_kernel(nbr_ref, w_ref, x_ref, out_ref, *, chunk: int):
-    nbr = nbr_ref[...]
-    w = w_ref[...]
-    x = x_ref[...]                # (N, DT) — D-tiled slice
-    br, e_width = nbr.shape
-    d = x.shape[1]
-
-    def contrib(nb, wc):                              # (BR, Ec) chunk
-        ec = nb.shape[1]
-        rows = jnp.take(x, nb.reshape(-1), axis=0).reshape(br, ec, d)
-        return jnp.einsum("be,bed->bd", wc.astype(jnp.float32),
-                          rows.astype(jnp.float32))
-
-    acc = _chunked_reduce(nbr, w, contrib,
-                          jnp.zeros((br, d), jnp.float32), chunk)
-    out_ref[...] = acc.astype(out_ref.dtype)
-
-
-def spmm_dense_bucket(bucket: ELLBucket, x: jax.Array,
-                      *, interpret: bool | None = None) -> jax.Array:
-    if interpret is None:
-        interpret = INTERPRET
-    r, e = bucket.nbr.shape
-    n, d = x.shape
-    br = min(ROW_BLOCK, r)
-    dt, ndt = _d_tiling(d)
-    return pl.pallas_call(
-        functools.partial(_dense_kernel, chunk=EDGE_CHUNK),
-        grid=(r // br, ndt),
-        in_specs=[
-            pl.BlockSpec((br, e), lambda i, j: (i, 0)),
-            pl.BlockSpec((br, e), lambda i, j: (i, 0)),
-            pl.BlockSpec((n, dt), lambda i, j: (0, j)),       # D-tiled operand
-        ],
-        out_specs=pl.BlockSpec((br, dt), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
-        interpret=interpret,
-    )(bucket.nbr, bucket.w, x)
-
-
-# ---------------------------------------------------------------------------
-# fused single-dispatch executors — ONE pallas_call for ALL buckets
+# chunk-walk executors over (block_of, start, nbr, w) arena tables
 # ---------------------------------------------------------------------------
 #
-# Grid = (D-tiles, chunks).  Chunks of the same output row-block are
+# Grid = ([D-tiles,] chunks).  Chunks of the same output row-block are
 # consecutive in the arena, so the output BlockSpec's scalar-prefetch index
 # map (blk[c]) revisits each block in an unbroken run: the block stays
 # VMEM-resident across its chunks and is zero-initialized by the chunk whose
 # ``start`` flag is set.  See DESIGN.md §1.
 
-def _fused_fwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, xv_ref, xi_ref,
-                      out_ref, *, d_tile: int):
+def _chunk_specs(r: int, n_lead: int):
+    """(ids in SMEM, weights in VMEM) block specs for chunk tables stored
+    (C, 1, r); ``n_lead`` grid axes precede the chunk axis."""
+    def idx(*a):
+        return (a[n_lead], 0, 0)
+    return (pl.BlockSpec((1, 1, r), idx, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, r), idx))
+
+
+def _chunk_tables(nbr, w):
+    c, br, ec = nbr.shape
+    return (jnp.reshape(jnp.asarray(nbr, jnp.int32), (c, 1, br * ec)),
+            jnp.reshape(jnp.asarray(w), (c, 1, br * ec)))
+
+
+def _arena_fwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, x_hbm, out_ref,
+                      rows_ref, sem, *, k: int, ec: int, d_tile: int):
     c = pl.program_id(1)
 
     @pl.when(st_ref[c] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    nbr = nbr_ref[0]              # (BR, Ec)
-    w = w_ref[0].astype(jnp.float32)
-    xv = xv_ref[...]              # (N, k)
-    xi = xi_ref[...]
-    br, ec = nbr.shape
-    k = xv.shape[1]
+    _gather_rows(x_hbm, nbr_ref, rows_ref, sem)
+    rows = rows_ref[...]                                  # (BR·Ec, lanes)
+    vals = jax.lax.bitcast_convert_type(rows[:, :k], jnp.float32)
+    xd = _densify(vals, rows[:, k:2 * k], k, pl.program_id(0) * d_tile,
+                  d_tile)                                 # (BR·Ec, DT)
+    out_ref[...] += _dot(_row_select(w_ref[0], out_ref.shape[0], ec), xd)
 
-    d_base = pl.program_id(0) * d_tile
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (1, 1, d_tile), 2) + d_base
 
-    flat = nbr.reshape(-1)
-    v = jnp.take(xv, flat, axis=0).reshape(br, ec, k)
-    col = jnp.take(xi, flat, axis=0).reshape(br, ec * k)
-    vw = (v.astype(jnp.float32) * w[..., None]).reshape(br, ec * k)
-    onehot = (col[:, :, None] == iota_d).astype(jnp.float32)
-    out_ref[...] += jnp.einsum("bm,bmd->bd", vw, onehot).astype(out_ref.dtype)
+def _arena_fwd(block_of, start, nbr, w, x_vals, x_idx, dim: int,
+               n_rows: int, interpret):
+    """fp32 (n_rows, dim) Y over the arena: CBSR rows gathered by DMA."""
+    c, br, ec = nbr.shape
+    k = x_vals.shape[1]
+    dt, ndt = _d_tiling(dim)
+    rows = _cbsr_rows(x_vals, x_idx)
+    ids, wts = _chunk_tables(nbr, w)
+    ids_spec, w_spec = _chunk_specs(br * ec, 1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(ndt, c),
+        in_specs=[ids_spec, w_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((br, dt), lambda d, i, blk, st: (blk[i], d)),
+        scratch_shapes=[pltpu.VMEM((br * ec, rows.shape[1]), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    return run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_arena_fwd_kernel, k=k, ec=ec, d_tile=dt),
+        grid_spec=grid_spec,
+        # fp32 accumulator arena regardless of input dtype (chunk revisits
+        # accumulate in the out buffer); the op wrapper casts after gather.
+        out_shape=jax.ShapeDtypeStruct((n_rows, dim), jnp.float32),
+        interpret=interp,
+    )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, rows), interpret)
 
+
+def _arena_bwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, gy_hbm, xi_ref,
+                      out_ref, rows_ref, sem, *, k: int, ec: int):
+    c = pl.program_id(0)
+
+    @pl.when(st_ref[c] == 1)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    _gather_rows(gy_hbm, nbr_ref, rows_ref, sem)
+    br = out_ref.shape[0]
+    # the sampled indices depend on the source row only, so the weighted
+    # target sum comes first and the sampling runs once per row
+    g = _dot(_row_select(w_ref[0], br, ec), rows_ref[...])   # (BR, Dp)
+    out_ref[...] += _sample(g, xi_ref[...], k)
+
+
+def _arena_bwd(block_of, start, tnbr, tw, gy, xi_rows, n_rows: int,
+               interpret):
+    """fp32 (n_rows, k) dV over the transposed arena: dY rows by DMA,
+    sampled at each arena row's CBSR indices (``xi_rows``, arena order)."""
+    c, br, ec = tnbr.shape
+    k = xi_rows.shape[1]
+    gyp = _lane_pad(gy.astype(jnp.float32))
+    ids, wts = _chunk_tables(tnbr, tw)
+    ids_spec, w_spec = _chunk_specs(br * ec, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(c,),
+        in_specs=[ids_spec, w_spec, pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0))],
+        out_specs=pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0)),
+        scratch_shapes=[pltpu.VMEM((br * ec, gyp.shape[1]), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    return run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_arena_bwd_kernel, k=k, ec=ec),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_rows, k), jnp.float32),
+        interpret=interp,
+    )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, gyp,
+      xi_rows.astype(jnp.int32)), interpret)
+
+
+def _arena_spmm_kernel(blk_ref, st_ref, nbr_ref, w_ref, x_hbm, out_ref,
+                       rows_ref, sem, *, ec: int):
+    c = pl.program_id(0)
+
+    @pl.when(st_ref[c] == 1)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    _gather_rows(x_hbm, nbr_ref, rows_ref, sem)
+    br, d = out_ref.shape
+    g = _dot(_row_select(w_ref[0], br, ec), rows_ref[...])   # (BR, Dp)
+    out_ref[...] += g[:, :d]
+
+
+def _arena_spmm(block_of, start, nbr, w, x, n_rows: int, interpret):
+    """fp32 (n_rows, D) dense-operand SpMM over the arena."""
+    c, br, ec = nbr.shape
+    d = x.shape[1]
+    xp = _lane_pad(x.astype(jnp.float32))
+    ids, wts = _chunk_tables(nbr, w)
+    ids_spec, w_spec = _chunk_specs(br * ec, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(c,),
+        in_specs=[ids_spec, w_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((br, d), lambda i, blk, st: (blk[i], 0)),
+        scratch_shapes=[pltpu.VMEM((br * ec, xp.shape[1]), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    return run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_arena_spmm_kernel, ec=ec),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_rows, d), jnp.float32),
+        interpret=interp,
+    )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, xp), interpret)
+
+
+def _bucket_arena(bucket: ELLBucket):
+    """A degree bucket's (R, E) slab as arena tables: R/BR chunks of width
+    E, chunk i opening row-block i."""
+    r, e = bucket.nbr.shape
+    br = min(ROW_BLOCK, r)
+    nb = r // br
+    return (jnp.arange(nb, dtype=jnp.int32), jnp.ones((nb,), jnp.int32),
+            jnp.reshape(bucket.nbr, (nb, br, e)),
+            jnp.reshape(bucket.w, (nb, br, e)))
+
+
+# ---------------------------------------------------------------------------
+# per-bucket executors (reference path): one launch per degree bucket
+# ---------------------------------------------------------------------------
+
+def drspmm_fwd_bucket(bucket: ELLBucket, x_vals: jax.Array, x_idx: jax.Array,
+                      dim: int, *, interpret: bool | None = None) -> jax.Array:
+    """Y_bucket (R, dim) for one degree bucket (rows still bucket-local)."""
+    r = bucket.nbr.shape[0]
+    return _arena_fwd(*_bucket_arena(bucket), x_vals, x_idx, dim, r,
+                      interpret).astype(x_vals.dtype)
+
+
+def drspmm_bwd_bucket(bucket: ELLBucket, gy: jax.Array, xi_rows: jax.Array,
+                      *, interpret: bool | None = None) -> jax.Array:
+    """dV_bucket (R, k) for one transposed-ELL bucket.
+
+    ``tnbr``/``tw`` come from the *transposed* ELL packing, so each source
+    row j is owned by exactly one grid cell — accumulation is a private VMEM
+    reduction, no atomics (DESIGN.md §2).  ``xi_rows`` is x_idx gathered at
+    this bucket's source rows, shape (R, k).
+    """
+    r = bucket.nbr.shape[0]
+    return _arena_bwd(*_bucket_arena(bucket), gy, xi_rows, r,
+                      interpret).astype(gy.dtype)
+
+
+def spmm_dense_bucket(bucket: ELLBucket, x: jax.Array,
+                      *, interpret: bool | None = None) -> jax.Array:
+    """Dense-operand SpMM for one bucket (baseline, cuSPARSE-analogue): the
+    same traversal with an (N, D) operand, so benchmarks can compare the CBSR
+    gather traffic (N·k) against the dense gather traffic (N·D)."""
+    r = bucket.nbr.shape[0]
+    return _arena_spmm(*_bucket_arena(bucket), x, r,
+                       interpret).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# fused single-dispatch executors — ONE pallas_call for ALL buckets
+# ---------------------------------------------------------------------------
 
 def drspmm_fwd_fused(fused: FusedELL, x_vals: jax.Array, x_idx: jax.Array,
                      dim: int, *, interpret: bool | None = None) -> jax.Array:
@@ -292,53 +369,8 @@ def drspmm_fwd_fused(fused: FusedELL, x_vals: jax.Array, x_idx: jax.Array,
 
     Read the caller-ordered output with ``jnp.take(y, fused.gather, 0)``.
     """
-    if interpret is None:
-        interpret = INTERPRET
-    c, br, ec = fused.nbr.shape
-    n, k = x_vals.shape
-    dt, ndt = _d_tiling(dim)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ndt, c),
-        in_specs=[
-            pl.BlockSpec((1, br, ec), lambda d, i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((1, br, ec), lambda d, i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((n, k), lambda d, i, blk, st: (0, 0)),
-            pl.BlockSpec((n, k), lambda d, i, blk, st: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, dt), lambda d, i, blk, st: (blk[i], d)),
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_fwd_kernel, d_tile=dt),
-        grid_spec=grid_spec,
-        # fp32 accumulator arena regardless of input dtype (chunk revisits
-        # accumulate in the out buffer); the op wrapper casts after gather.
-        out_shape=jax.ShapeDtypeStruct((fused.n_arena_rows, dim),
-                                       jnp.float32),
-        interpret=interpret,
-    )(fused.block_of, fused.start, fused.nbr, fused.w, x_vals, x_idx)
-
-
-def _fused_bwd_kernel(blk_ref, st_ref, tnbr_ref, tw_ref, gy_ref, xi_ref,
-                      out_ref):
-    c = pl.program_id(0)
-
-    @pl.when(st_ref[c] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    tnbr = tnbr_ref[0]            # (BR, Ec)
-    tw = tw_ref[0].astype(jnp.float32)
-    gy = gy_ref[...]              # (M, D)
-    xi = xi_ref[...]              # (BR, k) — this arena block's CBSR indices
-    br, ec = tnbr.shape
-    k = xi.shape[1]
-
-    g = jnp.take(gy, tnbr.reshape(-1), axis=0).reshape(br, ec, -1)
-    idx = jnp.broadcast_to(xi[:, None, :], (br, ec, k))
-    sampled = jnp.take_along_axis(g, idx, axis=2)      # (BR, Ec, k) — SSpMM
-    out_ref[...] += jnp.einsum("be,bek->bk", tw,
-                               sampled.astype(jnp.float32)).astype(out_ref.dtype)
+    return _arena_fwd(fused.block_of, fused.start, fused.nbr, fused.w,
+                      x_vals, x_idx, dim, fused.n_arena_rows, interpret)
 
 
 def drspmm_bwd_fused(fused_t: FusedELL, gy: jax.Array, xi_arena: jax.Array,
@@ -348,29 +380,16 @@ def drspmm_bwd_fused(fused_t: FusedELL, gy: jax.Array, xi_arena: jax.Array,
     ``fused_t`` is the fused *transposed* packing; ``xi_arena`` is x_idx
     gathered at ``fused_t.rows`` (arena source order), shape (R_arena, k).
     """
-    if interpret is None:
-        interpret = INTERPRET
-    c, br, ec = fused_t.nbr.shape
-    m, d = gy.shape
-    k = xi_arena.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(c,),
-        in_specs=[
-            pl.BlockSpec((1, br, ec), lambda i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((1, br, ec), lambda i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((m, d), lambda i, blk, st: (0, 0)),
-            pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0)),
-    )
-    return pl.pallas_call(
-        _fused_bwd_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((fused_t.n_arena_rows, k),
-                                       jnp.float32),
-        interpret=interpret,
-    )(fused_t.block_of, fused_t.start, fused_t.nbr, fused_t.w, gy, xi_arena)
+    return _arena_bwd(fused_t.block_of, fused_t.start, fused_t.nbr,
+                      fused_t.w, gy, xi_arena, fused_t.n_arena_rows,
+                      interpret)
+
+
+def spmm_dense_fused(fused: FusedELL, x: jax.Array,
+                     *, interpret: bool | None = None) -> jax.Array:
+    """Dense-operand SpMM over the fused arena — ONE kernel launch."""
+    return _arena_spmm(fused.block_of, fused.start, fused.nbr, fused.w, x,
+                       fused.n_arena_rows, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -423,84 +442,36 @@ def drspmm_bwd_multi(super_bwd: FusedELL, bwd_src_rows: jax.Array,
     return drspmm_bwd_fused(super_bwd, gy_cat, xi_arena, interpret=interpret)
 
 
-def _fused_dense_kernel(blk_ref, st_ref, nbr_ref, w_ref, x_ref, out_ref):
-    c = pl.program_id(1)
-
-    @pl.when(st_ref[c] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    nbr = nbr_ref[0]
-    w = w_ref[0].astype(jnp.float32)
-    x = x_ref[...]                # (N, DT) — D-tiled slice
-    br, ec = nbr.shape
-    d = x.shape[1]
-    rows = jnp.take(x, nbr.reshape(-1), axis=0).reshape(br, ec, d)
-    out_ref[...] += jnp.einsum("be,bed->bd", w,
-                               rows.astype(jnp.float32)).astype(out_ref.dtype)
-
-
-def spmm_dense_fused(fused: FusedELL, x: jax.Array,
-                     *, interpret: bool | None = None) -> jax.Array:
-    """Dense-operand SpMM over the fused arena — ONE kernel launch."""
-    if interpret is None:
-        interpret = INTERPRET
-    c, br, ec = fused.nbr.shape
-    n, d = x.shape
-    dt, ndt = _d_tiling(d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ndt, c),
-        in_specs=[
-            pl.BlockSpec((1, br, ec), lambda dd, i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((1, br, ec), lambda dd, i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((n, dt), lambda dd, i, blk, st: (0, dd)),
-        ],
-        out_specs=pl.BlockSpec((br, dt), lambda dd, i, blk, st: (blk[i], dd)),
-    )
-    return pl.pallas_call(
-        _fused_dense_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((fused.n_arena_rows, d), jnp.float32),
-        interpret=interpret,
-    )(fused.block_of, fused.start, fused.nbr, fused.w, x)
-
-
 # ---------------------------------------------------------------------------
 # dense-tier executors — tiny relations (nnz ≤ DENSE_TIER_NNZ, graphs/ell.py)
 # skip the chunk-walk arena entirely: the plan materializes the relation
-# stack as ONE dense matrix and the whole tier runs as a single masked
+# stack as ONE dense matrix and the whole tier runs as a single tiled
 # matmul (fwd) / single transposed matmul + in-kernel CBSR sampling (bwd).
 # Same custom-vjp contract as the arena path: grad flows to x_vals only,
-# sampled at x_idx (SSpMM).  DESIGN.md §14.
+# sampled at x_idx (SSpMM).  Both grids tile the source axis as well as the
+# output rows, so no block grows with the type-concat slab.  DESIGN.md §14.
 # ---------------------------------------------------------------------------
 
-DENSE_TIER_ROW_BLOCK = 8      # output rows per grid step (fwd M / bwd N)
-DENSE_TIER_SRC_CHUNK = 128    # source rows per scatter-densify step (fwd)
+DENSE_TIER_ROW_BLOCK = 256    # output rows per grid step (fwd M / bwd N)
+DENSE_TIER_SRC_CHUNK = 512    # contraction columns per grid step
 
 
-def _dense_tier_fwd_kernel(a_ref, xv_ref, xi_ref, out_ref,
-                           *, d_tile: int, n_chunk: int):
-    a = a_ref[...].astype(jnp.float32)        # (RB, Np)
-    xv = xv_ref[...].astype(jnp.float32)      # (Np, k)
-    xi = xi_ref[...]                          # (Np, k)
-    rb, npad = a.shape
+def _dense_tier_fwd_kernel(a_ref, xv_ref, xi_ref, out_ref, *, k: int,
+                           d_tile: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    d_base = pl.program_id(0) * d_tile
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (1, 1, d_tile), 2) + d_base
+    xd = _densify(xv_ref[...].astype(jnp.float32), xi_ref[...], k,
+                  pl.program_id(0) * d_tile, d_tile)      # (NC, DT)
+    out_ref[...] += _dot(a_ref[...].astype(jnp.float32), xd)
 
-    def body(c, acc):
-        off = c * n_chunk
-        vc = jax.lax.dynamic_slice_in_dim(xv, off, n_chunk, 0)   # (NC, k)
-        ic = jax.lax.dynamic_slice_in_dim(xi, off, n_chunk, 0)
-        ac = jax.lax.dynamic_slice_in_dim(a, off, n_chunk, 1)    # (RB, NC)
-        onehot = (ic[:, :, None] == iota_d).astype(jnp.float32)  # (NC, k, DT)
-        xd = jnp.einsum("nk,nkd->nd", vc, onehot)                # (NC, DT)
-        return acc + ac @ xd
 
-    acc = jnp.zeros((rb, d_tile), jnp.float32)
-    acc = jax.lax.fori_loop(0, npad // n_chunk, body, acc)
-    out_ref[...] = acc.astype(out_ref.dtype)
+def _tiles(n: int, cap: int, align: int) -> tuple:
+    """(block, padded extent) for an axis of size n: blocks of at most
+    ``cap``, aligned to ``align``."""
+    block = min(cap, _round_up(max(n, 1), align))
+    return block, _round_up(max(n, 1), block)
 
 
 def drspmm_dense_tier_fwd(a_dense: jax.Array, x_vals: jax.Array,
@@ -509,47 +480,51 @@ def drspmm_dense_tier_fwd(a_dense: jax.Array, x_vals: jax.Array,
     """Y = A·dense(CBSR(x)) for a dense-tier relation stack in ONE launch.
 
     ``a_dense`` is the (M, N) dense relation matrix (the plan's
-    ``dense_fwd``); the CBSR operand is scatter-densified in-kernel in
-    source chunks, so no (N, dim) intermediate is ever materialized in HBM.
-    Returns fp32 (M, dim); the op wrapper casts.
+    ``dense_fwd``); the CBSR operand is scatter-densified in-kernel one
+    source chunk at a time, so no (N, dim) intermediate is ever materialized
+    in HBM.  Returns fp32 (M, dim); the op wrapper casts.
     """
-    if interpret is None:
-        interpret = INTERPRET
     m, n = a_dense.shape
     k = x_vals.shape[1]
     if m == 0 or n == 0:
         return jnp.zeros((m, dim), jnp.float32)
-    rb = DENSE_TIER_ROW_BLOCK
-    nc = min(DENSE_TIER_SRC_CHUNK, _round_up(n, 8))
-    mp = _round_up(m, rb)
-    npad = _round_up(n, nc)
-    # constant-folded under jit: shapes are static, pads are zeros (padded
-    # x_idx rows point at column 0 but carry zero values — inert).
+    rb, mp = _tiles(m, DENSE_TIER_ROW_BLOCK, 8)
+    nc, npad = _tiles(n, DENSE_TIER_SRC_CHUNK, LANES)
+    # zero pads are inert: padded x_idx rows point at column 0 but carry
+    # zero values, padded A rows/columns are zero
     a_p = jnp.pad(a_dense, ((0, mp - m), (0, npad - n)))
     xv_p = jnp.pad(x_vals, ((0, npad - n), (0, 0)))
-    xi_p = jnp.pad(x_idx, ((0, npad - n), (0, 0)))
+    xi_p = jnp.pad(x_idx.astype(jnp.int32), ((0, npad - n), (0, 0)))
     dt, ndt = _d_tiling(dim)
-    y = pl.pallas_call(
-        functools.partial(_dense_tier_fwd_kernel, d_tile=dt, n_chunk=nc),
-        grid=(ndt, mp // rb),
+    y = run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_dense_tier_fwd_kernel, k=k, d_tile=dt),
+        grid=(ndt, mp // rb, npad // nc),
         in_specs=[
-            pl.BlockSpec((rb, npad), lambda d, i: (i, 0)),
-            pl.BlockSpec((npad, k), lambda d, i: (0, 0)),
-            pl.BlockSpec((npad, k), lambda d, i: (0, 0)),
+            pl.BlockSpec((rb, nc), lambda d, i, j: (i, j)),
+            pl.BlockSpec((nc, k), lambda d, i, j: (j, 0)),
+            pl.BlockSpec((nc, k), lambda d, i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((rb, dt), lambda d, i: (i, d)),
+        out_specs=pl.BlockSpec((rb, dt), lambda d, i, j: (i, d)),
         out_shape=jax.ShapeDtypeStruct((mp, dim), jnp.float32),
-        interpret=interpret,
-    )(a_p, xv_p, xi_p)
+        interpret=interp,
+    )(a_p, xv_p, xi_p), interpret)
     return y[:m]
 
 
-def _dense_tier_bwd_kernel(at_ref, gy_ref, xi_ref, out_ref):
-    at = at_ref[...].astype(jnp.float32)      # (RB, M)
-    gy = gy_ref[...].astype(jnp.float32)      # (M, D)
-    xi = xi_ref[...]                          # (RB, k)
-    dx = at @ gy                              # (RB, D) — dense row cotangent
-    out_ref[...] = jnp.take_along_axis(dx, xi, axis=1).astype(out_ref.dtype)
+def _dense_tier_bwd_kernel(at_ref, gy_ref, xi_ref, out_ref, acc_ref, *,
+                           k: int):
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += _dot(at_ref[...].astype(jnp.float32),
+                         gy_ref[...].astype(jnp.float32))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _sample_out():
+        out_ref[...] = _sample(acc_ref[...], xi_ref[...], k)
 
 
 def drspmm_dense_tier_bwd(a_dense_t: jax.Array, gy: jax.Array,
@@ -558,87 +533,51 @@ def drspmm_dense_tier_bwd(a_dense_t: jax.Array, gy: jax.Array,
     """dV = sample(Aᵀ·gY, x_idx) for the dense tier in ONE launch.
 
     ``a_dense_t`` is the transposed relation matrix (the plan's
-    ``dense_bwd``, (N, M)); the SSpMM sampling happens in-kernel via
-    ``take_along_axis`` at each source row's own CBSR indices, so the
-    (N, dim) dense cotangent never leaves VMEM.  Returns fp32 (N, k).
+    ``dense_bwd``, (N, M)); the (rows, dim) dense cotangent accumulates in a
+    VMEM scratch over M chunks and is sampled in-kernel at each source row's
+    own CBSR indices, so it never leaves VMEM.  Returns fp32 (N, k).
     """
-    if interpret is None:
-        interpret = INTERPRET
     n, m = a_dense_t.shape
     k = x_idx.shape[1]
     if n == 0 or m == 0:
         return jnp.zeros((n, k), jnp.float32)
-    rb = DENSE_TIER_ROW_BLOCK
-    npad = _round_up(n, rb)
-    at_p = jnp.pad(a_dense_t, ((0, npad - n), (0, 0)))
-    xi_p = jnp.pad(x_idx, ((0, npad - n), (0, 0)))
     d = gy.shape[1]
-    dv = pl.pallas_call(
-        _dense_tier_bwd_kernel,
-        grid=(npad // rb,),
+    rb, npad = _tiles(n, DENSE_TIER_ROW_BLOCK, 8)
+    mc, mpad = _tiles(m, DENSE_TIER_SRC_CHUNK, LANES)
+    at_p = jnp.pad(a_dense_t, ((0, npad - n), (0, mpad - m)))
+    gy_p = jnp.pad(gy, ((0, mpad - m), (0, 0)))
+    xi_p = jnp.pad(x_idx.astype(jnp.int32), ((0, npad - n), (0, 0)))
+    dv = run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_dense_tier_bwd_kernel, k=k),
+        grid=(npad // rb, mpad // mc),
         in_specs=[
-            pl.BlockSpec((rb, m), lambda i: (i, 0)),
-            pl.BlockSpec((m, d), lambda i: (0, 0)),
-            pl.BlockSpec((rb, k), lambda i: (i, 0)),
+            pl.BlockSpec((rb, mc), lambda i, j: (i, j)),
+            pl.BlockSpec((mc, d), lambda i, j: (j, 0)),
+            pl.BlockSpec((rb, k), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((rb, k), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rb, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((npad, k), jnp.float32),
-        interpret=interpret,
-    )(at_p, gy, xi_p)
+        scratch_shapes=[pltpu.VMEM((rb, d), jnp.float32)],
+        interpret=interp,
+    )(at_p, gy_p, xi_p), interpret)
     return dv[:n]
 
 
 # ---------------------------------------------------------------------------
 # fused learnable-edge executors — Y = A(w)·dense(CBSR(x)) with the weight
-# vector w (nnz,) gathered IN-KERNEL from the arena's eid table, so the
+# vector w (nnz,) gathered into arena order from the eid table (one XLA
+# gather of C·BR·Ec weights), then the fixed-weight chunk walk above: the
 # differentiable-edge path (kernels/ops.py::drspmm_learnable) is the same
 # single dispatch per direction as the fixed-weight path.  DESIGN.md §8.
 # ---------------------------------------------------------------------------
 
-def _pad_w_canon(w_canon: jax.Array, nnz: int) -> jax.Array:
-    """(nnz,) → (W, 1) with W = nnz+1 rounded up to the row block: slot
-    ``nnz`` (and everything after) is guaranteed zero, so −1-padded eids
-    remapped to ``nnz`` gather an inert weight.  2-D so the in-kernel gather
-    is the same row-take the CBSR operands use."""
-    wpad = _round_up(nnz + 1, ROW_BLOCK)
-    wp = jnp.zeros((wpad, 1), jnp.float32)
-    return wp.at[:nnz, 0].set(w_canon.astype(jnp.float32))
-
-
-def _gather_chunk_w(wp, eid, nnz: int):
-    """(BR, Ec) weight chunk from the padded canonical vector; −1 → 0."""
-    safe = jnp.where(eid < 0, nnz, eid)
-    br, ec = eid.shape
-    return jnp.take(wp, safe.reshape(-1), axis=0).reshape(br, ec)
-
-
-def _fused_fwd_learnable_kernel(blk_ref, st_ref, nbr_ref, eid_ref, wp_ref,
-                                xv_ref, xi_ref, out_ref, *, d_tile: int,
-                                nnz: int):
-    c = pl.program_id(1)
-
-    @pl.when(st_ref[c] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    nbr = nbr_ref[0]              # (BR, Ec)
-    eid = eid_ref[0]              # (BR, Ec) int32, −1 padding
-    wp = wp_ref[...]              # (W, 1) padded canonical weights
-    xv = xv_ref[...]              # (N, k)
-    xi = xi_ref[...]
-    br, ec = nbr.shape
-    k = xv.shape[1]
-    w = _gather_chunk_w(wp, eid, nnz)                 # in-kernel weight gather
-
-    d_base = pl.program_id(0) * d_tile
-    iota_d = jax.lax.broadcasted_iota(jnp.int32, (1, 1, d_tile), 2) + d_base
-
-    flat = nbr.reshape(-1)
-    v = jnp.take(xv, flat, axis=0).reshape(br, ec, k)
-    col = jnp.take(xi, flat, axis=0).reshape(br, ec * k)
-    vw = (v.astype(jnp.float32) * w[..., None]).reshape(br, ec * k)
-    onehot = (col[:, :, None] == iota_d).astype(jnp.float32)
-    out_ref[...] += jnp.einsum("bm,bmd->bd", vw, onehot).astype(out_ref.dtype)
+def _slot_weights(eid, nnz: int, w_canon: jax.Array) -> jax.Array:
+    """(C, BR, Ec) arena weights from the canonical vector; −1-padded eids
+    read an appended zero, so padding stays inert."""
+    wp = jnp.concatenate([w_canon.astype(jnp.float32),
+                          jnp.zeros((1,), jnp.float32)])
+    eid = jnp.asarray(eid)
+    return jnp.take(wp, jnp.where(eid < 0, nnz, eid), axis=0)
 
 
 def drspmm_fwd_learnable_fused(fused: FusedELL, nnz: int,
@@ -650,57 +589,10 @@ def drspmm_fwd_learnable_fused(fused: FusedELL, nnz: int,
     ``fused`` must carry an eid arena (``fuse_bucketed(..., eids=True)``).
     Read the caller-ordered output with ``jnp.take(y, fused.gather, 0)``.
     """
-    if interpret is None:
-        interpret = INTERPRET
     assert fused.eid is not None, "learnable executor needs an eid arena"
-    c, br, ec = fused.nbr.shape
-    n, k = x_vals.shape
-    wp = _pad_w_canon(w_canon, nnz)
-    wlen = wp.shape[0]
-    dt, ndt = _d_tiling(dim)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(ndt, c),
-        in_specs=[
-            pl.BlockSpec((1, br, ec), lambda d, i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((1, br, ec), lambda d, i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((wlen, 1), lambda d, i, blk, st: (0, 0)),
-            pl.BlockSpec((n, k), lambda d, i, blk, st: (0, 0)),
-            pl.BlockSpec((n, k), lambda d, i, blk, st: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, dt), lambda d, i, blk, st: (blk[i], d)),
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_fwd_learnable_kernel, d_tile=dt, nnz=nnz),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((fused.n_arena_rows, dim),
-                                       jnp.float32),
-        interpret=interpret,
-    )(fused.block_of, fused.start, fused.nbr, fused.eid, wp, x_vals, x_idx)
-
-
-def _fused_bwd_learnable_kernel(blk_ref, st_ref, tnbr_ref, teid_ref, wp_ref,
-                                gy_ref, xi_ref, out_ref, *, nnz: int):
-    c = pl.program_id(0)
-
-    @pl.when(st_ref[c] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    tnbr = tnbr_ref[0]            # (BR, Ec) target ids i ∈ N(j)
-    teid = teid_ref[0]            # (BR, Ec) canonical edge ids
-    wp = wp_ref[...]              # (W, 1)
-    gy = gy_ref[...]              # (M, D)
-    xi = xi_ref[...]              # (BR, k) — this arena block's CBSR indices
-    br, ec = tnbr.shape
-    k = xi.shape[1]
-    tw = _gather_chunk_w(wp, teid, nnz)
-
-    g = jnp.take(gy, tnbr.reshape(-1), axis=0).reshape(br, ec, -1)
-    idx = jnp.broadcast_to(xi[:, None, :], (br, ec, k))
-    sampled = jnp.take_along_axis(g, idx, axis=2)      # (BR, Ec, k) — SSpMM
-    out_ref[...] += jnp.einsum("be,bek->bk", tw,
-                               sampled.astype(jnp.float32)).astype(out_ref.dtype)
+    return _arena_fwd(fused.block_of, fused.start, fused.nbr,
+                      _slot_weights(fused.eid, nnz, w_canon), x_vals, x_idx,
+                      dim, fused.n_arena_rows, interpret)
 
 
 def drspmm_bwd_learnable_fused(fused_t: FusedELL, nnz: int,
@@ -708,58 +600,36 @@ def drspmm_bwd_learnable_fused(fused_t: FusedELL, nnz: int,
                                xi_arena: jax.Array,
                                *, interpret: bool | None = None) -> jax.Array:
     """Arena-ordered dL/dx_vals (R_arena, k) in ONE kernel launch — the
-    transposed sampled backward with the same in-kernel weight gather."""
-    if interpret is None:
-        interpret = INTERPRET
+    transposed sampled backward over the same arena-order weights."""
     assert fused_t.eid is not None, "learnable executor needs an eid arena"
-    c, br, ec = fused_t.nbr.shape
-    m, d = gy.shape
-    k = xi_arena.shape[1]
-    wp = _pad_w_canon(w_canon, nnz)
-    wlen = wp.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(c,),
-        in_specs=[
-            pl.BlockSpec((1, br, ec), lambda i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((1, br, ec), lambda i, blk, st: (i, 0, 0)),
-            pl.BlockSpec((wlen, 1), lambda i, blk, st: (0, 0)),
-            pl.BlockSpec((m, d), lambda i, blk, st: (0, 0)),
-            pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_bwd_learnable_kernel, nnz=nnz),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((fused_t.n_arena_rows, k),
-                                       jnp.float32),
-        interpret=interpret,
-    )(fused_t.block_of, fused_t.start, fused_t.nbr, fused_t.eid, wp, gy,
-      xi_arena)
+    return _arena_bwd(fused_t.block_of, fused_t.start, fused_t.nbr,
+                      _slot_weights(fused_t.eid, nnz, w_canon), gy,
+                      xi_arena, fused_t.n_arena_rows, interpret)
 
 
-def _fused_dw_learnable_kernel(blk_ref, nbr_ref, gy_ref, xv_ref, xi_ref,
-                               out_ref):
-    """Per-slot sampled dot: out[0, r, e] = Σ_t dY[row_r, idx[nbr_re, t]] ·
-    vals[nbr_re, t].  Same memory-access pattern as the dx gather with the
-    roles of weight and value swapped (kernels/learnable.py); the scatter of
-    slot contributions into canonical w order happens OUTSIDE the kernel
-    (one XLA scatter — TPUs have no fast in-kernel scatter)."""
-    nbr = nbr_ref[0]              # (BR, Ec)
-    gy = gy_ref[...]              # (BR, D) — this chunk's dY rows
-    xv = xv_ref[...]              # (N, k)
-    xi = xi_ref[...]
-    br, ec = nbr.shape
-    k = xv.shape[1]
-    d = gy.shape[1]
-
-    flat = nbr.reshape(-1)
-    v = jnp.take(xv, flat, axis=0).reshape(br, ec, k)
-    col = jnp.take(xi, flat, axis=0).reshape(br, ec, k)
-    g = jnp.broadcast_to(gy.astype(jnp.float32)[:, None, :], (br, ec, d))
-    sampled = jnp.take_along_axis(g, col, axis=2)      # (BR, Ec, k)
-    out_ref[0] = jnp.sum(sampled * v.astype(jnp.float32), axis=-1)
+def _arena_dw_kernel(blk_ref, nbr_ref, x_hbm, gy_ref, out_ref, rows_ref, sem,
+                     *, k: int, ec: int):
+    """Per-slot sampled dot: out[0, 0, b·Ec + e] = Σ_t dY[row_b, idx[n, t]] ·
+    vals[n, t] with n = nbr[b, e].  Same gather as the forward with the roles
+    of weight and value swapped (kernels/learnable.py); the scatter of slot
+    contributions into canonical w order happens OUTSIDE the kernel (one XLA
+    scatter — TPUs have no fast in-kernel scatter)."""
+    _gather_rows(x_hbm, nbr_ref, rows_ref, sem)
+    rows = rows_ref[...]
+    gy = gy_ref[...].astype(jnp.float32)              # (BR, D) chunk dY rows
+    br, d = gy.shape
+    vals = jax.lax.bitcast_convert_type(rows[:, :k], jnp.float32)
+    xd = _densify(vals, rows[:, k:2 * k], k, 0, d)    # (BR·Ec, D)
+    # broadcast each destination's dY row to its Ec slots (0/1 selection)
+    gy_slots = jax.lax.dot_general(
+        _row_select(jnp.ones((1, br * ec), jnp.float32), br, ec), gy,
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_HI)                                 # (BR·Ec, D)
+    # slot sums as a lane-major row: ones (1, D) · (xd ∘ gy_slots)ᵀ
+    out_ref[0] = jax.lax.dot_general(
+        jnp.ones((1, d), jnp.float32), xd * gy_slots,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=_HI)
 
 
 def drspmm_dw_learnable_fused(fused: FusedELL, gy_arena: jax.Array,
@@ -771,26 +641,31 @@ def drspmm_dw_learnable_fused(fused: FusedELL, gy_arena: jax.Array,
     The caller reduces to canonical order with one scatter-add over the eid
     table: ``zeros(nnz+1).at[where(eid<0, nnz, eid)].add(contrib)[:nnz]``.
     """
-    if interpret is None:
-        interpret = INTERPRET
     assert fused.eid is not None, "learnable executor needs an eid arena"
     c, br, ec = fused.nbr.shape
-    n, k = x_vals.shape
+    k = x_vals.shape[1]
     d = gy_arena.shape[1]
+    rows = _cbsr_rows(x_vals, x_idx)
+    ids, _ = _chunk_tables(fused.nbr, fused.w)
+
+    def idx(i, blk):
+        return (i, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(c,),
-        in_specs=[
-            pl.BlockSpec((1, br, ec), lambda i, blk: (i, 0, 0)),
-            pl.BlockSpec((br, d), lambda i, blk: (blk[i], 0)),
-            pl.BlockSpec((n, k), lambda i, blk: (0, 0)),
-            pl.BlockSpec((n, k), lambda i, blk: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, br, ec), lambda i, blk: (i, 0, 0)),
+        in_specs=[pl.BlockSpec((1, 1, br * ec), idx,
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec((br, d), lambda i, blk: (blk[i], 0))],
+        out_specs=pl.BlockSpec((1, 1, br * ec), idx),
+        scratch_shapes=[pltpu.VMEM((br * ec, rows.shape[1]), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        _fused_dw_learnable_kernel,
+    out = run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_arena_dw_kernel, k=k, ec=ec),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((c, br, ec), jnp.float32),
-        interpret=interpret,
-    )(fused.block_of, fused.nbr, gy_arena, x_vals, x_idx)
+        out_shape=jax.ShapeDtypeStruct((c, 1, br * ec), jnp.float32),
+        interpret=interp,
+    )(jnp.asarray(fused.block_of), ids, rows, gy_arena), interpret)
+    return out.reshape(c, br, ec)

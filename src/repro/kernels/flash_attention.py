@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.drspmm import INTERPRET
+from repro.kernels.drspmm import run_pallas
 
 BLOCK_Q = 128
 BLOCK_K = 128
@@ -74,8 +74,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """q (B, Sq, H, hd); k/v (B, Sk, H, hd) — H already tiled/padded.
 
     Returns (B, Sq, H, hd)."""
-    if interpret is None:
-        interpret = INTERPRET
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     bq = min(BLOCK_Q, sq)
@@ -87,7 +85,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, hd)
 
-    out = pl.pallas_call(
+    out = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_flash_kernel, causal=causal, sk=sk, block_k=bk,
                           scale=scale),
         grid=(b * h, sq // bq),
@@ -98,6 +96,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((None, bq, hd), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, hd), q.dtype),
-        interpret=interpret,
-    )(qf, kf, vf)
+        interpret=interp,
+    )(qf, kf, vf), interpret)
     return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)

@@ -62,12 +62,14 @@ Backend = Literal["pallas_fused", "xla_fused", "pallas", "xla", "dense"]
 DEFAULT_BACKEND: Backend = (
     "pallas_fused" if jax.default_backend() == "tpu" else "xla_fused")
 
-# Trace-time dispatch log: every fused-family executor issue appends a
-# "family:kind" tag while its op body runs (i.e. while TRACING under jit —
-# compiled replays don't re-run Python, so count deltas around an explicit
-# trace such as ``jax.make_jaxpr``).  This is how tests and bench smoke
-# assert the one-dispatch-per-direction-group property for the xla family,
-# where jaxpr ``pallas_call`` counting has nothing to count.  Bounded: a
+# Trace-time dispatch log: every executor issue appends a "family:kind" tag
+# while its op body runs (i.e. while TRACING under jit — compiled replays
+# don't re-run Python, so count deltas around an explicit trace such as
+# ``jax.make_jaxpr``).  Per-bucket issues log as "pallas:bucket_*" /
+# "xla:bucket_*", so a downgrade off the fused path shows.  This is how
+# tests and bench smoke assert the one-dispatch-per-direction-group
+# property for the xla family, where jaxpr ``pallas_call`` counting has
+# nothing to count.  Bounded: a
 # long-lived serve loop retraces per (bucket, device) compile and eviction
 # return, and nothing outside tests ever drains the log.
 FUSED_DISPATCH_LOG: "deque[str]" = deque(maxlen=4096)
@@ -207,6 +209,7 @@ def _fwd_impl(adj: BucketedELL, x_vals, x_idx, dim: int, backend: Backend):
         return jnp.take(ya, f.gather, axis=0).astype(x_vals.dtype)
     y = jnp.zeros((adj.n_dst, dim), x_vals.dtype)
     for b in adj.buckets:
+        _record_dispatch(f"{backend}:bucket_fwd")
         if backend == "pallas":
             yb = _k.drspmm_fwd_bucket(b, x_vals, x_idx, dim)
         else:
@@ -230,6 +233,7 @@ def _bwd_impl(adj_t: BucketedELL, gy, x_idx, backend: Backend):
         return jnp.take(ga, ft.gather, axis=0).astype(gy.dtype)
     gv = jnp.zeros((n, k), gy.dtype)
     for b in adj_t.buckets:
+        _record_dispatch(f"{backend}:bucket_bwd")
         xi_rows = jnp.take(x_idx, b.rows, axis=0)     # (R, k)
         if backend == "pallas":
             gb = _k.drspmm_bwd_bucket(b, gy, xi_rows)
@@ -379,6 +383,7 @@ def _spmm_fwd(adj: BucketedELL, x, backend: Backend):
         return jnp.take(ya, f.gather, axis=0).astype(x.dtype)
     y = jnp.zeros((adj.n_dst, x.shape[1]), x.dtype)
     for b in adj.buckets:
+        _record_dispatch(f"{backend}:bucket_spmm")
         if backend == "pallas":
             yb = _k.spmm_dense_bucket(b, x)
         else:
@@ -1116,7 +1121,7 @@ def _build_multi_sharded(splan, dim: int, backend: Backend, trace_key=None):
     scatter-added into the owner shards' dx rows (two-coordinate backward,
     DESIGN.md §12).  Padded slots carry zero weights end to end — inert.
     """
-    from repro.sharding.specs import shard_map_compat, shard_mesh
+    from repro.sharding.specs import shard_mesh
     from jax.sharding import PartitionSpec as P
 
     n, s_slab, t_slab, h = (splan.n_shards, splan.src_slab, splan.out_slab,
@@ -1168,10 +1173,10 @@ def _build_multi_sharded(splan, dim: int, backend: Backend, trace_key=None):
             back.reshape(n * h, -1))
 
     sm = dict(mesh=mesh, check_vma=False)
-    fwd_sm = shard_map_compat(in_specs=(spec,) * 9, out_specs=spec,
-                              **sm)(fwd_inner)
-    bwd_sm = shard_map_compat(in_specs=(spec,) * 9, out_specs=spec,
-                              **sm)(bwd_inner)
+    fwd_sm = jax.shard_map(fwd_inner, in_specs=(spec,) * 9,
+                           out_specs=spec, **sm)
+    bwd_sm = jax.shard_map(bwd_inner, in_specs=(spec,) * 9,
+                           out_specs=spec, **sm)
     fwd_tabs = (splan.fwd_nbr, splan.fwd_w, splan.fwd_block_of,
                 splan.fwd_start, splan.fwd_rows, splan.fwd_gather)
     bwd_tabs = (splan.bwd_nbr, splan.bwd_w, splan.bwd_block_of,

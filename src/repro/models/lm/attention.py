@@ -236,9 +236,8 @@ def decode_attention(q, k_cache, v_cache, pos, new_k, new_v):
     q_spec = P(bspec, None, None, None)
     new_spec = P(bspec, None, None, None)
 
-    from repro.sharding.specs import shard_map_compat
 
-    @shard_map_compat(
+    @jax.shard_map(
         mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, P(), new_spec, new_spec),
         out_specs=(q_spec, cache_spec, cache_spec),

@@ -30,7 +30,6 @@ from repro.sharding.specs import (batch_axes, constrain, get_mesh,
                                   manual_axes)
 
 
-from repro.sharding.specs import shard_map_compat as _shard_map
 
 
 def swiglu_ffn(x, w_gate, w_up, w_down, drelu_k: int = 0,
@@ -86,7 +85,7 @@ def _drelu_sharded(h, k: int, groups: int):
         bspec = None
     spec = P(bspec, None, "model", None)
 
-    @_shard_map(mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
+    @jax.shard_map(mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
     def local_topk(x):
         return _drelu_dense(x, k // groups)
 
@@ -209,7 +208,7 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, *, n_experts: int,
     x_spec = P(bspec, "model", None)
     w_spec = P("model", None, None)
 
-    @_shard_map(mesh=mesh,
+    @jax.shard_map(mesh=mesh,
                 in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
                 out_specs=x_spec, check_vma=False)
     def shmap_moe(x_l, rw, wg_l, wu_l, wd_l):

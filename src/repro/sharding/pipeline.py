@@ -50,9 +50,8 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params,
 
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
-    from repro.sharding.specs import shard_map_compat
 
-    @shard_map_compat(
+    @jax.shard_map(
         mesh=mesh, axis_names={axis},
         in_specs=(p_spec, P()), out_specs=P(), check_vma=False)
     def run(params_l, mbs):

@@ -16,18 +16,10 @@ Public helpers
 ``mesh_context(mesh)`` — scope the active mesh (thread-local); every
 ``constrain``/``make_pspec`` call inside resolves logical names against it::
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))   # repro.launch.mesh
     with mesh_context(mesh), mesh:
         state = init_train_state(...)        # params land FSDP-sharded
         out = train_step(state, batch)       # constrain() sees the mesh
-
-``shard_map_compat(mesh=..., in_specs=..., out_specs=...)`` — decorator
-factory over ``jax.shard_map`` that also runs on older jax releases (maps
-``check_vma``/``axis_names`` onto ``check_rep``/``auto``)::
-
-    @shard_map_compat(mesh=mesh, in_specs=P("data"), out_specs=P("data"))
-    def per_shard(x):                        # x is this device's slice
-        return x * 2
 
 ``DeviceRing`` / ``batch_devices`` — round-robin routing of *independent*
 dispatches (collated serve batches, data-parallel gradient shards) onto the
@@ -116,16 +108,7 @@ def constrain(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
     if mesh is None or mesh.size == 1 or manual_axes():
         return x
     spec = make_pspec(x.shape, axes, mesh)
-    try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except ValueError as e:
-        # Inside a partial-manual shard_map on jax versions where
-        # manual-mode detection (manual_axes) is unavailable, constraining
-        # a manual axis raises; the shard_map specs govern there — no-op.
-        # Any other invalid spec must still fail loudly.
-        if "manual" in str(e).lower():
-            return x
-        raise
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def param_sharding(shape: Sequence[int], axes: Sequence[Optional[str]],
@@ -300,46 +283,24 @@ def shard_mesh(n_shards: int) -> Mesh:
     if m is None:
         devs = jax.local_devices()
         if n_shards > len(devs):
-            raise ValueError(
-                f"shard_mesh({n_shards}) needs {n_shards} devices, "
-                f"{len(devs)} visible — set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n_shards} "
-                f"before the first jax import for virtual CPU devices")
+            hint = (f"set XLA_FLAGS=--xla_force_host_platform_device_count="
+                    f"{n_shards} before the first jax import for virtual "
+                    f"CPU devices" if devs[0].platform == "cpu" else
+                    f"this host has {len(devs)} {devs[0].platform} "
+                    f"device(s) ({devs[0].device_kind})")
+            raise ValueError(f"shard_mesh({n_shards}) needs {n_shards} "
+                             f"devices, {len(devs)} visible — {hint}")
         m = Mesh(np.asarray(devs[:n_shards]), ("shard",))
         _SHARD_MESHES[n_shards] = m
     return m
-
-
-def shard_map_compat(**kw):
-    """Decorator factory over jax.shard_map that also runs on older jax
-    releases, where shard_map lives in jax.experimental.shard_map and takes
-    ``check_rep`` / ``auto`` instead of ``check_vma`` / ``axis_names``."""
-    import functools
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        if "axis_names" in kw:
-            manual = set(kw.pop("axis_names"))
-            kw["auto"] = frozenset(kw["mesh"].axis_names) - manual
-    return functools.partial(sm, **kw)
 
 
 def manual_axes() -> Tuple[str, ...]:
     """Mesh axes already in Manual mode (i.e. we are inside a shard_map).
     Nested full-manual shard_maps over a mismatched mesh are rejected by
     JAX, so callers fall back to plain jnp in that case."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return tuple(n for n, t in zip(m.axis_names, m.axis_types)
-                         if "Manual" in str(t))
-    except Exception:
-        pass
-    try:
-        # jax 0.4.x: shard_map binds its manual axes in the core axis env.
-        from jax._src import core as _core
-        return tuple(_core.get_axis_env().axis_sizes)
-    except Exception:
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.axis_names:
         return ()
+    return tuple(n for n, t in zip(m.axis_names, m.axis_types)
+                 if t == jax.sharding.AxisType.Manual)

@@ -157,22 +157,20 @@ class CircuitTrainer:
         }
 
     def _peak_memory_bytes(self) -> int:
-        """Peak device memory, backend-guarded: real accelerators report
-        ``peak_bytes_in_use`` via ``device.memory_stats()``; CPU/interpret
-        backends return None there, so the gauge degrades to a live-buffer
-        estimate (Σ nbytes over ``jax.live_arrays()``) instead of crashing.
+        """Peak device memory: an accelerator's ``peak_bytes_in_use`` from
+        ``device.memory_stats()``, and an accelerator that does not report
+        it raises.  Only the CPU backend, which has no memory stats, falls
+        back to a live-buffer estimate (Σ nbytes over ``jax.live_arrays()``).
         The deterministic compiled-peak measure (``memory_analysis()``)
         lives in benchmarks/bench_backbone.py."""
-        try:
-            ms = jax.devices()[0].memory_stats()
-            if ms and "peak_bytes_in_use" in ms:
-                return int(ms["peak_bytes_in_use"])
-        except Exception:
-            pass
-        try:
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
             return int(sum(x.nbytes for x in jax.live_arrays()))
-        except Exception:
-            return 0
+        ms = dev.memory_stats()
+        if not ms or "peak_bytes_in_use" not in ms:
+            raise RuntimeError(f"{dev.device_kind} reports no "
+                               f"peak_bytes_in_use in memory_stats()")
+        return int(ms["peak_bytes_in_use"])
 
     def _recompute_ms(self, fwd_fn, args) -> float:
         """Per-step recompute-cost estimate under remat: the backward

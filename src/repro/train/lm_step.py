@@ -65,17 +65,7 @@ def make_train_step(lm: LM, *, lr: float = 3e-4, total_steps: int = 10_000,
 
     def train_step(state: TrainState, batch: Dict) -> Tuple[TrainState, Dict]:
         mesh = get_mesh()
-        if compress_pod_grads and not hasattr(jax, "shard_map"):
-            # Older jax: the partial-manual (axis_names) shard_map this path
-            # needs is emulated via experimental shard_map's `auto`, whose
-            # XLA lowering hits a hard CHECK (hlo_sharding_util manual
-            # subgroup) — fall back to exact gradients.
-            import warnings
-            warnings.warn("compress_pod_grads requires jax.shard_map "
-                          "(partial-manual); falling back to exact "
-                          "gradient all-reduce")
-            loss, grads = value_and_grads(state.params, batch)
-        elif (compress_pod_grads and mesh is not None
+        if (compress_pod_grads and mesh is not None
                 and "pod" in mesh.axis_names and mesh.shape["pod"] > 1):
             # pod-local grads; explicit int8-compressed all-reduce on the
             # slow inter-pod links.  data/model axes stay auto-sharded.
@@ -83,9 +73,7 @@ def make_train_step(lm: LM, *, lr: float = 3e-4, total_steps: int = 10_000,
             from repro.optim.compression import int8_allreduce_sum
             n_pod = mesh.shape["pod"]
 
-            from repro.sharding.specs import shard_map_compat
-
-            @shard_map_compat(
+            @jax.shard_map(
                 mesh=mesh, axis_names={"pod"},
                 in_specs=(P(), P("pod")), out_specs=(P(), P()),
                 check_vma=False)

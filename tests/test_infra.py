@@ -180,10 +180,11 @@ def test_pipeline_tokens_in_range():
 
 def test_make_pspec_divisibility_fallback():
     from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.sharding.specs import make_pspec
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     # size-1 axis: everything shards trivially
     assert make_pspec((16, 7), ("mlp", "vocab"), mesh) == P("model", "model")
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh1 = make_mesh((1,), ("data",))
     spec = make_pspec((16, 7), ("mlp", None), mesh1)
     assert spec == P(None, None)     # 'model' absent from mesh => replicated

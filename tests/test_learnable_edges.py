@@ -210,8 +210,9 @@ def test_fused_eid_packing_roundtrip(args):
     canon = np.argsort(dst, kind="stable")
     a_ref = np.zeros((n_dst, n_src), np.float32)
     np.add.at(a_ref, (dst[canon], src[canon]), w)
-    for f, shape in ((ff, (n_dst, n_src)), (fb, (n_src, n_dst))):
-        a = np.zeros(shape, np.float32)
+    for f, transposed in ((ff, False), (fb, True)):
+        a = np.zeros(a_ref.T.shape if transposed else a_ref.shape,
+                     np.float32)
         eid = np.asarray(f.eid)
         rows = np.asarray(f.rows)
         blk = np.asarray(f.block_of)
@@ -222,7 +223,8 @@ def test_fused_eid_packing_roundtrip(args):
                 sl = eid[ci, b]
                 m = sl >= 0
                 np.add.at(a[rid], np.asarray(f.nbr)[ci, b][m], w[sl[m]])
-        ref = a_ref if shape == (n_dst, n_src) else a_ref.T
+        # (chosen by direction, not shape: a square A has equal shapes)
+        ref = a_ref.T if transposed else a_ref
         np.testing.assert_allclose(a, ref, atol=1e-6)
     assert ff.nnz == fb.nnz == nnz == dst.shape[0]
 

@@ -10,11 +10,12 @@ SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 assert jax.device_count() == 8
 from repro.configs.base import get_config, reduced
+from repro.launch.mesh import make_mesh
 from repro.models.lm.model import build_lm
 from repro.sharding.specs import mesh_context
 from repro.train import lm_step
 
-mesh_mp = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_mp = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = reduced(get_config("qwen3-0.6b"))
 lm = build_lm(cfg, tp=2)
 batch = {"tokens": jnp.zeros((8, 32), jnp.int32),
@@ -58,7 +59,7 @@ from repro.checkpoint import save_checkpoint, restore_checkpoint
 tmp = tempfile.mkdtemp()
 with mesh_context(mesh_mp), mesh_mp:
     save_checkpoint(tmp, 0, state)
-mesh_small = jax.make_mesh((4, 2), ("data", "model"))
+mesh_small = make_mesh((4, 2), ("data", "model"))
 lm2 = build_lm(cfg, tp=2)
 with mesh_context(mesh_small), mesh_small:
     shardings = lm_step.train_state_shardings(lm2, mesh_small)

@@ -279,8 +279,11 @@ t = threading.Thread(target=eng.serve_forever)
 t.start()
 # phase 1: ONE batch in flight — it eats all 3 scheduled dispatch faults
 # (retry x2 exhausts the budget, then bisect); a wider burst would let a
-# second concurrent batch share the fault schedule and dodge the bisect
-rids = [eng.submit(graph(50, 25, s)) for s in range(2)]
+# second concurrent batch share the fault schedule and dodge the bisect.
+# Both graphs are built before either is submitted, so they arrive inside
+# one max_wait_ms window and flush as that one batch.
+burst = [graph(50, 25, s) for s in range(2)]
+rids = [eng.submit(g) for g in burst]
 for rid in rids:
     eng.result(rid, timeout=600.0)
 # phase 2: paced singles — each waits out max_wait_ms => deadline flushes

@@ -11,9 +11,10 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.sharding.pipeline import pipeline_apply, sequential_reference
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 S, M, MB, D = 4, 6, 2, 16
 rng = np.random.default_rng(0)
 params = {"w": jnp.asarray(rng.normal(size=(S, D, D)).astype(np.float32) * 0.3),
