@@ -69,9 +69,9 @@ DEFAULT_BACKEND: Backend = (
 # "xla:bucket_*", so a downgrade off the fused path shows.  This is how
 # tests and bench smoke assert the one-dispatch-per-direction-group
 # property for the xla family, where jaxpr ``pallas_call`` counting has
-# nothing to count.  Bounded: a
-# long-lived serve loop retraces per (bucket, device) compile and eviction
-# return, and nothing outside tests ever drains the log.
+# nothing to count.  Bounded: a long-lived serve loop retraces per (bucket,
+# device) compile and eviction return, and nothing outside tests ever drains
+# the log.
 FUSED_DISPATCH_LOG: "deque[str]" = deque(maxlen=4096)
 
 

@@ -236,7 +236,6 @@ def decode_attention(q, k_cache, v_cache, pos, new_k, new_v):
     q_spec = P(bspec, None, None, None)
     new_spec = P(bspec, None, None, None)
 
-
     @jax.shard_map(
         mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, P(), new_spec, new_spec),

@@ -30,8 +30,6 @@ from repro.sharding.specs import (batch_axes, constrain, get_mesh,
                                   manual_axes)
 
 
-
-
 def swiglu_ffn(x, w_gate, w_up, w_down, drelu_k: int = 0,
                drelu_groups: int = 1):
     """(B,S,d) -> (B,S,d).  ``drelu_k`` > 0 sparsifies the hidden row-wise
@@ -209,8 +207,8 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, *, n_experts: int,
     w_spec = P("model", None, None)
 
     @jax.shard_map(mesh=mesh,
-                in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
-                out_specs=x_spec, check_vma=False)
+                   in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
+                   out_specs=x_spec, check_vma=False)
     def shmap_moe(x_l, rw, wg_l, wu_l, wd_l):
         shard = jax.lax.axis_index("model")
         # recover the full sequence on each model shard (SP boundary gather)

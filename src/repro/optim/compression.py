@@ -52,9 +52,8 @@ def compressed_pod_psum(grads: Any, mesh) -> Any:
     def one(g):
         spec = P(*([None] * g.ndim))
 
-
-        @jax.shard_map(mesh=mesh,
-                          in_specs=spec, out_specs=spec, check_vma=False)
+        @jax.shard_map(mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False)
         def ar(g_l):
             return int8_allreduce_sum(g_l, "pod") / n_pod
 

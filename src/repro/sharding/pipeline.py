@@ -50,7 +50,6 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params,
 
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
-
     @jax.shard_map(
         mesh=mesh, axis_names={axis},
         in_specs=(p_spec, P()), out_specs=P(), check_vma=False)
