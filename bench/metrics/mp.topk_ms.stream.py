@@ -1,0 +1,6 @@
+"""``mp.topk_ms``, read in the stream cell, where it moves
+``stream_step_ms``."""
+
+import readers
+
+read = readers.load("mp.topk_ms").read

@@ -1,0 +1,6 @@
+"""``step.mfu``, read in the stream cell, where it moves
+``stream_step_ms``."""
+
+import readers
+
+read = readers.load("step.mfu").read
