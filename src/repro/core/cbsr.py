@@ -62,10 +62,11 @@ def cbsr_from_dense(x: jax.Array, k: int) -> CBSR:
     """
     n, d = x.shape
     k = min(k, d)
-    vals, idx = jax.lax.top_k(x, k)  # descending by value
-    order = jnp.argsort(idx, axis=1)
-    idx = jnp.take_along_axis(idx, order, axis=1).astype(jnp.int32)
-    vals = jnp.take_along_axis(vals, order, axis=1)
+    with jax.named_scope("cbsr"):
+        vals, idx = jax.lax.top_k(x, k)  # descending by value
+        order = jnp.argsort(idx, axis=1)
+        idx = jnp.take_along_axis(idx, order, axis=1).astype(jnp.int32)
+        vals = jnp.take_along_axis(vals, order, axis=1)
     return CBSR(values=vals, idx=idx, dim=d)
 
 

@@ -62,7 +62,8 @@ def drelu(x: jax.Array, k: int) -> jax.Array:
     """Dense D-ReLU: keep the top-``k`` entries of each row, zero the rest."""
     if k >= x.shape[-1]:
         return x
-    return _drelu_dense(x, k)
+    with jax.named_scope("drelu"):
+        return _drelu_dense(x, k)
 
 
 def drelu_grouped(x: jax.Array, k: int, groups: int) -> jax.Array:

@@ -180,13 +180,15 @@ def _plan_for(graph: CircuitGraph, cfg: HeteroMPConfig,
 def _merge(params: HeteroLayerParams, x_cell: jax.Array,
            agg_near: jax.Array, agg_pinned: jax.Array,
            agg_pin: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    # --- per-edge W^ψ (Eq. 4) ---
-    near_out = agg_near @ params.w_near + x_cell @ params.w_near_self
-    pinned_out = agg_pinned @ params.w_pinned + x_cell @ params.w_pinned_self
-    pin_out = agg_pin @ params.w_pin
-    # --- merge (Eqs. 8-9); Eqs. 12-14 are the autodiff of the max ---
-    y_cell = jnp.maximum(near_out, pinned_out) + params.b_cell
-    y_net = pin_out + params.b_net
+    with jax.named_scope("merge"):
+        # --- per-edge W^ψ (Eq. 4) ---
+        near_out = agg_near @ params.w_near + x_cell @ params.w_near_self
+        pinned_out = (agg_pinned @ params.w_pinned
+                      + x_cell @ params.w_pinned_self)
+        pin_out = agg_pin @ params.w_pin
+        # --- merge (Eqs. 8-9); Eqs. 12-14 are the autodiff of the max ---
+        y_cell = jnp.maximum(near_out, pinned_out) + params.b_cell
+        y_net = pin_out + params.b_net
     return y_cell, y_net
 
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.graphs.ell import (BucketedELL, RelationPlan, build_relation_plan,
                               degree_stats, ell_to_coo, pack_ell_pair)
+from repro.obs import span
 from repro.sharding.plan_shard import (ShardedRelationPlan,
                                        shard_relation_plan)
 
@@ -81,16 +82,17 @@ def relation_plan_of(graph: CircuitGraph,
     hit = _PLAN_CACHE.get(key)
     if hit is not None and hit[0]() is graph:
         return hit[1]
-    rels = []
-    for et in EDGE_TYPES:
-        if et not in graph.edges:
-            continue
-        s_t, d_t = EDGE_SCHEMA[et]
-        dst, src, w = ell_to_coo(graph.edges[et].adj)
-        rels.append((et, s_t, d_t, dst, src, w))
-    plan = build_relation_plan(
-        rels, {"cell": graph.n_cell, "net": graph.n_net},
-        dense_threshold=dense_threshold)
+    with span("graph.relation_plan"):
+        rels = []
+        for et in EDGE_TYPES:
+            if et not in graph.edges:
+                continue
+            s_t, d_t = EDGE_SCHEMA[et]
+            dst, src, w = ell_to_coo(graph.edges[et].adj)
+            rels.append((et, s_t, d_t, dst, src, w))
+        plan = build_relation_plan(
+            rels, {"cell": graph.n_cell, "net": graph.n_net},
+            dense_threshold=dense_threshold)
     _PLAN_CACHE[key] = (
         weakref.ref(graph, lambda _: _PLAN_CACHE.pop(key, None)), plan)
     return plan

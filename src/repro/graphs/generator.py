@@ -24,6 +24,7 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from repro.graphs.circuit import CircuitGraph, build_circuit_graph, graph_degree_stats
+from repro.obs import span
 
 # Table 1 anchor statistics (per-partition node counts for the three designs).
 TABLE1 = {
@@ -133,7 +134,10 @@ def generate_design(seed: int, size: str = "small", scale: float = 1.0,
 def pack_graph_parallel(coo, n_cell, n_net, xc, xn, y, n_threads: int = 3
                         ) -> CircuitGraph:
     """Pack the three subgraphs concurrently (paper Sec. 3.4: per-subgraph
-    CPU init threads).  Falls back to serial when n_threads == 1."""
+    CPU init threads).  Falls back to serial when n_threads == 1.
+
+    Spans (DESIGN.md §11): ``graph.pack_ell`` on the calling thread around
+    the pool, ``graph.pack_relation`` (``etype=``) in each worker."""
     if n_threads <= 1:
         return build_circuit_graph(coo, n_cell, n_net, xc, xn, y)
     from repro.graphs.circuit import EDGE_SCHEMA, EdgeSet
@@ -143,15 +147,17 @@ def pack_graph_parallel(coo, n_cell, n_net, xc, xn, y, n_threads: int = 3
     sizes = {"cell": n_cell, "net": n_net}
 
     def pack_one(et):
-        dst, src = coo[et]
-        s_t, d_t = EDGE_SCHEMA[et]
-        n_dst, n_src = sizes[d_t], sizes[s_t]
-        deg = _np.bincount(dst, minlength=n_dst).astype(_np.float32)
-        w = 1.0 / _np.maximum(deg[dst], 1.0)
-        adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
+        with span("graph.pack_relation", etype=et):
+            dst, src = coo[et]
+            s_t, d_t = EDGE_SCHEMA[et]
+            n_dst, n_src = sizes[d_t], sizes[s_t]
+            deg = _np.bincount(dst, minlength=n_dst).astype(_np.float32)
+            w = 1.0 / _np.maximum(deg[dst], 1.0)
+            adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
         return et, EdgeSet(adj=adj, adj_t=adj_t)
 
-    with ThreadPoolExecutor(max_workers=n_threads) as pool:
+    with span("graph.pack_ell"), \
+            ThreadPoolExecutor(max_workers=n_threads) as pool:
         edges = dict(pool.map(pack_one, list(coo)))
     import jax.numpy as jnp
     return CircuitGraph(n_cell=n_cell, n_net=n_net, edges=edges,

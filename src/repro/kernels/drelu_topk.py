@@ -74,6 +74,7 @@ def drelu_pallas(x: jax.Array, k: int, *, block_rows: int = ROW_BLOCK,
     out = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_drelu_kernel, k=k),
         grid=((n + pad) // br,),
+        name="drelu_topk",
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, d), x.dtype),

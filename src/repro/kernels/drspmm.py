@@ -225,6 +225,7 @@ def _arena_fwd(block_of, start, nbr, w, x_vals, x_idx, dim: int,
     return run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_fwd_kernel, k=k, ec=ec, d_tile=dt),
         grid_spec=grid_spec,
+        name="drspmm_arena_fwd",
         # fp32 accumulator arena regardless of input dtype (chunk revisits
         # accumulate in the out buffer); the op wrapper casts after gather.
         out_shape=jax.ShapeDtypeStruct((n_rows, dim), jnp.float32),
@@ -269,6 +270,7 @@ def _arena_bwd(block_of, start, tnbr, tw, gy, xi_rows, n_rows: int,
     return run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_bwd_kernel, k=k, ec=ec),
         grid_spec=grid_spec,
+        name="drspmm_arena_bwd",
         out_shape=jax.ShapeDtypeStruct((n_rows, k), jnp.float32),
         interpret=interp,
     )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, gyp,
@@ -307,6 +309,7 @@ def _arena_spmm(block_of, start, nbr, w, x, n_rows: int, interpret):
     return run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_spmm_kernel, ec=ec),
         grid_spec=grid_spec,
+        name="spmm_arena",
         out_shape=jax.ShapeDtypeStruct((n_rows, d), jnp.float32),
         interpret=interp,
     )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, xp), interpret)
@@ -499,6 +502,7 @@ def drspmm_dense_tier_fwd(a_dense: jax.Array, x_vals: jax.Array,
     y = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_dense_tier_fwd_kernel, k=k, d_tile=dt),
         grid=(ndt, mp // rb, npad // nc),
+        name="drspmm_dense_fwd",
         in_specs=[
             pl.BlockSpec((rb, nc), lambda d, i, j: (i, j)),
             pl.BlockSpec((nc, k), lambda d, i, j: (j, 0)),
@@ -550,6 +554,7 @@ def drspmm_dense_tier_bwd(a_dense_t: jax.Array, gy: jax.Array,
     dv = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_dense_tier_bwd_kernel, k=k),
         grid=(npad // rb, mpad // mc),
+        name="drspmm_dense_bwd",
         in_specs=[
             pl.BlockSpec((rb, mc), lambda i, j: (i, j)),
             pl.BlockSpec((mc, d), lambda i, j: (j, 0)),
@@ -665,6 +670,7 @@ def drspmm_dw_learnable_fused(fused: FusedELL, gy_arena: jax.Array,
     out = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_dw_kernel, k=k, ec=ec),
         grid_spec=grid_spec,
+        name="drspmm_arena_dw",
         out_shape=jax.ShapeDtypeStruct((c, 1, br * ec), jnp.float32),
         interpret=interp,
     )(jnp.asarray(fused.block_of), ids, rows, gy_arena), interpret)

@@ -109,7 +109,8 @@ def drcircuitgnn_forward(params: DRCircuitGNNParams, graph: CircuitGraph,
 def loss_fn(params, graph, cfg,
             spec: Optional[BackboneSpec] = None) -> jax.Array:
     pred = drcircuitgnn_forward(params, graph, cfg, spec)
-    return jnp.mean((pred - graph.y_cell) ** 2)
+    with jax.named_scope("loss"):
+        return jnp.mean((pred - graph.y_cell) ** 2)
 
 
 def batched_loss_fn(params, graph, cell_weight, cfg,
@@ -120,7 +121,8 @@ def batched_loss_fn(params, graph, cell_weight, cfg,
     padding, so this equals the mean of the members' per-graph ``loss_fn``
     values — batched gradients match the per-graph loop exactly."""
     pred = drcircuitgnn_forward(params, graph, cfg, spec)
-    return jnp.sum(cell_weight * (pred - graph.y_cell) ** 2)
+    with jax.named_scope("loss"):
+        return jnp.sum(cell_weight * (pred - graph.y_cell) ** 2)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
-"""Observability: request tracing, metrics registry, profiling hooks.
+"""Observability: request tracing, metrics registry, program spans on the
+profiler's clock (:func:`span`).
 
-Zero-dependency.  See DESIGN.md §11 for the trace model and metric naming
-scheme.  Quickstart::
+No dependency beyond ``jax.profiler``.  See DESIGN.md §11 for the trace
+model and metric naming scheme.  Quickstart::
 
     from repro.obs import TraceRecorder, MetricsRegistry
 
@@ -23,6 +24,7 @@ from repro.obs.trace import (
     Recorder,
     TraceRecorder,
     NULL_RECORDER,
+    span,
 )
 
 __all__ = [
@@ -35,4 +37,5 @@ __all__ = [
     "Recorder",
     "TraceRecorder",
     "NULL_RECORDER",
+    "span",
 ]

@@ -1,4 +1,15 @@
-"""Structured request tracing with Chrome trace-event export.
+"""Structured request tracing with Chrome trace-event export, and the
+program's spans on the profiler's clock.
+
+:func:`span` is the one entry point for a named stretch of program work
+(``train.dispatch``, ``graph.pack_ell``, ...).  It always opens a
+``jax.profiler.TraceAnnotation`` (a TraceMe): inactive it records nothing;
+while a profiler session runs the span lands in the same ``.xplane.pb``
+as the device ops, on their clock.  With an enabled recorder the same
+span also goes to it (B/E pair, own clock), and while a profiler session
+runs its host milliseconds are summed into the ``trace.span_ms{span=}``
+histogram of :data:`~repro.obs.metrics.DEFAULT_REGISTRY`.  With neither,
+the cost is the TraceMe's inactive check.
 
 One :class:`Recorder` interface, two implementations:
 
@@ -43,6 +54,10 @@ import json
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+from repro.obs.metrics import DEFAULT_REGISTRY
 
 DEFAULT_MAX_EVENTS = 65536
 
@@ -227,3 +242,45 @@ class TraceRecorder(Recorder):
     def __len__(self) -> int:
         with self._lock:
             return len(self._events)
+
+
+class _ProgramSpan:
+    """A TraceMe, the recorder's span, and the host time summed into
+    ``trace.span_ms`` while a profiler session runs."""
+
+    __slots__ = ("_ann", "_rspan", "_name", "_t0")
+
+    def __init__(self, ann, rspan, name: str):
+        self._ann, self._rspan, self._name = ann, rspan, name
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._rspan.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        ms = (time.perf_counter() - self._t0) * 1e3
+        if TraceAnnotation.is_enabled():
+            DEFAULT_REGISTRY.histogram("trace.span_ms",
+                                       span=self._name).observe(ms)
+        self._rspan.__exit__(*exc)
+        return self._ann.__exit__(*exc)
+
+
+def span(name: str, rec: Recorder = NULL_RECORDER, *,
+         step: Optional[int] = None, **args):
+    """``with obs.span("train.sync", rec): ...`` — the program's span.
+
+    ``step`` makes it the profiler's step marker
+    (``StepTraceAnnotation(name, step_num=step)``: the trace's ``Steps``
+    line).  On the recorder the span's track is the name's first dotted
+    part (``train``, ``graph``); ``args`` go to both."""
+    if step is None:
+        ann = TraceAnnotation(name, **args)
+    else:
+        ann = StepTraceAnnotation(name, step_num=step, **args)
+    if not (rec.enabled or TraceAnnotation.is_enabled()):
+        return ann
+    return _ProgramSpan(ann, rec.span(name.partition(".")[0], name, **args),
+                        name)

@@ -26,6 +26,7 @@ def adamw_init(params) -> AdamWState:
                       v=jax.tree.map(zeros, params))
 
 
+@jax.named_scope("adamw")
 def adamw_update(params, grads, state: AdamWState, lr,
                  b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
                  weight_decay: float = 0.0, grad_clip: float = 0.0):

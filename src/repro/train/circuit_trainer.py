@@ -25,6 +25,7 @@ from repro.models.backbone import BackboneSpec
 from repro.models.hgnn import (DRCircuitGNNParams, batched_loss_fn,
                                drcircuitgnn_forward, init_drcircuitgnn,
                                loss_fn)
+from repro.obs import span
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_RECORDER, Recorder
 from repro.optim import adamw_init, adamw_update, constant
@@ -112,7 +113,6 @@ class CircuitTrainer:
         self._batched_step_fn = self._build_batched_step()
         self._grad_fn = self._build_grad()
         self._apply_fn = self._build_apply()
-        self._fwd_fn, self._batched_fwd_fn = self._build_fwd_losses()
         self._batch_cache = {}        # id-tuple of member graphs -> device batch
         self._plan_cache = {}         # id(graph) -> plan-attached graph
         # Robustness (DESIGN.md §10): the chaos harness (fault/inject.py)
@@ -131,13 +131,9 @@ class CircuitTrainer:
             self.chaos.recorder = self._rec
         self._c_steps = self.metrics.counter("train.steps")
         self._c_nonfinite = self.metrics.counter("train.nonfinite_grad_steps")
+        # plan (or collated batch) cache misses: one device upload each
+        self._c_plan_uploads = self.metrics.counter("train.plan_uploads")
         self._h_step_ms = self.metrics.histogram("train.step_ms")
-        # Deep-backbone memory accounting (§11 gauges, backend-guarded —
-        # see _peak_memory_bytes / _recompute_ms): peak device bytes after
-        # each step, and the per-step recompute-cost estimate remat pays.
-        self._g_peak = self.metrics.gauge("train.peak_memory_bytes")
-        self._g_recompute = self.metrics.gauge("train.recompute_ms")
-        self._fwd_time_cache = {}     # id(step input) -> (pin, est_ms)
         self._global_step = 0
 
     @property
@@ -146,14 +142,16 @@ class CircuitTrainer:
         return int(self._c_nonfinite.value)
 
     def stats(self) -> Dict[str, float]:
-        """Registry-backed trainer counters + step-time percentiles."""
+        """Registry-backed trainer counters + step-time percentiles, and
+        the device's peak memory, read here (a high-water mark: reading
+        it once gives what reading it every step would)."""
         p50, p95, p99 = self._h_step_ms.percentiles((0.50, 0.95, 0.99))
         return {
             "steps": int(self._c_steps.value),
             "nonfinite_grad_steps": int(self._c_nonfinite.value),
+            "plan_uploads": int(self._c_plan_uploads.value),
             "step_p50_ms": p50, "step_p95_ms": p95, "step_p99_ms": p99,
-            "peak_memory_bytes": int(self._g_peak.value),
-            "recompute_ms": float(self._g_recompute.value),
+            "peak_memory_bytes": self._peak_memory_bytes(),
         }
 
     def _peak_memory_bytes(self) -> int:
@@ -172,43 +170,36 @@ class CircuitTrainer:
                                f"peak_bytes_in_use in memory_stats()")
         return int(ms["peak_bytes_in_use"])
 
-    def _recompute_ms(self, fwd_fn, args) -> float:
-        """Per-step recompute-cost estimate under remat: the backward
-        re-runs each checkpointed layer's forward exactly once, so the
-        extra work per step ≈ one forward pass — measured on the jitted
-        forward loss once per step input (id-cached, pinned like
-        _plan_cache) and emitted as the ``train.recompute_ms`` gauge.
-        0.0 with remat off."""
-        if not self.cfg.remat:
-            return 0.0
-        key = id(args[0])
-        hit = self._fwd_time_cache.get(key)
-        if hit is not None and hit[0] is args[0]:
-            return hit[1]
-        fwd_fn(self.params, *args).block_until_ready()   # compile warm-up
-        t0 = time.perf_counter()
-        fwd_fn(self.params, *args).block_until_ready()
-        est = (time.perf_counter() - t0) * 1e3
-        self._fwd_time_cache[key] = (args[0], est)
-        return est
-
-    def _tick(self, duration_s: float, recompute_ms: float = 0.0) -> None:
+    def _tick(self, duration_s: float) -> None:
         """Feed one step's wall-clock to the StepMonitor (host 0 — the
         single-process trainer; multi-host callers own their monitor) and
-        refresh the §11 memory/recompute gauges."""
+        count the step.  No device query: the step's host work stays
+        off the device's critical path."""
         self.monitor.record(self._global_step, 0, duration_s)
         self._global_step += 1
         self._c_steps.inc()
         self._h_step_ms.observe(duration_s * 1e3)
-        self._g_peak.set(self._peak_memory_bytes())
-        self._g_recompute.set(recompute_ms)
+
+    def _close_step(self, t_step: float, ok: bool, loss):
+        """The step's bookkeeping (``train.bookkeeping``): tick, count a
+        skipped non-finite step, read the loss.  Returns the loss as a
+        float, or None for a skipped step (a true no-op)."""
+        with span("train.bookkeeping", self._rec):
+            self._tick(time.perf_counter() - t_step)
+            if ok:
+                return float(loss)
+            self._c_nonfinite.inc()
+            if self._rec.enabled:
+                self._rec.instant("train", "nonfinite_grads_skip",
+                                  step=self._global_step)
+            return None
 
     def _build_step(self):
         mp_cfg, lr, wd = self.mp_cfg, self.lr, self.cfg.weight_decay
         spec = self.spec
 
         @jax.jit
-        def step(params, opt_state, graph: CircuitGraph):
+        def train_step(params, opt_state, graph: CircuitGraph):
             loss, grads = jax.value_and_grad(loss_fn)(params, graph, mp_cfg,
                                                       spec)
             ok = _grads_finite(grads)
@@ -218,14 +209,15 @@ class CircuitTrainer:
             return (_where_tree(ok, new_p, params),
                     _where_tree(ok, new_o, opt_state), loss, ok)
 
-        return step
+        return train_step
 
     def _build_batched_step(self):
         mp_cfg, lr, wd = self.mp_cfg, self.lr, self.cfg.weight_decay
         spec = self.spec
 
         @jax.jit
-        def step(params, opt_state, graph: CircuitGraph, cell_w):
+        def train_step_batched(params, opt_state, graph: CircuitGraph,
+                               cell_w):
             loss, grads = jax.value_and_grad(batched_loss_fn)(
                 params, graph, cell_w, mp_cfg, spec)
             ok = _grads_finite(grads)
@@ -235,7 +227,7 @@ class CircuitTrainer:
             return (_where_tree(ok, new_p, params),
                     _where_tree(ok, new_o, opt_state), loss, ok)
 
-        return step
+        return train_step_batched
 
     def _build_grad(self):
         """Loss+grad over one collated shard — the per-device half of a
@@ -244,30 +236,21 @@ class CircuitTrainer:
         mp_cfg, spec = self.mp_cfg, self.spec
 
         @jax.jit
-        def gfn(params, graph: CircuitGraph, cell_w):
+        def dp_grad(params, graph: CircuitGraph, cell_w):
             return jax.value_and_grad(batched_loss_fn)(params, graph,
                                                        cell_w, mp_cfg, spec)
 
-        return gfn
-
-    def _build_fwd_losses(self):
-        """Jitted forward-only losses — the measurement probes behind the
-        ``train.recompute_ms`` gauge (one forward ≈ the extra work a remat
-        backward pays per step)."""
-        mp_cfg, spec = self.mp_cfg, self.spec
-        f = jax.jit(lambda p, g: loss_fn(p, g, mp_cfg, spec))
-        fb = jax.jit(lambda p, g, w: batched_loss_fn(p, g, w, mp_cfg, spec))
-        return f, fb
+        return dp_grad
 
     def _build_apply(self):
         lr, wd = self.lr, self.cfg.weight_decay
 
         @jax.jit
-        def apply(params, opt_state, grads):
+        def dp_apply(params, opt_state, grads):
             return adamw_update(params, grads, opt_state,
                                 lr(opt_state.step), weight_decay=wd)
 
-        return apply
+        return dp_apply
 
     def _dp_step(self, graphs: List[CircuitGraph], ring: DeviceRing):
         """One data-parallel optimizer step over ``graphs``: members are
@@ -282,8 +265,9 @@ class CircuitTrainer:
         shards = [graphs[d::n_dev] for d in range(n_dev)]
         outs, weights = [], []
         for d, shard in enumerate(shards):
-            graph, cell_w, n_real = self._collate(shard,
-                                                  device=ring.devices[d])
+            with span("train.plan", self._rec):
+                graph, cell_w, n_real = self._collate(
+                    shard, device=ring.devices[d])
             p_d = jax.device_put(self.params, ring.devices[d])
             outs.append(self._grad_fn(p_d, graph, cell_w))   # async, dev d
             weights.append(n_real)
@@ -325,13 +309,13 @@ class CircuitTrainer:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.graphs.circuit import sharded_plan_of
             from repro.sharding.specs import shard_mesh
-            sp = sharded_plan_of(g, self.cfg.n_shards)
-            mesh = shard_mesh(self.cfg.n_shards)
-            pg = dataclasses.replace(g, plan=jax.device_put(
-                sp, NamedSharding(mesh, P("shard"))))
+            plan = sharded_plan_of(g, self.cfg.n_shards)
+            where = NamedSharding(shard_mesh(self.cfg.n_shards), P("shard"))
         else:
-            pg = dataclasses.replace(
-                g, plan=jax.device_put(relation_plan_of(g)))
+            plan, where = relation_plan_of(g), None
+        with span("train.plan_upload", self._rec):
+            pg = dataclasses.replace(g, plan=jax.device_put(plan, where))
+        self._c_plan_uploads.inc()
         self._plan_cache[key] = (g, pg)
         return pg
 
@@ -348,8 +332,10 @@ class CircuitTrainer:
         if hit is not None and all(a is b for a, b in zip(hit[0], graphs)):
             return hit[1]
         batch = collate_graphs(graphs)
-        entry = (jax.device_put(batch.graph, device),
-                 jax.device_put(batch.cell_weight, device), batch.n_real)
+        with span("train.plan_upload", self._rec):
+            entry = (jax.device_put(batch.graph, device),
+                     jax.device_put(batch.cell_weight, device), batch.n_real)
+        self._c_plan_uploads.inc()
         self._batch_cache[key] = (tuple(graphs), entry)
         return entry
 
@@ -366,25 +352,24 @@ class CircuitTrainer:
         one update — the serve engine's multi-device dispatch reused for
         training (same math as the single-device batched step)."""
         b = self.cfg.batch_size if batch_size is None else batch_size
+        rec = self._rec
         if b <= 1:
             losses = []
             for g in graphs:
-                if self.chaos is not None:
-                    self.chaos.stall("straggler")
-                pg = self._planned(g)
-                t_step = time.perf_counter()
-                self.params, self.opt_state, loss, ok = self._step_fn(
-                    self.params, self.opt_state, pg)
-                ok = bool(ok)                  # device barrier ends the step
-                self._tick(time.perf_counter() - t_step,
-                           self._recompute_ms(self._fwd_fn, (pg,)))
-                if not ok:
-                    self._c_nonfinite.inc()
-                    if self._rec.enabled:
-                        self._rec.instant("train", "nonfinite_grads_skip",
-                                          step=self._global_step)
-                    continue                   # skipped: a true no-op step
-                losses.append(float(loss))
+                with span("train", rec, step=self._global_step):
+                    if self.chaos is not None:
+                        self.chaos.stall("straggler")
+                    with span("train.plan", rec):
+                        pg = self._planned(g)
+                    t_step = time.perf_counter()
+                    with span("train.dispatch", rec):
+                        self.params, self.opt_state, loss, ok = \
+                            self._step_fn(self.params, self.opt_state, pg)
+                    with span("train.sync", rec):
+                        ok = bool(ok)          # device barrier ends the step
+                    loss = self._close_step(t_step, ok, loss)
+                if loss is not None:           # None: skipped, a no-op step
+                    losses.append(loss)
             return float(np.mean(losses)) if losses else float("nan")
         ring = None
         if devices is not None:
@@ -392,29 +377,27 @@ class CircuitTrainer:
         losses, weights = [], []
         for i in range(0, len(graphs), b):
             chunk = graphs[i:i + b]
-            if self.chaos is not None:
-                self.chaos.stall("straggler")
-            t_step = time.perf_counter()
-            recompute = 0.0
-            if ring is not None and len(chunk) > 1:
-                loss, n_real, ok = self._dp_step(chunk, ring)
-            else:
-                graph, cell_w, n_real = self._collate(chunk)
-                self.params, self.opt_state, loss, ok = \
-                    self._batched_step_fn(self.params, self.opt_state,
-                                          graph, cell_w)
-                ok = bool(ok)
-                recompute = self._recompute_ms(self._batched_fwd_fn,
-                                               (graph, cell_w))
-            self._tick(time.perf_counter() - t_step, recompute)
-            if not ok:
-                self._c_nonfinite.inc()
-                if self._rec.enabled:
-                    self._rec.instant("train", "nonfinite_grads_skip",
-                                      step=self._global_step)
-                continue
-            losses.append(float(loss))
-            weights.append(n_real)
+            with span("train", rec, step=self._global_step):
+                if self.chaos is not None:
+                    self.chaos.stall("straggler")
+                t_step = time.perf_counter()
+                if ring is not None and len(chunk) > 1:
+                    with span("train.dispatch", rec):
+                        loss, n_real, ok = self._dp_step(chunk, ring)
+                else:
+                    with span("train.plan", rec):
+                        graph, cell_w, n_real = self._collate(chunk)
+                    with span("train.dispatch", rec):
+                        self.params, self.opt_state, loss, ok = \
+                            self._batched_step_fn(self.params,
+                                                  self.opt_state, graph,
+                                                  cell_w)
+                    with span("train.sync", rec):
+                        ok = bool(ok)
+                loss = self._close_step(t_step, ok, loss)
+            if loss is not None:
+                losses.append(loss)
+                weights.append(n_real)
         return float(np.average(losses, weights=weights)) if losses \
             else float("nan")
 
@@ -441,8 +424,6 @@ class CircuitTrainer:
         self._step_fn = self._build_step()
         self._batched_step_fn = self._build_batched_step()
         self._grad_fn = self._build_grad()
-        self._fwd_fn, self._batched_fwd_fn = self._build_fwd_losses()
-        self._fwd_time_cache.clear()
         return ks
 
     def fit(self, train_graphs: List[CircuitGraph],

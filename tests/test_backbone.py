@@ -71,7 +71,7 @@ def test_remat_parity_deep(graph):
 
 def test_remat_trained_params_parity(graph):
     """Two trainers differing ONLY in remat converge to allclose params
-    (and the gauges report: remat measures recompute, plain reads 0)."""
+    (and both report the device's peak memory)."""
     trained, stats = {}, {}
     for remat in (False, True):
         cfg = CircuitTrainConfig(epochs=2, hidden=32, k_cell=8, k_net=8,
@@ -86,8 +86,6 @@ def test_remat_trained_params_parity(graph):
                     jax.tree.leaves(trained[False])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
-    assert stats[True]["recompute_ms"] > 0.0
-    assert stats[False]["recompute_ms"] == 0.0
     assert stats[True]["peak_memory_bytes"] > 0
 
 
