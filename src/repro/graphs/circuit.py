@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graphs.ell import (BucketedELL, RelationPlan, build_relation_plan,
-                              degree_stats, ell_to_coo, pack_ell_pair)
+                              degree_stats, pack_ell_pair)
 from repro.obs import span
 from repro.sharding.plan_shard import (ShardedRelationPlan,
                                        shard_relation_plan)
@@ -83,15 +83,16 @@ def relation_plan_of(graph: CircuitGraph,
     if hit is not None and hit[0]() is graph:
         return hit[1]
     with span("graph.relation_plan"):
-        rels = []
-        for et in EDGE_TYPES:
-            if et not in graph.edges:
-                continue
-            s_t, d_t = EDGE_SCHEMA[et]
-            dst, src, w = ell_to_coo(graph.edges[et].adj)
-            rels.append((et, s_t, d_t, dst, src, w))
+        ets = [et for et in EDGE_TYPES if et in graph.edges]
+        # The graph's own forward/transposed packings, fetched to the host
+        # in one batched transfer: no COO round trip and no second pack.
+        # The host copies die with this call, and fuse_bucketed's memo
+        # entries for them with them, so only the plan outlives it.
+        packed = jax.device_get(
+            {et: (graph.edges[et].adj, graph.edges[et].adj_t) for et in ets})
         plan = build_relation_plan(
-            rels, {"cell": graph.n_cell, "net": graph.n_net},
+            [(et,) + EDGE_SCHEMA[et] for et in ets],
+            {"cell": graph.n_cell, "net": graph.n_net}, packed=packed,
             dense_threshold=dense_threshold)
     _PLAN_CACHE[key] = (
         weakref.ref(graph, lambda _: _PLAN_CACHE.pop(key, None)), plan)

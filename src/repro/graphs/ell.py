@@ -115,6 +115,23 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _stable_order(keys: np.ndarray) -> np.ndarray:
+    """``np.argsort(keys, kind="stable")`` for non-negative ids, by LSD
+    radix passes over 16-bit digits.  numpy's stable sort of a 16-bit
+    dtype is a counting (radix) sort, linear in ``keys.size``, where the
+    comparison sort of int64 ids is n log n; a stable sort's permutation
+    is unique, so the result is the same."""
+    keys = np.asarray(keys, np.int64)
+    top = int(keys.max()) if keys.size else 0
+    order = np.argsort((keys & 0xFFFF).astype(np.uint16), kind="stable")
+    shift = 16
+    while top >> shift:
+        digit = ((keys[order] >> shift) & 0xFFFF).astype(np.uint16)
+        order = order[np.argsort(digit, kind="stable")]
+        shift += 16
+    return order
+
+
 def pack_ell(dst: np.ndarray, src: np.ndarray, w: np.ndarray | None,
              n_dst: int, n_src: int,
              bounds: Sequence[int] = DEFAULT_BOUNDS,
@@ -126,6 +143,12 @@ def pack_ell(dst: np.ndarray, src: np.ndarray, w: np.ndarray | None,
     dst, src : int arrays (nnz,) — edge endpoints (dst aggregates from src).
     w : float array (nnz,) or None for unit weights.
     bounds : inclusive degree upper bounds for all but the last bucket.
+
+    Each row keeps its edges in their COO order (a stable sort by ``dst``).
+    Every bucket's slab is a window of one flat buffer, which a single
+    scatter fills: an edge lands at its row's start in the buffer (the
+    row's place in its bucket × the bucket's width) plus its slot in the
+    row, ``arange(nnz) − rowptr[dst]``.
     """
     dst = np.asarray(dst, np.int64)
     src = np.asarray(src, np.int64)
@@ -134,7 +157,7 @@ def pack_ell(dst: np.ndarray, src: np.ndarray, w: np.ndarray | None,
     w = np.asarray(w, np.float32)
 
     # CSR-ify (stage 1 of Alg. 1).
-    order = np.argsort(dst, kind="stable")
+    order = _stable_order(dst)
     dst, src, w = dst[order], src[order], w[order]
     deg = np.bincount(dst, minlength=n_dst)
     rowptr = np.zeros(n_dst + 1, np.int64)
@@ -142,10 +165,9 @@ def pack_ell(dst: np.ndarray, src: np.ndarray, w: np.ndarray | None,
 
     # Stage 2: classify rows by degree.  Empty rows are dropped entirely.
     nonempty = np.nonzero(deg > 0)[0]
-    edges_of = lambda r: slice(rowptr[r], rowptr[r + 1])
-
-    buckets = []
-    nnz = 0
+    start = np.zeros(deg.size, np.int64)    # row's first slot in the buffer
+    slabs = []                              # (rows, n_r, width, offset)
+    size = 0
     lo = 1
     bnds = list(bounds) + [int(deg.max()) if deg.size and deg.max() > 0 else 1]
     for hi in bnds:
@@ -157,18 +179,25 @@ def pack_ell(dst: np.ndarray, src: np.ndarray, w: np.ndarray | None,
             continue
         width = int(deg[rows].max())
         n_r = _round_up(rows.size, row_block)
-        nbr = np.zeros((n_r, width), np.int32)
-        wts = np.zeros((n_r, width), np.float32)
+        start[rows] = size + np.arange(rows.size) * width
+        slabs.append((rows, n_r, width, size))
+        size += n_r * width
+
+    nbr = np.zeros(size, np.int32)
+    wts = np.zeros(size, np.float32)
+    at = (start - rowptr[:-1])[dst] + np.arange(dst.size)
+    nbr[at] = src
+    wts[at] = w
+    nnz = int((wts != 0).sum())
+    buckets = []
+    for rows, n_r, width, off in slabs:
         rid = np.zeros(n_r, np.int32)
         rid[: rows.size] = rows
-        for i, r in enumerate(rows):
-            sl = edges_of(r)
-            d = rowptr[r + 1] - rowptr[r]
-            nbr[i, :d] = src[sl]
-            wts[i, :d] = w[sl]
-        nnz += int((wts != 0).sum())
-        buckets.append(ELLBucket(rows=jnp.asarray(rid), nbr=jnp.asarray(nbr),
-                                 w=jnp.asarray(wts)))
+        window = slice(off, off + n_r * width)
+        buckets.append(ELLBucket(
+            rows=jnp.asarray(rid),
+            nbr=jnp.asarray(nbr[window].reshape(n_r, width)),
+            w=jnp.asarray(wts[window].reshape(n_r, width))))
     if not buckets:  # empty matrix — keep one inert bucket for shape sanity
         buckets = [ELLBucket(rows=jnp.zeros((row_block,), jnp.int32),
                              nbr=jnp.zeros((row_block, 1), jnp.int32),
@@ -194,7 +223,7 @@ def pack_eid_slabs(dst: np.ndarray, src: np.ndarray, n_dst: int, n_src: int,
     src = np.asarray(src, np.int64)
     nnz = dst.shape[0]
     assert nnz < (1 << 24), "edge ids exceed f32 exact-integer range"
-    order = np.argsort(dst, kind="stable")           # pack_ell's canonical
+    order = _stable_order(dst)                       # pack_ell's canonical
     eid = np.empty(nnz, np.int64)
     eid[order] = np.arange(nnz)                      # caller-order -> canon
     fwd = pack_ell(dst, src, eid.astype(np.float32) + 1.0, n_dst, n_src,
@@ -337,7 +366,7 @@ def _effective_widths(w: np.ndarray) -> np.ndarray:
     return np.where(nz.any(axis=1), e - np.argmax(nz[:, ::-1], axis=1), 0)
 
 
-def _block_widths(adj: BucketedELL, row_block: int) -> list:
+def _block_widths(adj: BucketedELL, row_block: int) -> np.ndarray:
     """Max effective width of each fused row-block, after the descending
     degree sort each bucket undergoes inside :func:`fuse_bucketed` — i.e.
     exactly the widths the arena's chunk counts are derived from."""
@@ -346,11 +375,21 @@ def _block_widths(adj: BucketedELL, row_block: int) -> list:
         width_r = np.sort(_effective_widths(np.asarray(b.w, np.float32)))[::-1]
         rpad = _round_up(max(width_r.size, 1), row_block)
         width_r = np.concatenate(
-            [width_r, np.zeros(rpad - width_r.size, np.int64)])
-        for t in range(rpad // row_block):
-            bws.append(int(width_r[t * row_block:(t + 1) * row_block]
-                           .max(initial=0)))
-    return bws
+            [width_r, np.zeros(rpad - width_r.size, width_r.dtype)])
+        bws.append(width_r.reshape(-1, row_block).max(axis=1))
+    return np.concatenate([np.zeros(0, np.int64)] + bws)
+
+
+def _min_slot_chunk(bws: np.ndarray, row_block: int,
+                    candidates: Sequence[int]) -> int:
+    """The candidate chunk width minimizing Σ_blocks BR·Ec·max(1,
+    ceil(bw/Ec)) over the block widths ``bws``; ties go to the wider."""
+    cands = np.asarray(candidates, np.int64)
+    bws = np.asarray(bws, np.int64)
+    n_chunks = np.maximum(1, -(-bws[None, :] // cands[:, None]))
+    slots = row_block * cands * n_chunks.sum(axis=1)
+    _slots, neg_chunk = min(zip(slots.tolist(), (-cands).tolist()))
+    return -neg_chunk
 
 
 def pick_chunk(adj: BucketedELL, row_block: int = None,
@@ -365,12 +404,8 @@ def pick_chunk(adj: BucketedELL, row_block: int = None,
     """
     if row_block is None:
         row_block = FUSED_ROW_BLOCK
-    bws = _block_widths(adj, row_block)
-
-    def slots(c):
-        return sum(row_block * c * max(1, -(-bw // c)) for bw in bws)
-
-    return min(candidates, key=lambda c: (slots(c), -c))
+    return _min_slot_chunk(_block_widths(adj, row_block), row_block,
+                           candidates)
 
 
 def fuse_bucketed(adj: BucketedELL, row_block: int = None,
@@ -402,7 +437,10 @@ def fuse_bucketed(adj: BucketedELL, row_block: int = None,
     if chunk is None:
         chunk = pick_chunk(adj, row_block)
 
-    nbr_chunks, w_chunks, block_of, start = [], [], [], []
+    # Per bucket, one reshape/transpose turns the padded (rows, width) slab
+    # into its (blocks, chunks, BR, Ec) chunk grid, and a mask keeps each
+    # row-block's first max(1, ceil(bw/Ec)) chunks, in (block, chunk) order.
+    nbr_parts, w_parts, blk_parts, start_parts = [], [], [], []
     rows_parts = []
     gather = np.full(adj.n_dst, -1, np.int64)
     blk = 0
@@ -422,9 +460,7 @@ def fuse_bucketed(adj: BucketedELL, row_block: int = None,
         rid_p[:r] = rid
         # Effective row width = last carried weight (pack_ell fills rows
         # left-to-right; zero-weight slots contribute nothing either way).
-        nz = wt_p != 0
-        width_r = np.where(nz.any(axis=1),
-                           epad - np.argmax(nz[:, ::-1], axis=1), 0)
+        width_r = _effective_widths(wt_p)
         # Finer-than-bucket adaptivity: order rows by effective width so
         # each row-block's chunk count tracks its OWN max degree, not the
         # bucket's.  A degree-17 row in a width-64 bucket then costs
@@ -439,31 +475,34 @@ def fuse_bucketed(adj: BucketedELL, row_block: int = None,
         gather[rid_p[real]] = arena_off + np.nonzero(real)[0]
         rows_parts.append(rid_p)
         arena_off += rpad
-        for t in range(rpad // row_block):
-            sl = slice(t * row_block, (t + 1) * row_block)
-            bw = int(width_r[sl].max(initial=0))
-            nch = max(1, -(-bw // chunk))            # ≥1 so the block inits
-            for ci in range(nch):
-                cs = slice(ci * chunk, (ci + 1) * chunk)
-                nbr_chunks.append(nb_p[sl, cs])
-                w_chunks.append(wt_p[sl, cs])
-                block_of.append(blk)
-                start.append(1 if ci == 0 else 0)
-            blk += 1
+        n_blk, n_ch = rpad // row_block, epad // chunk
+        bw = width_r.reshape(n_blk, row_block).max(axis=1)
+        nch = np.maximum(1, -(-bw // chunk))      # ≥1 so the block inits
+        keep = np.arange(n_ch)[None, :] < nch[:, None]    # (blocks, chunks)
+
+        def chunks(a):
+            return a.reshape(n_blk, row_block, n_ch, chunk) \
+                .transpose(0, 2, 1, 3)[keep]
+
+        nbr_parts.append(chunks(nb_p))
+        w_parts.append(chunks(wt_p))
+        blk_parts.append(np.repeat(blk + np.arange(n_blk), nch))
+        start_parts.append(np.nonzero(keep)[1] == 0)
+        blk += n_blk
 
     # Trailing sentinel block: BR guaranteed-zero arena rows that empty
     # original rows gather from.
-    nbr_chunks.append(np.zeros((row_block, chunk), np.int32))
-    w_chunks.append(np.zeros((row_block, chunk), np.float32))
-    block_of.append(blk)
-    start.append(1)
+    nbr_parts.append(np.zeros((1, row_block, chunk), np.int32))
+    w_parts.append(np.zeros((1, row_block, chunk), np.float32))
+    blk_parts.append(np.asarray([blk]))
+    start_parts.append(np.ones(1, bool))
     sentinel_row = arena_off
     rows_parts.append(np.zeros(row_block, np.int32))
     gather[gather < 0] = sentinel_row
 
     nnz = adj.nnz if adj.nnz >= 0 else int(
         sum(int((np.asarray(b.w) != 0).sum()) for b in adj.buckets))
-    w_arena = np.stack(w_chunks)
+    w_arena = np.concatenate(w_parts)
     eid_arena = None
     if eids:
         # w slots hold f32(id+1) with 0 padding (exact up to 2^24 edges,
@@ -476,10 +515,10 @@ def fuse_bucketed(adj: BucketedELL, row_block: int = None,
     # tracers into the memo and leak them out of the trace.  numpy leaves
     # are trace-safe constants.
     fused = FusedELL(
-        nbr=np.stack(nbr_chunks),
+        nbr=np.concatenate(nbr_parts),
         w=w_arena,
-        block_of=np.asarray(block_of, np.int32),
-        start=np.asarray(start, np.int32),
+        block_of=np.concatenate(blk_parts).astype(np.int32),
+        start=np.concatenate(start_parts).astype(np.int32),
         rows=np.concatenate(rows_parts).astype(np.int32),
         gather=gather.astype(np.int32),
         n_dst=adj.n_dst, n_src=adj.n_src, nnz=nnz,
@@ -788,12 +827,9 @@ def pick_chunk_multi(packings: Sequence[BucketedELL], row_block: int = None,
     matching ``pick_chunk``."""
     if row_block is None:
         row_block = FUSED_ROW_BLOCK
-    bws = [bw for p in packings for bw in _block_widths(p, row_block)]
-
-    def slots(c):
-        return sum(row_block * c * max(1, -(-bw // c)) for bw in bws)
-
-    return min(candidates, key=lambda c: (slots(c), -c))
+    bws = np.concatenate([np.zeros(0, np.int64)]
+                         + [_block_widths(p, row_block) for p in packings])
+    return _min_slot_chunk(bws, row_block, candidates)
 
 
 def _empty_super_arena(n_dst: int, n_src: int, row_block: int,
@@ -905,7 +941,9 @@ def build_relation_plan(relations: Sequence[tuple], n_of: Dict[str, int], *,
     ----------
     relations : sequence of ``(etype, src_type, dst_type, dst, src, w)``
         COO edge lists per relation; the sequence order fixes the segment
-        (and output-concat) order.
+        (and output-concat) order.  With ``packed`` whose packings carry
+        their ``nnz`` (as :func:`pack_ell`'s do) the COO is not read, and
+        ``(etype, src_type, dst_type)`` alone will do.
     n_of : ordered ``{node_type: count}`` — the order fixes the source
         concat layout ``[type0; type1; …]`` the caller's CBSR operands are
         stacked in.
@@ -921,9 +959,11 @@ def build_relation_plan(relations: Sequence[tuple], n_of: Dict[str, int], *,
         its quantization grid + ``BucketLayout`` floors).
     packed : optional ``{etype: (fwd_bucketed, bwd_bucketed)}`` — reuse
         already-built degree-bucketed packings instead of re-running
-        ``pack_ell`` (the collator shares the pair it packs for the
-        per-edge-type arenas; fusing at the plan's shared chunk width is
-        memoized separately per (packing, width)).
+        ``pack_ell`` (:func:`repro.graphs.circuit.relation_plan_of` passes
+        the graph's own pair; the collator shares the pair it packs for
+        the per-edge-type arenas; fusing at the plan's shared chunk width
+        is memoized separately per (packing, width)).  Plans built here
+        count in ``graph.plan_builds{source="packed"|"coo"}``.
     dense_threshold : nnz at or below which a relation is routed to the
         dense tier (default :data:`DENSE_TIER_NNZ`); the
         :data:`DENSE_TIER_AREA` table-size guard always applies on top.
@@ -942,6 +982,8 @@ def build_relation_plan(relations: Sequence[tuple], n_of: Dict[str, int], *,
         off += int(n_of[t])
     n_src_total = off
     thr = DENSE_TIER_NNZ if dense_threshold is None else int(dense_threshold)
+    _METRICS.inc("graph.plan_builds",
+                 source="coo" if packed is None else "packed")
 
     # Plan packing may run lazily inside a jit trace (first call of a
     # jitted layer over a concrete graph): force the pack_ell slabs to be
@@ -1047,7 +1089,7 @@ def build_relation_plan(relations: Sequence[tuple], n_of: Dict[str, int], *,
 
     segments = []
     a_pos = 0
-    for i, (et, st, dt, _d, _s, _w) in enumerate(relations):
+    for i, (et, st, dt) in enumerate(r[:3] for r in relations):
         if tier_of[i] == "arena":
             fa, ba = fwd_a[a_pos], bwd_a[a_pos]
             (fc, fr), (bc, brr) = f_offs[a_pos], b_offs[a_pos]

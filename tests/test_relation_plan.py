@@ -245,6 +245,120 @@ def test_relation_plan_memoized(layer_setup):
     assert with_plan(pg) is pg
 
 
+# ------------------ plan from the graph's own packings ------------------
+
+def _coo_built_plan(g):
+    """The plan as built from COO read back out of the forward packings
+    (``build_relation_plan`` packing both directions itself)."""
+    from repro.graphs.ell import ell_to_coo
+    rels = [(et,) + EDGE_SCHEMA[et] + ell_to_coo(g.edges[et].adj)
+            for et in ("near", "pin", "pinned")]
+    return build_relation_plan(rels, {"cell": g.n_cell, "net": g.n_net})
+
+
+@pytest.fixture(scope="module")
+def packed_vs_coo():
+    # near is past the dense-tier crossover, pin / pinned below it
+    g = _graph(300, 150, 17)
+    return g, relation_plan_of(g), _coo_built_plan(g)
+
+
+def test_relation_plan_of_matches_coo_built_plan(packed_vs_coo):
+    """Forward leaves bit-identical; the backward arena differs only in
+    the neighbour order inside a row (the graph's own ``adj_t``), so its
+    matrix, segments and every shape are equal."""
+    g, plan, ref = packed_vs_coo
+    assert plan.has_arena and plan.has_dense
+    assert plan.segments == ref.segments
+    assert (plan.src_types, plan.src_off, plan.src_sizes) == \
+        (ref.src_types, ref.src_off, ref.src_sizes)
+    assert jax.tree.structure(plan) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(plan), jax.tree.leaves(ref)):
+        assert np.shape(a) == np.shape(b)
+        assert np.asarray(a).dtype == np.asarray(b).dtype
+    for f in dataclasses.fields(plan.fwd):
+        a, b = getattr(plan.fwd, f.name), getattr(ref.fwd, f.name)
+        if f.metadata.get("static") or a is None:
+            assert a == b, f.name
+        else:
+            assert np.array_equal(np.asarray(a), np.asarray(b)), f.name
+    for name in ("block_of", "start", "rows", "gather", "rel"):
+        assert np.array_equal(getattr(plan.bwd, name),
+                              getattr(ref.bwd, name)), name
+    assert np.array_equal(plan.bwd.to_dense(), ref.bwd.to_dense())
+    assert np.array_equal(plan.bwd_src_rows, ref.bwd_src_rows)
+    assert np.array_equal(plan.dense_fwd, ref.dense_fwd)
+    assert np.array_equal(plan.dense_bwd, ref.dense_bwd)
+    # each backward row holds the graph's own adj_t row, in its order
+    near = plan.segment("near")
+    lo, hi = near.bwd_chunks
+    assert not np.array_equal(plan.bwd.nbr[lo:hi], ref.bwd.nbr[lo:hi])
+
+
+def test_relation_plan_of_packs_nothing(monkeypatch):
+    """The graph's packings feed the plan: no ``pack_ell`` on this path."""
+    import repro.graphs.ell as ell
+    g = _graph(120, 60, 29)
+    calls = []
+    real = ell.pack_ell
+    monkeypatch.setattr(ell, "pack_ell",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    relation_plan_of(g)
+    assert calls == []
+    _coo_built_plan(g)
+    assert len(calls) == 6        # the COO path packs both directions
+
+
+def test_relation_plan_of_pins_no_fused_arena():
+    """The plan is memoized whole; the per-relation fused arenas it was
+    built from leave no ``_FUSE_CACHE`` entry behind."""
+    from repro.graphs.ell import _FUSE_CACHE
+    g = _graph(120, 60, 37)
+    before = set(_FUSE_CACHE)
+    relation_plan_of(g)
+    assert set(_FUSE_CACHE) <= before
+
+
+def test_plan_builds_counter():
+    """``graph.plan_builds{source="packed"}`` counts one per new graph and
+    none on a memo hit; only a plan packed from COO counts ``coo``."""
+    from repro.obs.metrics import DEFAULT_REGISTRY as reg
+
+    def counts():
+        return (reg.value("graph.plan_builds", source="packed"),
+                reg.value("graph.plan_builds", source="coo"))
+
+    p0, c0 = counts()
+    g1, g2 = _graph(40, 20, 31), _graph(44, 22, 32)
+    relation_plan_of(g1)
+    assert counts() == (p0 + 1, c0)
+    relation_plan_of(g1)                      # memo hit
+    assert counts() == (p0 + 1, c0)
+    relation_plan_of(g2)
+    assert counts() == (p0 + 2, c0)
+    _coo_built_plan(g1)
+    assert counts() == (p0 + 2, c0 + 1)
+
+
+def test_trainer_step_with_packed_plan_matches_coo_plan(packed_vs_coo):
+    """One training step on the graph's plan and on the COO-built plan:
+    same loss, and the same weights after the update, to 1e-6."""
+    from repro.train.circuit_trainer import CircuitTrainConfig, \
+        CircuitTrainer
+    g, plan, ref = packed_vs_coo
+    cfg = CircuitTrainConfig(hidden=32, k_cell=8, k_net=8, seed=3)
+    out = []
+    for p in (plan, ref):
+        tr = CircuitTrainer(cfg, g.x_cell.shape[1], g.x_net.shape[1])
+        loss = tr.train_epoch([dataclasses.replace(g, plan=p)])
+        out.append((loss, jax.tree.leaves(tr.params)))
+    (l_a, p_a), (l_b, p_b) = out
+    assert abs(l_a - l_b) <= 1e-6 * max(1.0, abs(l_b))
+    for a, b in zip(p_a, p_b):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
 # --------------------- segment round-trip property ---------------------
 
 rt_plans = st.integers(0, 2 ** 31 - 1).flatmap(lambda seed: st.tuples(
