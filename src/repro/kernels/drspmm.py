@@ -675,3 +675,190 @@ def drspmm_dw_learnable_fused(fused: FusedELL, gy_arena: jax.Array,
         interpret=interp,
     )(jnp.asarray(fused.block_of), ids, rows, gy_arena), interpret)
     return out.reshape(c, br, ec)
+
+
+# ---------------------------------------------------------------------------
+# GENConv softmax aggregation over a relation super-arena (DeeperGCN's
+# ``aggr="softmax"``, models/deepgen.py, DESIGN.md §15):
+#
+#   a_ic = Σ_{j→i} softmax_j(t_r · m_jc) · m_jc        per channel c
+#
+# One forward kernel and one transposed backward kernel per direction-group,
+# over the same (C, BR, Ec) chunk tables as DR-SpMM.  Each edge gathers its
+# source's whole lane-padded row by DMA; the row is stored SLOT-major in
+# VMEM (slot e of the BR destinations of a chunk is one aligned (BR, Hp)
+# tile), so every step below is elementwise on (BR, Hp) tiles.  Padding
+# slots gather a sentinel row the op appends (zeros forward — messages are
+# ≥ eps > 0, so ``m > 0`` marks a real edge; zero cotangent and a huge
+# normaliser backward, so they contribute exactly 0).
+# ---------------------------------------------------------------------------
+
+GEN_NEG = -1e30          # running-max floor: exp(GEN_NEG − real) == 0
+
+
+def _gather_rows_slot_major(src_hbm, ids_ref, rows_ref, sem, br: int,
+                            ec: int):
+    """``_gather_rows`` with id r = b·Ec + e landing in row e·BR + b, so the
+    BR destinations' slot e is the aligned tile rows[e·BR:(e+1)·BR]."""
+    def start(r, carry):
+        dst = (r % ec) * br + r // ec
+        pltpu.make_async_copy(src_hbm.at[pl.ds(ids_ref[0, 0, r], 1)],
+                              rows_ref.at[pl.ds(dst, 1)], sem).start()
+        return carry
+
+    def wait(r, carry):
+        pltpu.make_async_copy(src_hbm.at[pl.ds(0, 1)],
+                              rows_ref.at[pl.ds(0, 1)], sem).wait()
+        return carry
+
+    n = br * ec
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
+
+
+def _gen_fwd_kernel(blk_ref, st_ref, rel_ref, nbr_ref, t_ref, m_hbm,
+                    out_ref, lse_ref, rows_ref, sem, mx_s, s_s, a_s, *,
+                    ec: int):
+    c = pl.program_id(0)
+    br = out_ref.shape[0]
+
+    @pl.when(st_ref[c] == 1)
+    def _init():
+        mx_s[...] = jnp.full(mx_s.shape, GEN_NEG, jnp.float32)
+        s_s[...] = jnp.zeros_like(s_s)
+        a_s[...] = jnp.zeros_like(a_s)
+
+    _gather_rows_slot_major(m_hbm, nbr_ref, rows_ref, sem, br, ec)
+    t = t_ref[0, rel_ref[c]]
+    # online softmax over the row-block's chunks: this chunk's max first,
+    # then one rescale of the running sums and one exp per slot
+    mx = mx_s[...]
+    for e in range(ec):
+        m = rows_ref[e * br:(e + 1) * br, 0, :]
+        mx = jnp.maximum(mx, jnp.where(m > 0, t * m, GEN_NEG))
+    alpha = jnp.exp(mx_s[...] - mx)
+    s = s_s[...] * alpha
+    a = a_s[...] * alpha
+    for e in range(ec):
+        m = rows_ref[e * br:(e + 1) * br, 0, :]
+        p = jnp.where(m > 0, jnp.exp(t * m - mx), 0.0)
+        s = s + p
+        a = a + p * m
+    mx_s[...] = mx
+    s_s[...] = s
+    a_s[...] = a
+    # written at every chunk; the block's last chunk leaves the final value
+    nz = s > 0
+    s1 = jnp.where(nz, s, 1.0)
+    out_ref[...] = jnp.where(nz, a / s1, 0.0)
+    lse_ref[...] = jnp.where(nz, mx + jnp.log(s1), 0.0)
+
+
+def gen_aggr_fwd(fused: FusedELL, nbr, t_rel: jax.Array, m_rows: jax.Array,
+                 *, interpret: bool | None = None):
+    """Arena-ordered (out, lse), each fp32 (R_arena, Hp), in ONE launch.
+
+    ``nbr`` is the arena's (C, BR, Ec) id table with padding slots pointed
+    at the sentinel row of ``m_rows``, the (N + 1, 1, Hp) lane-padded
+    messages with a zero sentinel row (the unit axis keeps a row one DMA:
+    a 2-D table wider than one lane tile is (8, 128)-tiled in HBM, and
+    Mosaic refuses a 1-row slice of it); ``t_rel`` (n_rel,) is each
+    relation's temperature, indexed by the arena's ``rel`` chunk table."""
+    c, br, ec = fused.nbr.shape
+    hp = m_rows.shape[2]
+    ids = jnp.reshape(jnp.asarray(nbr, jnp.int32), (c, 1, br * ec))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(c,),
+        in_specs=[pl.BlockSpec((1, 1, br * ec), lambda i, *_: (i, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=[pl.BlockSpec((br, hp), lambda i, blk, *_: (blk[i], 0)),
+                   pl.BlockSpec((br, hp), lambda i, blk, *_: (blk[i], 0))],
+        scratch_shapes=[pltpu.VMEM((br * ec, 1, hp), jnp.float32),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.VMEM((br, hp), jnp.float32),
+                        pltpu.VMEM((br, hp), jnp.float32),
+                        pltpu.VMEM((br, hp), jnp.float32)],
+    )
+    shape = jax.ShapeDtypeStruct((fused.n_arena_rows, hp), jnp.float32)
+    return run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_gen_fwd_kernel, ec=ec),
+        grid_spec=grid_spec,
+        name="gen_aggr_fwd",
+        out_shape=(shape, shape),
+        interpret=interp,
+    )(jnp.asarray(fused.block_of), jnp.asarray(fused.start),
+      jnp.asarray(fused.rel), ids,
+      jnp.reshape(t_rel.astype(jnp.float32), (1, -1)), m_rows), interpret)
+
+
+def _gen_bwd_kernel(blk_ref, st_ref, rel_ref, nbr_ref, t_ref, y_hbm, m_ref,
+                    dm_ref, dt_ref, rows_ref, sem, *, ec: int):
+    c = pl.program_id(0)
+    br, hp = dm_ref.shape
+
+    @pl.when(st_ref[c] == 1)
+    def _init():
+        dm_ref[...] = jnp.zeros_like(dm_ref)
+        dt_ref[...] = jnp.zeros_like(dt_ref)
+
+    _gather_rows_slot_major(y_hbm, nbr_ref, rows_ref, sem, br, ec)
+    t = t_ref[0, rel_ref[c]]
+    m = m_ref[...]
+    tm = t * m
+    dm = dm_ref[...]
+    dt = dt_ref[...]
+    for e in range(ec):
+        g = rows_ref[e * br:(e + 1) * br, 0, 0:hp]
+        a = rows_ref[e * br:(e + 1) * br, 0, hp:2 * hp]
+        lse = rows_ref[e * br:(e + 1) * br, 0, 2 * hp:3 * hp]
+        q = g * jnp.exp(tm - lse)                 # g_ic · p_ijc
+        dm = dm + q * (1.0 + t * (m - a))
+        dt = dt + q * m * (m - a)
+    dm_ref[...] = dm
+    dt_ref[...] = dt
+
+
+def gen_aggr_bwd(fused_t: FusedELL, nbr, t_rel: jax.Array,
+                 y_rows: jax.Array, m_arena: jax.Array,
+                 *, interpret: bool | None = None):
+    """Arena-ordered (dm, dt), each fp32 (R_arena, Hp), over the transposed
+    arena in ONE launch.
+
+    ``y_rows`` is the (n_out + 1, 1, 3·Hp) table [g | a | lse] in the full
+    output-concat order, its last row the sentinel [0 | 0 | huge] that the
+    padding slots of ``nbr`` point at (the unit axis as in
+    ``gen_aggr_fwd``); ``m_arena`` the lane-padded messages
+    at each arena row's source.  ``dm`` accumulates
+    Σ_i g_ic p_ijc (1 + t (m_jc − a_ic)); ``dt`` the per-row terms of
+    ∂t = Σ g p m (m − a), summed per relation by the caller."""
+    c, br, ec = fused_t.nbr.shape
+    hp = m_arena.shape[1]
+    ids = jnp.reshape(jnp.asarray(nbr, jnp.int32), (c, 1, br * ec))
+    row_spec = pl.BlockSpec((br, hp), lambda i, blk, *_: (blk[i], 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(c,),
+        in_specs=[pl.BlockSpec((1, 1, br * ec), lambda i, *_: (i, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.HBM),
+                  row_spec],
+        out_specs=[row_spec, row_spec],
+        scratch_shapes=[pltpu.VMEM((br * ec,) + y_rows.shape[1:],
+                                   jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    shape = jax.ShapeDtypeStruct((fused_t.n_arena_rows, hp), jnp.float32)
+    return run_pallas(lambda interp: pl.pallas_call(
+        functools.partial(_gen_bwd_kernel, ec=ec),
+        grid_spec=grid_spec,
+        name="gen_aggr_bwd",
+        out_shape=(shape, shape),
+        interpret=interp,
+    )(jnp.asarray(fused_t.block_of), jnp.asarray(fused_t.start),
+      jnp.asarray(fused_t.rel), ids,
+      jnp.reshape(t_rel.astype(jnp.float32), (1, -1)), y_rows, m_arena),
+        interpret)

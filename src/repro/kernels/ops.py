@@ -1082,6 +1082,204 @@ def drspmm_multi(plan: RelationPlan, cbsr, dim: int, *,
 
 
 # ---------------------------------------------------------------------------
+# softmax_aggr_multi — GENConv's softmax aggregation (DeeperGCN, DESIGN.md
+# §15) over the same RelationPlan: one ``gen_aggr_fwd`` and one transposed
+# ``gen_aggr_bwd`` per direction-group on ``pallas_fused``; ``xla_fused``
+# walks the same arena tables in plain XLA.  Dense-tier relations (tiny,
+# sub-crossover) run a plain masked implementation over the plan's dense
+# table, differentiated by autodiff.
+# ---------------------------------------------------------------------------
+
+GEN_EPS = 1e-7           # GENConv's message offset: m = relu(x) + eps
+_GEN_BIG = 1e30          # sentinel normaliser: exp(t·m − big) == 0
+
+
+def _gen_remap(f: FusedELL, sentinel: int):
+    """The arena's ids with padding slots pointed at ``sentinel``."""
+    return jnp.where(jnp.asarray(f.w) != 0, jnp.asarray(f.nbr), sentinel)
+
+
+def _gen_fwd_xla(f: FusedELL, t_rel, m_cat):
+    """(out, lse), arena order, (R_arena, H): the kernel's math in XLA."""
+    valid = (jnp.asarray(f.w) != 0)[..., None]
+    xm = jnp.take(m_cat, jnp.asarray(f.nbr), axis=0)          # (C,BR,Ec,H)
+    t = jnp.take(t_rel, jnp.asarray(f.rel))[:, None, None, None]
+    z = jnp.where(valid, t * xm, _k.GEN_NEG)
+    blk = jnp.asarray(f.block_of)
+    n_blocks = f.n_arena_rows // f.row_block
+    mx = jax.ops.segment_max(jnp.max(z, axis=2), blk, num_segments=n_blocks)
+    p = jnp.where(valid, jnp.exp(z - jnp.take(mx, blk, axis=0)[:, :, None]),
+                  0.0)
+    s = jax.ops.segment_sum(jnp.sum(p, axis=2), blk, num_segments=n_blocks)
+    a = jax.ops.segment_sum(jnp.sum(p * xm, axis=2), blk,
+                            num_segments=n_blocks)
+    nz = s > 0
+    s1 = jnp.where(nz, s, 1.0)
+    h = m_cat.shape[1]
+    out = jnp.where(nz, a / s1, 0.0).reshape(f.n_arena_rows, h)
+    lse = jnp.where(nz, mx + jnp.log(s1), 0.0).reshape(f.n_arena_rows, h)
+    return out, lse
+
+
+def _gen_bwd_xla(ft: FusedELL, t_rel, y_rows, m_arena):
+    """(dm, dt), arena order: the backward kernel's math in XLA."""
+    h = m_arena.shape[1]
+    valid = (jnp.asarray(ft.w) != 0)[..., None]
+    rows = jnp.take(y_rows, jnp.asarray(ft.nbr), axis=0)     # (C,BR,Ec,3H)
+    g, a, lse = rows[..., :h], rows[..., h:2 * h], rows[..., 2 * h:]
+    m = jnp.take(m_arena, _arena_rows(ft), axis=0)[:, :, None, :]
+    t = jnp.take(t_rel, jnp.asarray(ft.rel))[:, None, None, None]
+    q = jnp.where(valid, g * jnp.exp(t * m - lse), 0.0)
+    blk = jnp.asarray(ft.block_of)
+    n_blocks = ft.n_arena_rows // ft.row_block
+    dm = jax.ops.segment_sum(jnp.sum(q * (1.0 + t * (m - a)), axis=2), blk,
+                             num_segments=n_blocks)
+    dt = jax.ops.segment_sum(jnp.sum(q * m * (m - a), axis=2), blk,
+                             num_segments=n_blocks)
+    return (dm.reshape(ft.n_arena_rows, h), dt.reshape(ft.n_arena_rows, h))
+
+
+def _gen_arena_fwd(plan: RelationPlan, m_cat, t_rel, backend: Backend):
+    """Arena-tier (y, lse) in the arena-only output concat."""
+    _METRICS.inc("mp.gen_aggr_dispatches", dir="fwd")
+    f = plan.fwd
+    if backend == "pallas_fused":
+        h = m_cat.shape[1]
+        rows = _k._lane_pad(jnp.concatenate(
+            [m_cat, jnp.zeros((1, h), m_cat.dtype)]))[:, None, :]
+        y, lse = _k.gen_aggr_fwd(f, _gen_remap(f, m_cat.shape[0]), t_rel,
+                                 rows)
+        y, lse = y[:, :h], lse[:, :h]
+    else:
+        y, lse = _gen_fwd_xla(f, t_rel, m_cat)
+    gather = jnp.asarray(f.gather)
+    return jnp.take(y, gather, axis=0), jnp.take(lse, gather, axis=0)
+
+
+def _gen_arena_bwd(plan: RelationPlan, m_cat, t_rel, y, lse, gy,
+                   backend: Backend):
+    """Cotangents of (m_cat, t_rel) from the arena tier's output cotangent
+    ``gy`` (arena-only concat) over the transposed super-arena."""
+    _METRICS.inc("mp.gen_aggr_dispatches", dir="bwd")
+    ft = plan.bwd
+    h = m_cat.shape[1]
+    # [g | a | lse] in the FULL output concat the transposed arena's ids
+    # address (dense-tier rows are never referenced: zeros)
+    zero = lambda n: jnp.zeros((n, 3 * h), jnp.float32)
+    tab = jnp.concatenate([gy, y, lse], axis=1)
+    parts = [tab[s.arena_out_off:s.arena_out_off + s.n_dst]
+             if s.tier == "arena" else zero(s.n_dst) for s in plan.segments]
+    m_arena = jnp.take(m_cat, jnp.asarray(plan.bwd_src_rows), axis=0)
+    if backend == "pallas_fused":
+        n_out = plan.n_out_total
+        sentinel = jnp.concatenate([jnp.zeros((1, 2 * h), jnp.float32),
+                                    jnp.full((1, h), _GEN_BIG, jnp.float32)],
+                                   axis=1)
+        pad3 = lambda x: jnp.concatenate(
+            [_k._lane_pad(x[:, i * h:(i + 1) * h]) for i in range(3)], axis=1)
+        rows = pad3(jnp.concatenate(parts + [sentinel]))[:, None, :]
+        dm, dt = _k.gen_aggr_bwd(ft, _gen_remap(ft, n_out), t_rel, rows,
+                                 _k._lane_pad(m_arena))
+        dm, dt = dm[:, :h], dt[:, :h]
+    else:
+        dm, dt = _gen_bwd_xla(ft, t_rel, jnp.concatenate(parts), m_arena)
+    dx = jnp.take(dm, jnp.asarray(ft.gather), axis=0)   # relation-concat
+    dm_cat = jnp.zeros_like(m_cat)
+    for s in plan.arena_segments:
+        o = plan.src_off[plan.src_types.index(s.src_type)]
+        dm_cat = dm_cat.at[o:o + s.n_src].add(
+            dx[s.src_out_off:s.src_out_off + s.n_src])
+    dt_rel = jnp.stack([jnp.sum(dt[s.bwd_rows[0]:s.bwd_rows[1]])
+                        for s in plan.arena_segments])
+    return dm_cat, dt_rel
+
+
+def _gen_arena(plan: RelationPlan, m_cat, t_rel, backend: Backend):
+    """Custom-vjp arena tier; the plan rides as a primal (as in
+    ``_multi_traced``) so the op is safe inside scan and checkpoint."""
+
+    @jax.custom_vjp
+    def f(plan, m_cat, t_rel):
+        return _gen_arena_fwd(plan, m_cat, t_rel, backend)[0]
+
+    def f_fwd(plan, m_cat, t_rel):
+        y, lse = _gen_arena_fwd(plan, m_cat, t_rel, backend)
+        return y, (plan, m_cat, t_rel, y, lse)
+
+    def f_bwd(res, gy):
+        plan, m_cat, t_rel, y, lse = res
+        dm, dt = _gen_arena_bwd(plan, m_cat, t_rel, y, lse, gy, backend)
+        return _zero_plan_cotangent(plan), dm, dt
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(plan, m_cat, t_rel)
+
+
+_GEN_DENSE_BLOCK = 1 << 22     # elements of one (rows, n_src, H) block
+
+
+def _gen_dense(a, m_src, t):
+    """Softmax aggregation of one dense-tier relation from its (n_dst,
+    n_src) weight block (non-zero = edge): a masked softmax over
+    destination-row blocks, each block checkpointed so no (n_dst, n_src, H)
+    tensor is kept."""
+    n_dst, n_src = a.shape
+    h = m_src.shape[1]
+    rb = max(1, min(n_dst, _GEN_DENSE_BLOCK // max(n_src * h, 1)))
+    n_pad = -(-n_dst // rb) * rb
+    mask = jnp.pad(a != 0, ((0, n_pad - n_dst), (0, 0)))
+    z = t * m_src
+
+    @jax.checkpoint
+    def block(mk):
+        mk = mk[:, :, None]
+        # softmax's own VJP centres the gradient, g·(m − a), as the kernel
+        w = jax.nn.softmax(jnp.where(mk, z[None], _k.GEN_NEG), axis=1)
+        out = jnp.sum(w * m_src[None], axis=1)
+        return jnp.where(jnp.any(mk, axis=1), out, 0.0)
+
+    out = jax.lax.map(block, mask.reshape(n_pad // rb, rb, n_src))
+    return out.reshape(n_pad, h)[:n_dst]
+
+
+def softmax_aggr_multi(plan: RelationPlan, x_by_type, t_by_relation, *,
+                       backend: Backend = DEFAULT_BACKEND):
+    """GENConv softmax aggregation of every relation of ``plan``.
+
+    For relation r from type s to type d, with messages
+    m_j = relu(x_s[j]) + eps:  a_ic = Σ_{j→i} softmax_j(t_r m_jc) m_jc,
+    per channel, over i's in-edges; a destination with no in-edges gets 0.
+    ``x_by_type`` maps each source node type to its (n_t, H) features and
+    ``t_by_relation`` each relation to its scalar temperature.  Returns
+    ``{etype: (n_dst, H)}``; gradients flow to the features and to every
+    ``t``.  Edge weights in the plan only mark edges (non-zero); their
+    values are not used.  Arena-tier relations run as one ``gen_aggr_fwd``
+    and one ``gen_aggr_bwd`` per call on ``pallas_fused`` (f32, per-channel
+    running max), and as the same arena walk in XLA on ``xla_fused``; the
+    other backend names follow ``drspmm_multi``'s family rules."""
+    eff = "pallas_fused" if _multi_effective_backend(backend) \
+        == "pallas_fused" else "xla_fused"
+    m = {t: jax.nn.relu(x_by_type[t].astype(jnp.float32)) + GEN_EPS
+         for t in plan.src_types}
+    out = {}
+    if plan.has_arena:
+        m_cat = jnp.concatenate([m[t] for t in plan.src_types])
+        t_rel = jnp.stack([jnp.asarray(t_by_relation[s.etype], jnp.float32)
+                           for s in plan.arena_segments])
+        y = _gen_arena(plan, m_cat, t_rel, eff)
+        for s in plan.arena_segments:
+            out[s.etype] = y[s.arena_out_off:s.arena_out_off + s.n_dst]
+    for s in plan.dense_segments:
+        o = plan.src_off[plan.src_types.index(s.src_type)]
+        a = jnp.asarray(plan.dense_fwd)[s.dense_off:s.dense_off + s.n_dst,
+                                        o:o + s.n_src]
+        out[s.etype] = _gen_dense(a, m[s.src_type],
+                                  jnp.asarray(t_by_relation[s.etype],
+                                              jnp.float32))
+    return out
+
+
+# ---------------------------------------------------------------------------
 # drspmm_multi_sharded — the giant-graph path (DESIGN.md §12): the
 # super-arena partitioned by destination row-block over a ("shard",) mesh
 # (sharding/plan_shard.py), executed under shard_map with ONE all-to-all

@@ -1,23 +1,25 @@
 """Deep-backbone stack machinery: declarative specs, wiring, remat.
 
-The exemplar circuit models (GSR-GNN, circuit-fewshot's DeepGEN configs)
-are 10–15 layers at hidden 128; training them naively holds every layer's
-activations — and, on the plan path, nothing extra, but the activations
-alone — live through the backward.  This module turns the ad-hoc
-``for lp in layers`` loops of models/hgnn.py into a first-class backbone
-(DESIGN.md §13):
+The exemplar deep circuit models (GSR-GNN, circuit-fewshot's DeepGEN
+configs) are 10–15 layers at hidden 128; training them naively holds every
+layer's activations — and, on the plan path, nothing extra, but the
+activations alone — live through the backward.  This module turns the
+ad-hoc ``for lp in layers`` loops of models/hgnn.py into a first-class
+backbone (DESIGN.md §13):
 
 * :class:`BackboneSpec` — the declarative stack description (depth,
   hidden, wiring, remat) shared by the trainer, the serve engine, the
   benches, and the examples.  ``CircuitTrainConfig.n_layers`` is its
   single depth source of truth.
 * :func:`apply_stack` — the one stack executor.  ``wiring`` draws the
-  DeepGEN-style reuse pattern: ``"plain"`` (h_i = f_i(h_{i-1})),
-  ``"residual"`` (+ h_{i-1} from the second layer on, so depth-1 is
-  exactly the vanilla stack), ``"dense"`` (+ Σ of all previous layer
-  states).  ``remat=True`` wraps each layer in :func:`jax.checkpoint`:
-  the backward *recomputes* the layer's fused forward instead of storing
-  its activations, and peak training memory stops scaling with depth.
+  skip pattern around the DR hetero layer: ``"plain"``
+  (h_i = f_i(h_{i-1})), ``"residual"`` (+ h_{i-1} from the second layer
+  on, so depth-1 is exactly the vanilla stack), ``"dense"`` (+ Σ of all
+  previous layer states).  DeepGEN's pre-activation res+ GENConv stack is
+  its own model (models/deepgen.py), not a wiring.  ``remat=True`` wraps
+  each layer in :func:`jax.checkpoint`: the backward *recomputes* the
+  layer's fused forward instead of storing its activations, and peak
+  training memory stops scaling with depth.
 * :func:`init_stack` — the shared init-key plumbing
   (``init_drcircuitgnn`` / ``init_homo`` are thin wrappers over it with
   bit-identical RNG streams to the pre-backbone code).

@@ -21,10 +21,10 @@ from repro.fault.monitor import StepMonitor
 from repro.graphs.circuit import CircuitGraph, relation_plan_of
 from repro.graphs.collate import collate_graphs
 from repro.kernels import ops
+from repro.models import deepgen
 from repro.models.backbone import BackboneSpec
-from repro.models.hgnn import (DRCircuitGNNParams, batched_loss_fn,
-                               drcircuitgnn_forward, init_drcircuitgnn,
-                               loss_fn)
+from repro.models.hgnn import (batched_loss_fn, drcircuitgnn_forward,
+                               init_drcircuitgnn, loss_fn)
 from repro.obs import span
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_RECORDER, Recorder
@@ -32,9 +32,29 @@ from repro.optim import adamw_init, adamw_update, constant
 from repro.sharding.specs import DeviceRing
 from repro.train import metrics as M
 
+MODELS = ("drcircuitgnn", "deepgen")
+
+
+def _model_fns(model: str):
+    """(init, forward, loss, batched loss) of a model, looked up when a
+    trainer is built.  Each function after init takes (params, graph,
+    [cell_weight,] HeteroMPConfig, BackboneSpec)."""
+    if model == "deepgen":
+        return (deepgen.init_deepgen, deepgen.deepgen_forward,
+                deepgen.loss_fn, deepgen.batched_loss_fn)
+    if model == "drcircuitgnn":
+        return (init_drcircuitgnn, drcircuitgnn_forward, loss_fn,
+                batched_loss_fn)
+    raise ValueError(f"unknown model {model!r}; expected one of {MODELS}")
+
 
 @dataclasses.dataclass
 class CircuitTrainConfig:
+    # "drcircuitgnn" (D-ReLU + DR-SpMM hetero layers) or "deepgen" (the
+    # res+ GENConv stack with softmax aggregation, models/deepgen.py; it
+    # reads hidden, n_layers, lr, weight_decay, backend, use_plan, seed and
+    # batch_size)
+    model: str = "drcircuitgnn"
     hidden: int = 64
     n_layers: int = 2
     k_cell: int = 16
@@ -69,8 +89,9 @@ class CircuitTrainConfig:
     # params AND the spec).  ``remat=True`` checkpoints each hetero layer:
     # the backward recomputes the layer's fused forward instead of storing
     # its activations, so depth-15 trains at roughly depth-3 peak memory
-    # (bench_backbone asserts it).  ``wiring`` selects the DeepGEN-style
-    # reuse pattern: "plain" | "residual" | "dense".
+    # (bench_backbone asserts it).  ``wiring`` selects the DR stack's
+    # skip pattern: "plain" | "residual" | "dense" (DeepGEN's res+ is its
+    # own model, ``model="deepgen"``).
     remat: bool = False
     wiring: str = "plain"
 
@@ -104,9 +125,10 @@ class CircuitTrainer:
         # one depth knob end-to-end (trainer, examples, benches)
         self.spec = BackboneSpec(depth=cfg.n_layers, hidden=cfg.hidden,
                                  wiring=cfg.wiring, remat=cfg.remat)
+        init, self._forward, self._loss_fn, self._batched_loss_fn = \
+            _model_fns(cfg.model)
         key = jax.random.PRNGKey(cfg.seed)
-        self.params = init_drcircuitgnn(key, f_cell, f_net, cfg.hidden,
-                                        cfg.n_layers)
+        self.params = init(key, f_cell, f_net, cfg.hidden, cfg.n_layers)
         self.opt_state = adamw_init(self.params)
         self.lr = constant(cfg.lr)
         self._step_fn = self._build_step()
@@ -196,7 +218,7 @@ class CircuitTrainer:
 
     def _build_step(self):
         mp_cfg, lr, wd = self.mp_cfg, self.lr, self.cfg.weight_decay
-        spec = self.spec
+        spec, loss_fn = self.spec, self._loss_fn
 
         @jax.jit
         def train_step(params, opt_state, graph: CircuitGraph):
@@ -213,7 +235,7 @@ class CircuitTrainer:
 
     def _build_batched_step(self):
         mp_cfg, lr, wd = self.mp_cfg, self.lr, self.cfg.weight_decay
-        spec = self.spec
+        spec, batched_loss_fn = self.spec, self._batched_loss_fn
 
         @jax.jit
         def train_step_batched(params, opt_state, graph: CircuitGraph,
@@ -234,6 +256,7 @@ class CircuitTrainer:
         data-parallel step.  Placement follows the committed arguments, so
         dispatching shard d with replica-d params runs on device d."""
         mp_cfg, spec = self.mp_cfg, self.spec
+        batched_loss_fn = self._batched_loss_fn
 
         @jax.jit
         def dp_grad(params, graph: CircuitGraph, cell_w):
@@ -295,7 +318,8 @@ class CircuitTrainer:
         re-uploading the plan's host arrays every step.  The jit cache is
         keyed by shapes, so equal-shaped graphs still share one executable.
         """
-        if not plan_applicable(self.mp_cfg, self.cfg.hidden):
+        if self.cfg.model == "drcircuitgnn" and \
+                not plan_applicable(self.mp_cfg, self.cfg.hidden):
             return g
         key = id(g)
         hit = self._plan_cache.get(key)
@@ -446,8 +470,7 @@ class CircuitTrainer:
     def evaluate(self, graphs: List[CircuitGraph]) -> Dict[str, float]:
         preds, labels = [], []
         for g in graphs:
-            p = drcircuitgnn_forward(self.params, g, self.mp_cfg,
-                                     self.spec)
+            p = self._forward(self.params, g, self.mp_cfg, self.spec)
             preds.append(np.asarray(p))
             labels.append(np.asarray(g.y_cell))
         return M.all_metrics(np.concatenate(preds), np.concatenate(labels))

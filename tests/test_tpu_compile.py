@@ -153,3 +153,26 @@ def test_learnable_dw_compiles(one_chip):
              _sds(one_chip, (N_ARENA, 64), jnp.float32),
              _sds(one_chip, (N_SRC, K_KEEP), jnp.float32),
              _sds(one_chip, (N_SRC, K_KEEP), jnp.int32))
+
+
+@pytest.mark.parametrize("hidden", [128, 256])
+def test_gen_aggr_fwd_compiles(one_chip, hidden):
+    """DeepGEN's softmax-aggregation forward: a whole lane-padded message
+    row per edge, slot-major in VMEM."""
+    arena = _super_arena(one_chip)
+    _compile(lambda f, nbr, t, m: K.gen_aggr_fwd(f, nbr, t, m,
+                                                 interpret=False),
+             arena, arena.nbr, _sds(one_chip, (3,), jnp.float32),
+             _sds(one_chip, (N_SRC + 1, 1, hidden), jnp.float32))
+
+
+@pytest.mark.parametrize("hidden", [128, 256])
+def test_gen_aggr_bwd_compiles(one_chip, hidden):
+    """Its transposed backward: one [g | a | lse] row per edge, kept one
+    DMA by the table's unit axis."""
+    arena = _super_arena(one_chip)
+    _compile(lambda f, nbr, t, y, m: K.gen_aggr_bwd(f, nbr, t, y, m,
+                                                    interpret=False),
+             arena, arena.nbr, _sds(one_chip, (3,), jnp.float32),
+             _sds(one_chip, (N_ARENA + 1, 1, 3 * hidden), jnp.float32),
+             _sds(one_chip, (N_ARENA, hidden), jnp.float32))
