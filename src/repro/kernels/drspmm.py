@@ -19,13 +19,18 @@ uniform (BR, Ec) neighbour chunks (DESIGN.md §1):
 
 Kernel-body idioms, all in a form Mosaic lowers (DESIGN.md §1.4):
 
-* **Row gathers by DMA.**  The gathered operand (the CBSR slab, dY, or a
-  dense x) stays in HBM; each grid step reads its chunk's BR·Ec neighbour ids
-  from an SMEM block and issues one row DMA per id into a VMEM scratch, all
-  in flight at once, then drains them.  No VMEM block grows with the number
-  of source rows.  A DMA row must span whole 128-lane tiles, so operands are
-  lane-padded first; the CBSR operand travels as one int32 row table
-  (value bits | indices | zero padding), one DMA per neighbour.
+* **Row gathers from a resident slab.**  The gathered operand (the CBSR
+  slab, dY, or a dense x) enters the call in HBM.  Where it fits the VMEM
+  budget (:func:`_slab_budget`, half the core's VMEM less the call's other
+  buffers) the first grid step copies it whole into a VMEM scratch with one
+  DMA, and each grid step reads its chunk's BR·Ec neighbour ids from an SMEM
+  block and copies those rows out of the slab with dynamic single-row
+  loads.  A slab over the budget stays in HBM and each id is one row DMA
+  into a VMEM scratch, all in flight at once, then drained.  The path is
+  chosen at trace time from the slab's bytes and counted in
+  ``kernels.gather_path``.  Operands are lane-padded to whole 128-lane tiles
+  (a DMA row must span them); the CBSR operand travels as one int32 row
+  table (value bits | indices | zero padding), one row per neighbour.
 * **Scatter by compare-select.**  The k CBSR values of a gathered row are
   densified into a (rows, D-tile) slice with one lane-iota compare-select per
   kept position (TPUs have no fast in-kernel scatter).
@@ -56,11 +61,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.graphs.ell import ELLBucket, FusedELL, ROW_BLOCK, _round_up
+from repro.obs.metrics import DEFAULT_REGISTRY as _METRICS
 
 # One MXU lane-width: D-tiling granularity for wide embeddings, and the
 # lane-tile width a DMA row must span.
 D_TILE = 128
 LANES = 128
+
+# Share of a core's VMEM that a resident source slab and the call's other
+# buffers may take together (the rest is headroom for Mosaic's temporaries).
+VMEM_SLAB_SHARE = 0.5
+# A resident call's scoped VMEM limit adds this share of the core's VMEM to
+# what its own buffers take: room for the kernel body's temporaries.
+VMEM_MARGIN_SHARE = 0.25
+# VMEM capacity assumed where the trace names no TPU on a host without one
+# (interpret mode on a CPU host): 16 MiB, the smallest of any TPU
+# generation ``pltpu.get_tpu_info`` knows, so a slab judged resident here
+# fits on every chip.
+VMEM_FALLBACK_BYTES = 16 * 1024 * 1024
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -105,10 +123,92 @@ def _cbsr_rows(x_vals: jax.Array, x_idx: jax.Array) -> jax.Array:
 # kernel-body helpers
 # ---------------------------------------------------------------------------
 
-def _gather_rows(src_hbm, ids_ref, rows_ref, sem):
-    """rows_ref[r] = src_hbm[ids_ref[0, 0, r]] for every r: one row DMA per
-    id, all started before the first wait (same-size copies on one
-    semaphore, so any descriptor of that size drains one)."""
+def _tile_bytes(shape, dtype) -> int:
+    """VMEM bytes of an array of ``shape``: the last two axes padded to
+    whole (8, 128) tiles."""
+    *lead, r, c = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    n = _round_up(r, 8) * _round_up(c, LANES) * jnp.dtype(dtype).itemsize
+    for a in lead:
+        n *= a
+    return n
+
+
+def _vmem_capacity() -> int:
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:                       # the trace names no TPU
+        # on a TPU host the fallback would halve the budget below the
+        # Table-1 slabs and silently put every launch on the DMA path
+        if jax.default_backend() == "tpu":
+            raise
+        return VMEM_FALLBACK_BYTES
+
+
+def _slab_budget(other_bytes: int) -> int:
+    """Bytes a VMEM-resident source slab may take in a call whose other
+    VMEM buffers (rows scratch, pipelined blocks) take ``other_bytes``."""
+    return int(VMEM_SLAB_SHARE * _vmem_capacity()) - other_bytes
+
+
+def _gather_scratch(kernel: str, src, n_slots: int, blocks, n_grid: int):
+    """(scratch shapes, compiler params) of a chunk walk that gathers
+    ``n_slots`` rows of ``src`` a grid step and pipelines ``blocks``
+    ((shape, dtype) each, double-buffered): the rows scratch and its
+    semaphore, then the whole slab as one more VMEM scratch when it fits
+    the budget (no params: the per-row DMA path otherwise).  Counts the
+    path in ``kernels.gather_path`` once per launch, as it is traced."""
+    rows = (n_slots, src.shape[1])
+    scratch = [pltpu.VMEM(rows, src.dtype), pltpu.SemaphoreType.DMA(())]
+    other = _tile_bytes(rows, src.dtype) + 2 * sum(
+        _tile_bytes(shape, dtype) for shape, dtype in blocks)
+    slab = _tile_bytes(src.shape, src.dtype)
+    resident = slab <= _slab_budget(other)
+    _METRICS.inc("kernels.gather_path", kernel=kernel,
+                 path="vmem" if resident else "dma")
+    if not resident:
+        return scratch, None
+    # the slab scratch is filled at grid step 0 and read by every later
+    # step, so no grid axis may be split across cores
+    return scratch + [pltpu.VMEM(src.shape, src.dtype)], pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * n_grid,
+        vmem_limit_bytes=slab + other
+        + int(VMEM_MARGIN_SHARE * _vmem_capacity()))
+
+
+def _gather_rows(src_hbm, ids_ref, rows_ref, sem, slab=(), first=None):
+    """rows_ref[r] = src_hbm[ids_ref[0, 0, r]] for every r.
+
+    With a resident ``slab`` (one VMEM scratch shaped like ``src_hbm``),
+    the grid step where ``first`` holds copies the whole source into it
+    with one DMA, and every step copies its rows out of it with dynamic
+    single-row loads.  Without one, one row DMA per id, all started before
+    the first wait (same-size copies on one semaphore, so any descriptor of
+    that size drains one)."""
+    n = rows_ref.shape[0]
+    if slab:
+        slab_ref, = slab
+        assert n % 8 == 0, "a chunk's rows are whole 8-row tiles (BR = 8)"
+
+        @pl.when(first)
+        def _load():
+            copy = pltpu.make_async_copy(src_hbm, slab_ref, sem)
+            copy.start()
+            copy.wait()
+
+        def row(r):
+            return slab_ref[pl.ds(ids_ref[0, 0, r], 1), :]
+
+        # eight single-row loads make one aligned (8, lanes) tile store; a
+        # loop over the tiles keeps the body the same size for any chunk
+        def tile(t, carry):
+            base = pl.multiple_of(t * 8, 8)
+            rows_ref[pl.ds(base, 8), :] = jnp.concatenate(
+                [row(base + i) for i in range(8)], axis=0)
+            return carry
+
+        jax.lax.fori_loop(0, n // 8, tile, 0)
+        return
+
     def start(r, carry):
         pltpu.make_async_copy(src_hbm.at[pl.ds(ids_ref[0, 0, r], 1)],
                               rows_ref.at[pl.ds(r, 1)], sem).start()
@@ -119,7 +219,6 @@ def _gather_rows(src_hbm, ids_ref, rows_ref, sem):
                               rows_ref.at[pl.ds(0, 1)], sem).wait()
         return carry
 
-    n = rows_ref.shape[0]
     jax.lax.fori_loop(0, n, start, 0)
     jax.lax.fori_loop(0, n, wait, 0)
 
@@ -190,14 +289,15 @@ def _chunk_tables(nbr, w):
 
 
 def _arena_fwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, x_hbm, out_ref,
-                      rows_ref, sem, *, k: int, ec: int, d_tile: int):
+                      rows_ref, sem, *slab, k: int, ec: int, d_tile: int):
     c = pl.program_id(1)
 
     @pl.when(st_ref[c] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _gather_rows(x_hbm, nbr_ref, rows_ref, sem)
+    _gather_rows(x_hbm, nbr_ref, rows_ref, sem, slab,
+                 (pl.program_id(0) == 0) & (c == 0))
     rows = rows_ref[...]                                  # (BR·Ec, lanes)
     vals = jax.lax.bitcast_convert_type(rows[:, :k], jnp.float32)
     xd = _densify(vals, rows[:, k:2 * k], k, pl.program_id(0) * d_tile,
@@ -207,25 +307,29 @@ def _arena_fwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, x_hbm, out_ref,
 
 def _arena_fwd(block_of, start, nbr, w, x_vals, x_idx, dim: int,
                n_rows: int, interpret):
-    """fp32 (n_rows, dim) Y over the arena: CBSR rows gathered by DMA."""
+    """fp32 (n_rows, dim) Y over the arena: CBSR rows gathered from the
+    resident slab, or by DMA."""
     c, br, ec = nbr.shape
     k = x_vals.shape[1]
     dt, ndt = _d_tiling(dim)
     rows = _cbsr_rows(x_vals, x_idx)
     ids, wts = _chunk_tables(nbr, w)
     ids_spec, w_spec = _chunk_specs(br * ec, 1)
+    scratch, params = _gather_scratch(
+        "drspmm_arena_fwd", rows, br * ec,
+        [((1, br * ec), w.dtype), ((br, dt), jnp.float32)], 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(ndt, c),
         in_specs=[ids_spec, w_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
         out_specs=pl.BlockSpec((br, dt), lambda d, i, blk, st: (blk[i], d)),
-        scratch_shapes=[pltpu.VMEM((br * ec, rows.shape[1]), jnp.int32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=scratch,
     )
     return run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_fwd_kernel, k=k, ec=ec, d_tile=dt),
         grid_spec=grid_spec,
         name="drspmm_arena_fwd",
+        compiler_params=params,
         # fp32 accumulator arena regardless of input dtype (chunk revisits
         # accumulate in the out buffer); the op wrapper casts after gather.
         out_shape=jax.ShapeDtypeStruct((n_rows, dim), jnp.float32),
@@ -234,14 +338,14 @@ def _arena_fwd(block_of, start, nbr, w, x_vals, x_idx, dim: int,
 
 
 def _arena_bwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, gy_hbm, xi_ref,
-                      out_ref, rows_ref, sem, *, k: int, ec: int):
+                      out_ref, rows_ref, sem, *slab, k: int, ec: int):
     c = pl.program_id(0)
 
     @pl.when(st_ref[c] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _gather_rows(gy_hbm, nbr_ref, rows_ref, sem)
+    _gather_rows(gy_hbm, nbr_ref, rows_ref, sem, slab, c == 0)
     br = out_ref.shape[0]
     # the sampled indices depend on the source row only, so the weighted
     # target sum comes first and the sampling runs once per row
@@ -251,26 +355,31 @@ def _arena_bwd_kernel(blk_ref, st_ref, nbr_ref, w_ref, gy_hbm, xi_ref,
 
 def _arena_bwd(block_of, start, tnbr, tw, gy, xi_rows, n_rows: int,
                interpret):
-    """fp32 (n_rows, k) dV over the transposed arena: dY rows by DMA,
-    sampled at each arena row's CBSR indices (``xi_rows``, arena order)."""
+    """fp32 (n_rows, k) dV over the transposed arena: dY rows from the
+    resident slab or by DMA, sampled at each arena row's CBSR indices
+    (``xi_rows``, arena order)."""
     c, br, ec = tnbr.shape
     k = xi_rows.shape[1]
     gyp = _lane_pad(gy.astype(jnp.float32))
     ids, wts = _chunk_tables(tnbr, tw)
     ids_spec, w_spec = _chunk_specs(br * ec, 0)
+    scratch, params = _gather_scratch(
+        "drspmm_arena_bwd", gyp, br * ec,
+        [((1, br * ec), tw.dtype), ((br, k), jnp.int32),
+         ((br, k), jnp.float32)], 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(c,),
         in_specs=[ids_spec, w_spec, pl.BlockSpec(memory_space=pltpu.HBM),
                   pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0))],
         out_specs=pl.BlockSpec((br, k), lambda i, blk, st: (blk[i], 0)),
-        scratch_shapes=[pltpu.VMEM((br * ec, gyp.shape[1]), jnp.float32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=scratch,
     )
     return run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_bwd_kernel, k=k, ec=ec),
         grid_spec=grid_spec,
         name="drspmm_arena_bwd",
+        compiler_params=params,
         out_shape=jax.ShapeDtypeStruct((n_rows, k), jnp.float32),
         interpret=interp,
     )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, gyp,
@@ -278,14 +387,14 @@ def _arena_bwd(block_of, start, tnbr, tw, gy, xi_rows, n_rows: int,
 
 
 def _arena_spmm_kernel(blk_ref, st_ref, nbr_ref, w_ref, x_hbm, out_ref,
-                       rows_ref, sem, *, ec: int):
+                       rows_ref, sem, *slab, ec: int):
     c = pl.program_id(0)
 
     @pl.when(st_ref[c] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _gather_rows(x_hbm, nbr_ref, rows_ref, sem)
+    _gather_rows(x_hbm, nbr_ref, rows_ref, sem, slab, c == 0)
     br, d = out_ref.shape
     g = _dot(_row_select(w_ref[0], br, ec), rows_ref[...])   # (BR, Dp)
     out_ref[...] += g[:, :d]
@@ -298,18 +407,21 @@ def _arena_spmm(block_of, start, nbr, w, x, n_rows: int, interpret):
     xp = _lane_pad(x.astype(jnp.float32))
     ids, wts = _chunk_tables(nbr, w)
     ids_spec, w_spec = _chunk_specs(br * ec, 0)
+    scratch, params = _gather_scratch(
+        "spmm_arena", xp, br * ec,
+        [((1, br * ec), w.dtype), ((br, d), jnp.float32)], 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(c,),
         in_specs=[ids_spec, w_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
         out_specs=pl.BlockSpec((br, d), lambda i, blk, st: (blk[i], 0)),
-        scratch_shapes=[pltpu.VMEM((br * ec, xp.shape[1]), jnp.float32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=scratch,
     )
     return run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_spmm_kernel, ec=ec),
         grid_spec=grid_spec,
         name="spmm_arena",
+        compiler_params=params,
         out_shape=jax.ShapeDtypeStruct((n_rows, d), jnp.float32),
         interpret=interp,
     )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, xp), interpret)
@@ -613,13 +725,13 @@ def drspmm_bwd_learnable_fused(fused_t: FusedELL, nnz: int,
 
 
 def _arena_dw_kernel(blk_ref, nbr_ref, x_hbm, gy_ref, out_ref, rows_ref, sem,
-                     *, k: int, ec: int):
+                     *slab, k: int, ec: int):
     """Per-slot sampled dot: out[0, 0, b·Ec + e] = Σ_t dY[row_b, idx[n, t]] ·
     vals[n, t] with n = nbr[b, e].  Same gather as the forward with the roles
     of weight and value swapped (kernels/learnable.py); the scatter of slot
     contributions into canonical w order happens OUTSIDE the kernel (one XLA
     scatter — TPUs have no fast in-kernel scatter)."""
-    _gather_rows(x_hbm, nbr_ref, rows_ref, sem)
+    _gather_rows(x_hbm, nbr_ref, rows_ref, sem, slab, pl.program_id(0) == 0)
     rows = rows_ref[...]
     gy = gy_ref[...].astype(jnp.float32)              # (BR, D) chunk dY rows
     br, d = gy.shape
@@ -652,6 +764,9 @@ def drspmm_dw_learnable_fused(fused: FusedELL, gy_arena: jax.Array,
     d = gy_arena.shape[1]
     rows = _cbsr_rows(x_vals, x_idx)
     ids, _ = _chunk_tables(fused.nbr, fused.w)
+    scratch, params = _gather_scratch(
+        "drspmm_arena_dw", rows, br * ec,
+        [((br, d), gy_arena.dtype), ((1, br * ec), jnp.float32)], 1)
 
     def idx(i, blk):
         return (i, 0, 0)
@@ -664,13 +779,13 @@ def drspmm_dw_learnable_fused(fused: FusedELL, gy_arena: jax.Array,
                   pl.BlockSpec(memory_space=pltpu.HBM),
                   pl.BlockSpec((br, d), lambda i, blk: (blk[i], 0))],
         out_specs=pl.BlockSpec((1, 1, br * ec), idx),
-        scratch_shapes=[pltpu.VMEM((br * ec, rows.shape[1]), jnp.int32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=scratch,
     )
     out = run_pallas(lambda interp: pl.pallas_call(
         functools.partial(_arena_dw_kernel, k=k, ec=ec),
         grid_spec=grid_spec,
         name="drspmm_arena_dw",
+        compiler_params=params,
         out_shape=jax.ShapeDtypeStruct((c, 1, br * ec), jnp.float32),
         interpret=interp,
     )(jnp.asarray(fused.block_of), ids, rows, gy_arena), interpret)

@@ -30,9 +30,16 @@ SHAPES = [
 ]
 
 
+# every Pallas parity test below runs on both gather paths of the arena
+# kernels (conftest.py ``gather_path``)
+PATHS = pytest.mark.parametrize("gather_path", ["vmem", "dma"],
+                                indirect=True)
+
+
+@PATHS
 @pytest.mark.parametrize("n_dst,n_src,nnz,d,k", SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_drspmm_fwd_vs_oracle(n_dst, n_src, nnz, d, k, dtype):
+def test_drspmm_fwd_vs_oracle(n_dst, n_src, nnz, d, k, dtype, gather_path):
     rng = np.random.default_rng(n_dst + d)
     adj, adj_t = make_graph(rng, n_dst, n_src, nnz)
     x = rng.normal(size=(n_src, d)).astype(np.float32)
@@ -45,8 +52,9 @@ def test_drspmm_fwd_vs_oracle(n_dst, n_src, nnz, d, k, dtype):
                                rtol=tol, atol=tol)
 
 
+@PATHS
 @pytest.mark.parametrize("n_dst,n_src,nnz,d,k", SHAPES[:4])
-def test_drspmm_bwd_vs_oracle(n_dst, n_src, nnz, d, k):
+def test_drspmm_bwd_vs_oracle(n_dst, n_src, nnz, d, k, gather_path):
     rng = np.random.default_rng(7 * n_dst + d)
     adj, adj_t = make_graph(rng, n_dst, n_src, nnz)
     x = rng.normal(size=(n_src, d)).astype(np.float32)
@@ -75,8 +83,9 @@ def test_xla_backend_matches_pallas(n_dst, n_src, nnz, d, k):
                                rtol=1e-5, atol=1e-5)
 
 
+@PATHS
 @pytest.mark.parametrize("n,d", [(32, 16), (64, 64), (40, 128)])
-def test_dense_spmm_kernel(n, d):
+def test_dense_spmm_kernel(n, d, gather_path):
     rng = np.random.default_rng(n + d)
     adj, adj_t = make_graph(rng, n, n, n * 6)
     x = rng.normal(size=(n, d)).astype(np.float32)
@@ -117,3 +126,47 @@ def test_gradient_zero_outside_cbsr_support():
     xs = drelu(x, 4)
     mask = np.asarray(xs != 0)
     assert np.all(np.asarray(g)[~mask] == 0.0)
+
+
+def test_gather_path_boundary(monkeypatch):
+    """The path follows the slab's bytes: a slab exactly at the budget is
+    VMEM-resident, one row over it takes per-row DMAs, and both agree."""
+    from _gather import path_of
+    from repro.graphs.ell import fuse_bucketed
+    rng = np.random.default_rng(11)
+    n = 40                                 # whole 8-row tiles
+    adj, _ = make_graph(rng, 24, n, 150)
+    fused = fuse_bucketed(adj)
+    x = jnp.asarray(rng.normal(size=(n + 1, 16)).astype(np.float32))
+    monkeypatch.setattr(K, "_slab_budget",
+                        lambda other_bytes: n * K.LANES * 4)
+    y_at, path_at = path_of(K.spmm_dense_fused, fused, x[:n])
+    y_over, path_over = path_of(K.spmm_dense_fused, fused, x)
+    assert (path_at, path_over) == ("vmem", "dma")
+    np.testing.assert_array_equal(np.asarray(y_at), np.asarray(y_over))
+    y_ref = ref.spmm_dense_ref(adj, x[:n])
+    np.testing.assert_allclose(
+        np.take(np.asarray(y_at), np.asarray(fused.gather), 0),
+        np.asarray(y_ref),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_slab_budget_follows_the_device(monkeypatch):
+    """Half the core's VMEM less the call's other buffers; with no TPU
+    described the conservative capacity stands in."""
+    assert K._vmem_capacity() == K.VMEM_FALLBACK_BYTES   # CPU host
+    monkeypatch.setattr(K, "_vmem_capacity", lambda: 128 << 20)
+    assert K._slab_budget(1 << 20) == (64 << 20) - (1 << 20)
+
+
+def test_undescribed_tpu_is_refused_not_assumed(monkeypatch):
+    """On a TPU host a chip ``get_tpu_info`` cannot describe raises: the
+    16 MiB stand-in would quietly put the Table-1 slabs on the DMA path."""
+    def undescribed():
+        raise ValueError("unknown device kind")
+
+    monkeypatch.setattr(K.pltpu, "get_tpu_info", undescribed)
+    assert K._vmem_capacity() == K.VMEM_FALLBACK_BYTES   # CPU host
+    monkeypatch.setattr(K.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="unknown device kind"):
+        K._vmem_capacity()

@@ -118,9 +118,14 @@ def setup_mixed(seed=7, n_dst=41, n_src=37, d=16, k=4):
     return fwd, bwd, nnz, w, c, d, dense_y
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_backend_parity_fwd_and_grads(backend):
-    """Every backend matches the dense oracle: forward, dw, and dx."""
+@pytest.mark.parametrize("backend,gather_path", [
+    pytest.param(b, p, id=b if p is None else f"{b}-{p}")
+    for b in BACKENDS
+    for p in (("vmem", "dma") if b.startswith("pallas") else (None,))],
+    indirect=["gather_path"])
+def test_backend_parity_fwd_and_grads(backend, gather_path):
+    """Every backend matches the dense oracle: forward, dw, and dx; the
+    Pallas ones on both gather paths of the arena kernels."""
     fwd, bwd, nnz, w, c, d, dense_y = setup_mixed()
 
     def loss(wv, xv):

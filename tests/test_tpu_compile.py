@@ -10,9 +10,13 @@ super-arena of that partition (about 18.5k chunks of 8×4 neighbour slots).
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and under
-pytest-xdist only the worker running this file does.
+pytest-xdist only the worker running this file does.  Each compile is
+traced under an abstract mesh of the described chip, so that what the
+kernels ask of the device (``pltpu.get_tpu_info``: the VMEM that decides
+the arena gather's path) is the v5e's, as in a run on the chip.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -23,6 +27,8 @@ import pytest
 from repro.graphs.ell import FusedELL
 from repro.kernels import drspmm as K
 from repro.kernels.drelu_topk import drelu_pallas
+
+from _gather import path_of
 
 N_SRC = 9816 + 9100          # type-concat CBSR slab (cells + nets)
 N_ARENA = 28_000             # super-arena output rows
@@ -70,28 +76,69 @@ def _super_arena(sharding) -> FusedELL:
 def _compile(fn, *args):
     """Native compile for the described chip; the HLO must carry the
     Mosaic kernel (an interpreted kernel would lower to plain HLO)."""
-    compiled = jax.jit(fn).lower(*args).compile()
+    devices = {d for a in jax.tree.leaves(args) for d in a.sharding.device_set}
+    mesh = jax.sharding.Mesh(np.array(sorted(devices, key=lambda d: d.id)),
+                             ("chip",))
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
 
+def _compiled_path(fn, *args) -> str:
+    """Compile ``fn`` and name the gather path its arena kernel took."""
+    return path_of(_compile, fn, *args)[1]
+
+
 @pytest.mark.parametrize("hidden", [64, 128, 256])   # 256: D-tiled grid
 def test_drspmm_fwd_multi_compiles(one_chip, hidden):
-    _compile(lambda f, v, i: K.drspmm_fwd_multi(f, v, i, hidden,
-                                                interpret=False),
-             _super_arena(one_chip),
-             _sds(one_chip, (N_SRC, K_KEEP), jnp.float32),
-             _sds(one_chip, (N_SRC, K_KEEP), jnp.int32))
+    """The type-concat CBSR slab (9.7 MB) is VMEM-resident on a v5e."""
+    assert _compiled_path(
+        lambda f, v, i: K.drspmm_fwd_multi(f, v, i, hidden, interpret=False),
+        _super_arena(one_chip),
+        _sds(one_chip, (N_SRC, K_KEEP), jnp.float32),
+        _sds(one_chip, (N_SRC, K_KEEP), jnp.int32)) == "vmem"
 
 
-@pytest.mark.parametrize("hidden", [64, 128])
+@pytest.mark.parametrize("hidden", [64, 128, 256])
 def test_drspmm_bwd_multi_compiles(one_chip, hidden):
-    _compile(lambda f, rows, gy, i: K.drspmm_bwd_multi(f, rows, gy, i,
-                                                       interpret=False),
-             _super_arena(one_chip),
-             _sds(one_chip, (N_ARENA,), jnp.int32),
-             _sds(one_chip, (N_ARENA, hidden), jnp.float32),
-             _sds(one_chip, (N_SRC, K_KEEP), jnp.int32))
+    """So is the lane-padded dY (14.3 MB at hidden 128, 28.7 MB at 256)."""
+    assert _compiled_path(
+        lambda f, rows, gy, i: K.drspmm_bwd_multi(f, rows, gy, i,
+                                                  interpret=False),
+        _super_arena(one_chip),
+        _sds(one_chip, (N_ARENA,), jnp.int32),
+        _sds(one_chip, (N_ARENA, hidden), jnp.float32),
+        _sds(one_chip, (N_SRC, K_KEEP), jnp.int32)) == "vmem"
+
+
+def test_drspmm_fwd_over_budget_compiles_on_dma_path(one_chip):
+    """A source slab past the VMEM budget (140k type-concat rows, 71.7 MB
+    against half of the v5e's 128 MiB) keeps the per-row DMA gather, whose
+    VMEM does not grow with the source."""
+    n_src = 140_000
+    arena = dataclasses.replace(_super_arena(one_chip), n_src=n_src)
+    assert _compiled_path(
+        lambda f, v, i: K.drspmm_fwd_multi(f, v, i, 64, interpret=False),
+        arena, _sds(one_chip, (n_src, K_KEEP), jnp.float32),
+        _sds(one_chip, (n_src, K_KEEP), jnp.int32)) == "dma"
+
+
+def test_widest_fused_chunk_compiles(one_chip):
+    """A fused chunk of 8 × 16 slots, the widest ``pick_chunk`` makes:
+    its rows leave the slab through the same loop of 8-row tiles as the
+    8 × 4 chunk's, sixteen trips in place of four."""
+    arena = dataclasses.replace(
+        _super_arena(one_chip), chunk=16,
+        nbr=_sds(one_chip, (N_CHUNKS // 4, BR, 16), jnp.int32),
+        w=_sds(one_chip, (N_CHUNKS // 4, BR, 16), jnp.float32),
+        block_of=_sds(one_chip, (N_CHUNKS // 4,), jnp.int32),
+        start=_sds(one_chip, (N_CHUNKS // 4,), jnp.int32),
+        rel=_sds(one_chip, (N_CHUNKS // 4,), jnp.int32))
+    assert _compiled_path(
+        lambda f, v, i: K.drspmm_fwd_multi(f, v, i, 64, interpret=False),
+        arena, _sds(one_chip, (N_SRC, K_KEEP), jnp.float32),
+        _sds(one_chip, (N_SRC, K_KEEP), jnp.int32)) == "vmem"
 
 
 def test_dense_tier_fwd_compiles(one_chip):
@@ -137,7 +184,6 @@ def test_default_branch_is_native_for_tpu(one_chip):
 
 
 def _eid_arena(sharding) -> FusedELL:
-    import dataclasses
     return dataclasses.replace(
         _super_arena(sharding), rel=None,
         eid=_sds(sharding, (N_CHUNKS, BR, EC), jnp.int32))
@@ -147,12 +193,13 @@ def test_learnable_dw_compiles(one_chip):
     """The per-slot dL/dw kernel.  The learnable forward and dx run the
     fixed-weight kernels above on weights gathered into arena order by XLA
     (DESIGN.md §8.2), so they add no kernel of their own."""
-    _compile(lambda f, g, v, i: K.drspmm_dw_learnable_fused(
-                 f, g, v, i, interpret=False),
-             _eid_arena(one_chip),
-             _sds(one_chip, (N_ARENA, 64), jnp.float32),
-             _sds(one_chip, (N_SRC, K_KEEP), jnp.float32),
-             _sds(one_chip, (N_SRC, K_KEEP), jnp.int32))
+    assert _compiled_path(
+        lambda f, g, v, i: K.drspmm_dw_learnable_fused(f, g, v, i,
+                                                       interpret=False),
+        _eid_arena(one_chip),
+        _sds(one_chip, (N_ARENA, 64), jnp.float32),
+        _sds(one_chip, (N_SRC, K_KEEP), jnp.float32),
+        _sds(one_chip, (N_SRC, K_KEEP), jnp.int32)) == "vmem"
 
 
 @pytest.mark.parametrize("hidden", [128, 256])
