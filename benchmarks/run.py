@@ -3,7 +3,6 @@
     PYTHONPATH=src python -m benchmarks.run [--fast]
 
 Output: ``name,us_per_call,derived`` CSV rows.
-  * bench_drspmm   — Fig. 11 (DR-SpMM fwd/bwd vs dense baseline, K × dim)
   * bench_parallel — Fig. 12 / Table 3 (kernel vs parallel-scheduling savings)
   * bench_kvalues  — Fig. 10 (K sweep: correlations + step time)
   * bench_table2   — Table 2 (DR-CircuitGNN vs GCN/SAGE/GAT correlations)
@@ -27,10 +26,9 @@ def main() -> None:
     scale = 0.04 if args.fast else 0.08
     epochs = 2 if args.fast else 6
 
-    from benchmarks import (bench_drspmm, bench_kvalues, bench_lm,
-                            bench_parallel, bench_table2)
+    from benchmarks import (bench_kvalues, bench_lm, bench_parallel,
+                            bench_table2)
     suites = {
-        "drspmm": lambda: bench_drspmm.bench(scale=scale),
         "parallel": lambda: bench_parallel.bench(scale=scale),
         "kvalues": lambda: bench_kvalues.bench(scale=max(scale * 0.6, 0.03),
                                                epochs=max(epochs // 2, 2)),

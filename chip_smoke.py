@@ -13,9 +13,10 @@ on one partition and an ``evaluate`` on another.  It checks that
 * the compiled step calls Mosaic kernels (``tpu_custom_call``) and no kernel
   on the TPU branch of the step is interpreted,
 * the losses are finite and fall,
-* only the fused Pallas executors were dispatched (no per-bucket or XLA
-  downgrade),
-* a forward agrees with the dense oracle backend within ``PARITY_TOL``.
+* only the Pallas plan-path executors were dispatched,
+* a forward agrees within ``PARITY_TOL`` with the ``xla_fused`` executor
+  of the same plan: the same arena tables walked in plain XLA on the chip,
+  independent of the Pallas kernels.
 
 ``--four-chips`` instead trains ``n_shards=4`` (DESIGN.md §12) on the four
 devices of one host and compares losses and a forward with the
@@ -39,9 +40,11 @@ import time
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-# predictions are sigmoid outputs in [0, 1]; Pallas vs dense oracle, both
-# at f32 ("highest") matmul precision
-PARITY_TOL = 1e-3
+# predictions are sigmoid outputs in [0, 1]; Pallas vs the XLA executor,
+# both at f32 ("highest") matmul precision.  On one TPU v5e the sound
+# kernels read 1.19e-7; the same check with the Pallas side's forward at
+# bf16 matmul passes reads 7.99e-3.
+PARITY_TOL = 1e-5
 # sharded vs single-device plan path: same math, different summation order
 SHARD_TOL = 1e-4
 N_STEPS = 5
@@ -185,16 +188,16 @@ def one_chip(graphs) -> None:
     check(not bad, f"dispatches off the fused Pallas plan path: {bad}")
     log(f"peak_bytes_in_use={peak_bytes(jax.devices()[0])}")
 
-    dense_cfg = dataclasses.replace(trainer.mp_cfg, backend="dense")
+    xla_cfg = dataclasses.replace(trainer.mp_cfg, backend="xla_fused")
     with jax.default_matmul_precision("highest"):
         y = jax.jit(lambda p: drcircuitgnn_forward(
             p, g, trainer.mp_cfg, trainer.spec))(trainer.params)
         y_ref = jax.jit(lambda p: drcircuitgnn_forward(
-            p, g, dense_cfg, trainer.spec))(trainer.params)
+            p, g, xla_cfg, trainer.spec))(trainer.params)
     err = max_abs_diff(y, y_ref)
-    log(f"forward parity vs dense backend: max_abs_diff={err!r} "
+    log(f"forward parity vs the xla_fused executor: max_abs_diff={err!r} "
         f"tol={PARITY_TOL}")
-    check(err <= PARITY_TOL, "forward disagrees with the dense oracle")
+    check(err <= PARITY_TOL, "forward disagrees with the xla_fused executor")
 
 
 def four_chips(graphs) -> None:

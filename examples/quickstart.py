@@ -29,9 +29,10 @@ print(f"D-ReLU(k={k}): nnz per row =",
 c = cbsr_from_dense(x_sparse, k)
 print("CBSR:", c.values.shape, c.idx.shape)
 
-# 4. DR-SpMM over the 'near' adjacency (Pallas kernel, interpret on CPU)
+# 4. DR-SpMM over the 'near' adjacency (the Pallas arena kernels,
+#    interpreted on CPU)
 es = graph.edges["near"]
-y = ops.drspmm(es.adj, es.adj_t, c.values, c.idx, 64, backend="pallas")
+y = ops.drspmm(es.adj, es.adj_t, c.values, c.idx, 64, backend="pallas_fused")
 y_ref = ref.drspmm_fwd_ref(es.adj, c.values, c.idx, 64)
 print("DR-SpMM max|err| vs dense oracle:",
       float(jnp.abs(y - y_ref).max()))

@@ -13,7 +13,7 @@ each DR-SpMM is the custom VJP in kernels/ops.py.
 
 Two execution strategies share the math:
 
-* **plan path** (default on the fused backends): ALL edge-type directions
+* **plan path** (the default): ALL edge-type directions
   of the layer run as ONE dispatch per direction-group over a
   :class:`~repro.graphs.ell.RelationPlan` super-arena
   (``ops.drspmm_multi`` — one forward ``pallas_call``, one transposed
@@ -21,9 +21,9 @@ Two execution strategies share the math:
   (``graph.plan``, attached by the collator / ``with_plan``) or is built
   lazily and memoized when the graph is concrete.  Per-type D-ReLU/CBSR is
   computed once and shared by every relation consuming that type.
-* **serial path** (the reference, and the fallback for per-bucket/dense
-  backends, dense aggregation, or traced graphs without a plan): the
-  per-relation loop of PR 1–4, one ``drspmm``/``spmm`` per edge type —
+* **serial path** (the reference, and the fallback for dense aggregation
+  or graphs without a plan): the per-relation loop, one
+  ``drspmm``/``spmm`` per edge type —
   but the per-type D-ReLU/CBSR is shared across relations here too
   (``near`` and ``pin`` both consume the cell slab; 2 sparsifications per
   layer, not 3 — tests/test_backbone.py pins the dispatch count).
@@ -57,12 +57,12 @@ class HeteroMPConfig:
     k_cell: int = 16          # D-ReLU K for cell-sourced embeddings
     k_net: int = 16           # D-ReLU K for net-sourced embeddings
     # "pallas_fused" on TPU (one kernel dispatch per edge-type direction,
-    # DESIGN.md §1), "xla_fused" on CPU — the same fused arena in plain XLA.
+    # DESIGN.md §1), "xla_fused" on CPU — the same arena in plain XLA.
     backend: ops.Backend = ops.DEFAULT_BACKEND
     use_drelu: bool = True    # False => dense baseline path (plain SpMM)
     drelu_backend: str = "topk"   # topk (lax.top_k) | pallas (binary search)
-    # Relation-fused layer dispatch (DESIGN.md §9): on the fused backends a
-    # layer's whole message passing runs as ONE dispatch per direction-group
+    # Relation-fused layer dispatch (DESIGN.md §9): a layer's whole
+    # message passing runs as ONE dispatch per direction-group
     # via the graph's RelationPlan.  False pins the serial per-direction
     # reference loop (exact parity: tests/test_relation_plan.py).
     use_plan: bool = True
@@ -144,13 +144,11 @@ def _sparsify_types(x_cell: jax.Array, x_net: jax.Array,
 
 
 def plan_applicable(cfg: HeteroMPConfig, hidden: int) -> bool:
-    """True iff the plan path can serve this config: fused backend (the
-    per-bucket/dense names keep their reference semantics) and CBSR
-    aggregation on both node types (dense SpMM stays serial).  The single
-    gate shared by :func:`_plan_for` and the trainer's plan attachment, so
-    the two cannot drift."""
+    """True iff the plan path can serve this config: CBSR aggregation on
+    both node types (dense SpMM stays serial).  The single gate shared by
+    :func:`_plan_for` and the trainer's plan attachment, so the two cannot
+    drift."""
     return (cfg.use_plan and cfg.use_drelu
-            and cfg.backend in ("pallas_fused", "xla_fused")
             and cfg.k_cell < hidden and cfg.k_net < hidden)
 
 

@@ -21,8 +21,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.graphs.ell import (BucketedELL, RelationPlan, build_relation_plan,
-                              degree_stats, pack_ell_pair)
+from repro.graphs.ell import (BucketedELL, FusedELL, RelationPlan,
+                              build_relation_plan, degree_stats,
+                              fuse_bucketed, pack_ell_pair)
 from repro.obs import span
 from repro.sharding.plan_shard import (ShardedRelationPlan,
                                        shard_relation_plan)
@@ -36,8 +37,10 @@ EDGE_SCHEMA = {"near": ("cell", "cell"), "pin": ("cell", "net"),
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class EdgeSet:
-    adj: BucketedELL      # A   (n_dst x n_src)
-    adj_t: BucketedELL    # Aᵀ  (n_src x n_dst)
+    # BucketedELL as packed; FusedELL once fused for a traced step
+    # (collated batches, ``with_fused_edges``)
+    adj: BucketedELL | FusedELL      # A   (n_dst x n_src)
+    adj_t: BucketedELL | FusedELL    # Aᵀ  (n_src x n_dst)
 
 
 @jax.tree_util.register_dataclass
@@ -108,6 +111,17 @@ def with_plan(graph: CircuitGraph) -> CircuitGraph:
     if graph.plan is not None:
         return graph
     return dataclasses.replace(graph, plan=relation_plan_of(graph))
+
+
+def with_fused_edges(graph: CircuitGraph) -> CircuitGraph:
+    """``graph`` with every edge set's packings fused host-side
+    (:func:`fuse_bucketed`, memoized per packing) — the form the serial
+    per-relation path takes inside a jitted step, where the ops accept no
+    :class:`BucketedELL`."""
+    edges = {et: EdgeSet(adj=fuse_bucketed(es.adj),
+                         adj_t=fuse_bucketed(es.adj_t))
+             for et, es in graph.edges.items()}
+    return dataclasses.replace(graph, edges=edges)
 
 
 # (id(graph), n_shards)-keyed memo, weakref-guarded like _PLAN_CACHE: the

@@ -43,7 +43,7 @@ from repro.graphs.circuit import (CircuitGraph, EDGE_SCHEMA, EDGE_TYPES,
 from repro.graphs.ell import (DEFAULT_BOUNDS, DENSE_TIER_AREA,
                               DENSE_TIER_NNZ, FusedELL, RelationPlan,
                               arena_stats, build_relation_plan, ell_to_coo,
-                              fuse_bucketed, pack_ell, pack_ell_pair,
+                              fuse_bucketed, pack_ell,
                               pack_fused_eid_pair, pad_fused_arena, _round_up)
 from repro.obs.metrics import DEFAULT_REGISTRY as _METRICS
 
@@ -202,9 +202,9 @@ class MemberSlice:
 class CollatedBatch:
     """One collated dispatch unit.
 
-    ``graph`` is a regular :class:`CircuitGraph` (padded sizes); with
-    ``fused=True`` its edge sets hold pre-packed :class:`FusedELL` arenas so
-    the fused executors run even when the graph is a traced jit argument.
+    ``graph`` is a regular :class:`CircuitGraph` (padded sizes) whose edge
+    sets hold pre-packed :class:`FusedELL` arenas, so the ops run even when
+    the graph is a traced jit argument.
     ``cell_weight`` holds 1/(n_real·n_cell_i) on member i's rows and 0 on
     padding — ``Σ w·(pred−y)²`` over the batch equals the mean of per-graph
     mean-MSE losses, so batched gradients match the per-graph loop.
@@ -292,7 +292,6 @@ def _chunk_for(chunk, etype: str) -> Optional[int]:
 
 
 def collate_graphs(graphs: Sequence[CircuitGraph], *,
-                   fused: bool = True,
                    quantize: bool = True,
                    node_bits: int = NODE_GRID_BITS,
                    arena_bits: int = ARENA_GRID_BITS,
@@ -300,19 +299,14 @@ def collate_graphs(graphs: Sequence[CircuitGraph], *,
                    layout: Optional[BucketLayout] = None,
                    n_real: Optional[int] = None,
                    with_eids: bool = False,
-                   with_plan: Optional[bool] = None,
+                   with_plan: bool = True,
                    bounds: Sequence[int] = DEFAULT_BOUNDS) -> CollatedBatch:
     """Merge member graphs into one block-diagonal :class:`CircuitGraph`.
 
     Parameters
     ----------
-    fused : pre-pack each edge-type direction as a :class:`FusedELL` arena
-        (the serve/train hot path — fused executors run with the graph
-        traced).  ``False`` packs plain :class:`BucketedELL` pairs: the
-        exact block-diagonal graph, usable under every backend (parity
-        tests).
-    quantize : pad member node slabs and (with ``fused``) arena dims up the
-        bucket grid; ``False`` gives the exact-size collation.
+    quantize : pad member node slabs and arena dims up the bucket grid;
+        ``False`` gives the exact-size collation.
     chunk : pin the fused arenas' chunk width (int, or per-edge-type dict);
         ``None`` lets ``fuse_bucketed`` pick per packing from the degree
         histogram.
@@ -326,18 +320,18 @@ def collate_graphs(graphs: Sequence[CircuitGraph], *,
         edge counts), so the collated batch can carry learnable per-edge
         weights through ``ops.drspmm_learnable`` — the batch weight vector
         is the concatenation of the members' canonical vectors
-        (:meth:`CollatedBatch.concat_edge_weights`).  Requires ``fused``.
-        With ``quantize``, the per-edge-type nnz is rounded up the arena
-        grid (and floored at the bucket's running max when a ``layout``
-        tracks it): the traced weight vector is zero-padded to that size,
-        so mixed learnable-weight streams add one jit entry per GRID POINT
-        instead of one per distinct nnz.
+        (:meth:`CollatedBatch.concat_edge_weights`).  With ``quantize``,
+        the per-edge-type nnz is rounded up the arena grid (and floored at
+        the bucket's running max when a ``layout`` tracks it): the traced
+        weight vector is zero-padded to that size, so mixed
+        learnable-weight streams add one jit entry per GRID POINT instead
+        of one per distinct nnz.
     with_plan : attach a :class:`RelationPlan` super-arena pair to the
         collated graph (``batch.graph.plan``) so hetero layers run ONE
         dispatch per direction-group even with the graph traced
-        (DESIGN.md §9).  Defaults to ``fused``; the plan's per-relation
-        segments are quantized/floored under the same ``layout`` as the
-        per-edge-type arenas, so plan signatures are bucket-stable.
+        (DESIGN.md §9).  The plan's per-relation segments are
+        quantized/floored under the same ``layout`` as the per-edge-type
+        arenas, so plan signatures are bucket-stable.
     """
     assert graphs, "collate_graphs needs at least one member"
     n_real = len(graphs) if n_real is None else n_real
@@ -372,10 +366,6 @@ def collate_graphs(graphs: Sequence[CircuitGraph], *,
                 1.0 / (n_real * m.n_cell)
 
     # --- merged COO per edge type, member weights carried through ---
-    assert not (with_eids and not fused), "with_eids requires fused collation"
-    if with_plan is None:
-        with_plan = fused
-    assert not (with_plan and not fused), "with_plan requires fused collation"
     off_of = {"cell": [m.cell_off for m in members],
               "net": [m.net_off for m in members]}
     edges = {}
@@ -398,77 +388,73 @@ def collate_graphs(graphs: Sequence[CircuitGraph], *,
         w = np.concatenate(ws)
         n_dst, n_src = sizes_pad[d_t], sizes_pad[s_t]
         coo_of[et] = (dst, src, w)
-        if fused:
-            # one degree-bucketed pack per direction, SHARED by the
-            # per-edge-type arena and the relation plan (fusing at each
-            # consumer's chunk width is memoized per (packing, width))
-            bucketed = {"fwd": pack_ell(dst, src, w, n_dst, n_src, bounds),
-                        "bwd": pack_ell(src, dst, w, n_src, n_dst, bounds)}
-            bucketed_of[et] = (bucketed["fwd"], bucketed["bwd"])
-            packed = {}
-            for dname in ("fwd", "bwd"):
-                ck = layout.chunk.get((et, dname)) if layout else None
-                if ck is None:
-                    ck = _chunk_for(chunk, et)
-                a = fuse_bucketed(bucketed[dname], chunk=ck)
+        # one degree-bucketed pack per direction, SHARED by the
+        # per-edge-type arena and the relation plan (fusing at each
+        # consumer's chunk width is memoized per (packing, width))
+        bucketed = {"fwd": pack_ell(dst, src, w, n_dst, n_src, bounds),
+                    "bwd": pack_ell(src, dst, w, n_src, n_dst, bounds)}
+        bucketed_of[et] = (bucketed["fwd"], bucketed["bwd"])
+        packed = {}
+        for dname in ("fwd", "bwd"):
+            ck = layout.chunk.get((et, dname)) if layout else None
+            if ck is None:
+                ck = _chunk_for(chunk, et)
+            a = fuse_bucketed(bucketed[dname], chunk=ck)
+            if layout is not None:
+                layout.chunk.setdefault((et, dname), a.chunk)
+            # Pack-time arena efficiency gauges (DESIGN.md §11): cheap
+            # — static fields and bucket shapes only, no array scans —
+            # and labeled by (etype, dir), a bounded cardinality.
+            st = arena_stats(a, bucketed[dname])
+            for gname in ("fill_ratio", "padded_slots", "slots",
+                          "chunk", "slot_saving"):
+                _METRICS.set(f"arena.{gname}", st[gname],
+                             etype=et, dir=dname)
+            if quantize:
+                a = _quantize_arena(a, arena_bits, bounds, layout,
+                                    (et, dname))
+            packed[dname] = a
+        if with_eids:
+            # Batch-canonical edge ids: member node-id blocks are
+            # disjoint and increasing, so the batch dst-stable sort is
+            # the concatenation of the members' canonical orders —
+            # member i's ids are its own canonical ids + Σ_{j<i} nnz_j.
+            # Member weights are all non-zero (ell_to_coo masks), so the
+            # eid packing sorts/chunks identically to the weight packing
+            # and the eid table drops straight onto the weight arena.
+            efwd, ebwd, _order, et_nnz = pack_fused_eid_pair(
+                dst, src, n_dst, n_src, bounds,
+                chunk=(packed["fwd"].chunk, packed["bwd"].chunk))
+            for dname, ea in (("fwd", efwd), ("bwd", ebwd)):
+                a = packed[dname]
+                if quantize:
+                    ea = _pad_fused_arena(ea, a.n_chunks,
+                                          a.n_arena_rows)
+                assert ea.nbr.shape == a.nbr.shape, (et, dname)
+                packed[dname] = dataclasses.replace(
+                    a, eid=np.asarray(ea.eid))
+            # Shape-bucketed nnz (ROADMAP): the learnable weight vector
+            # is a TRACED operand sized by nnz, so a distinct nnz per
+            # batch means one jit entry per batch.  Round it up the
+            # arena grid (floored at the bucket's running max) and let
+            # concat_edge_weights zero-pad — padded ids are never
+            # gathered, so the pad slots are inert.
+            nnz_pad = et_nnz
+            if quantize:
+                nnz_pad = quantize_up(et_nnz, arena_bits, minimum=8)
                 if layout is not None:
-                    layout.chunk.setdefault((et, dname), a.chunk)
-                # Pack-time arena efficiency gauges (DESIGN.md §11): cheap
-                # — static fields and bucket shapes only, no array scans —
-                # and labeled by (etype, dir), a bounded cardinality.
-                st = arena_stats(a, bucketed[dname])
-                for gname in ("fill_ratio", "padded_slots", "slots",
-                              "chunk", "slot_saving"):
-                    _METRICS.set(f"arena.{gname}", st[gname],
-                                 etype=et, dir=dname)
-                if quantize:
-                    a = _quantize_arena(a, arena_bits, bounds, layout,
-                                        (et, dname))
-                packed[dname] = a
-            if with_eids:
-                # Batch-canonical edge ids: member node-id blocks are
-                # disjoint and increasing, so the batch dst-stable sort is
-                # the concatenation of the members' canonical orders —
-                # member i's ids are its own canonical ids + Σ_{j<i} nnz_j.
-                # Member weights are all non-zero (ell_to_coo masks), so the
-                # eid packing sorts/chunks identically to the weight packing
-                # and the eid table drops straight onto the weight arena.
-                efwd, ebwd, _order, et_nnz = pack_fused_eid_pair(
-                    dst, src, n_dst, n_src, bounds,
-                    chunk=(packed["fwd"].chunk, packed["bwd"].chunk))
-                for dname, ea in (("fwd", efwd), ("bwd", ebwd)):
-                    a = packed[dname]
-                    if quantize:
-                        ea = _pad_fused_arena(ea, a.n_chunks,
-                                              a.n_arena_rows)
-                    assert ea.nbr.shape == a.nbr.shape, (et, dname)
-                    packed[dname] = dataclasses.replace(
-                        a, eid=np.asarray(ea.eid))
-                # Shape-bucketed nnz (ROADMAP): the learnable weight vector
-                # is a TRACED operand sized by nnz, so a distinct nnz per
-                # batch means one jit entry per batch.  Round it up the
-                # arena grid (floored at the bucket's running max) and let
-                # concat_edge_weights zero-pad — padded ids are never
-                # gathered, so the pad slots are inert.
-                nnz_pad = et_nnz
-                if quantize:
-                    nnz_pad = quantize_up(et_nnz, arena_bits, minimum=8)
-                    if layout is not None:
-                        floor = layout.min_nnz.get(et)
-                        if floor is None:   # first batch: headroom, like
-                            floor = quantize_up(   # the chunk-count floors
-                                int(np.ceil(et_nnz * ARENA_HEADROOM)),
-                                arena_bits, minimum=8)
-                        nnz_pad = max(nnz_pad, floor)
-                        layout.min_nnz[et] = nnz_pad
-                edge_nnz[et] = nnz_pad
-                edge_nnz_exact[et] = et_nnz
-                edge_eid_offsets[et] = tuple(
-                    int(o) for o in np.cumsum([0] + m_nnz[:-1]))
-            adj, adj_t = packed["fwd"], packed["bwd"]
-        else:
-            adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src, bounds)
-        edges[et] = EdgeSet(adj=adj, adj_t=adj_t)
+                    floor = layout.min_nnz.get(et)
+                    if floor is None:   # first batch: headroom, like
+                        floor = quantize_up(   # the chunk-count floors
+                            int(np.ceil(et_nnz * ARENA_HEADROOM)),
+                            arena_bits, minimum=8)
+                    nnz_pad = max(nnz_pad, floor)
+                    layout.min_nnz[et] = nnz_pad
+            edge_nnz[et] = nnz_pad
+            edge_nnz_exact[et] = et_nnz
+            edge_eid_offsets[et] = tuple(
+                int(o) for o in np.cumsum([0] + m_nnz[:-1]))
+        edges[et] = EdgeSet(adj=packed["fwd"], adj_t=packed["bwd"])
 
     plan = None
     if with_plan:
@@ -533,7 +519,7 @@ def _build_batch_plan(coo_of: Dict[str, tuple],
 
     plan = build_relation_plan(relations, sizes_pad, bounds=bounds,
                                chunk=chunk, pad=pad,
-                               packed=bucketed_of or None, tiers=tiers)
+                               packed=bucketed_of, tiers=tiers)
     if layout is not None:
         layout.plan_chunk.setdefault("fwd", plan.fwd.chunk)
         layout.plan_chunk.setdefault("bwd", plan.bwd.chunk)

@@ -10,13 +10,14 @@ and evil rows get wide, deep tiles.
 
 Two packings live here:
 
-* :class:`BucketedELL` — one slab per degree bucket, dispatched as one
-  ``pallas_call`` each (the reference per-bucket path).
+* :class:`BucketedELL` — one slab per degree bucket: the packing graphs
+  are built and stored in, and the source every arena is fused from.
 * :class:`FusedELL` — all bucket slabs re-chunked into a single uniform
   chunk arena plus a per-chunk metadata table, so the *entire* bucketed
   aggregation runs as ONE ``pallas_call`` (DESIGN.md §1).  Output rows are
   laid out arena-contiguously; a single inverse-permutation gather replaces
-  the per-bucket ``y.at[rows].add`` combine.
+  a per-bucket ``y.at[rows].add`` combine.  Every op executes over it
+  (kernels/ops.py).
 
 All packing is host-side numpy (one-time preprocessing, matching the paper's
 CSR/CSC preprocessing stage).
@@ -214,7 +215,8 @@ def pack_eid_slabs(dst: np.ndarray, src: np.ndarray, n_dst: int, n_src: int,
     instead of weights, with ``nnz`` as the padding sentinel.  Lets a
     learnable weight vector w (nnz,) be gathered into the exact slab layout
     pack_ell produces — the basis of differentiable edge weights
-    (kernels/learnable.py).  Returns (fwd_slabs, bwd_slabs, order, nnz):
+    (``kernels/ops.py::drspmm_learnable`` over their
+    ``fuse_bucketed(..., eids=True)`` arenas).  Returns (fwd_slabs, bwd_slabs, order, nnz):
     slabs are BucketedELL whose ``w`` holds f32-encoded edge ids (exact up
     to 2^24 edges); ``order`` maps the canonical order back to the caller's
     COO order.
@@ -801,8 +803,7 @@ class RelationPlan:
 
     def to_dense(self) -> np.ndarray:
         """Full (n_out_total, n_src_total) block matrix across BOTH tiers —
-        the oracle every executor path must match (round-trip tests, the
-        ``dense`` reference backend)."""
+        the oracle every executor path must match (round-trip tests)."""
         a = np.zeros((self.n_out_total, self.n_src_total), np.float32)
         if self.has_arena:
             fa = np.asarray(self.fwd.to_dense(), np.float32)

@@ -6,16 +6,12 @@ Backward (Alg. 2):  dV[j, t]  += w_ij * dY[i, X_idx[j, t]]           over i∈N(
 Layout / TPU mapping
 --------------------
 Every sparse executor here is ONE chunk-walk kernel over an arena of
-uniform (BR, Ec) neighbour chunks (DESIGN.md §1):
-
-* **Fused** (default hot path): the :class:`~repro.graphs.ell.FusedELL`
-  arena holds ALL degree buckets; a scalar-prefetch metadata table routes
-  each chunk's accumulation into its output row-block (grouped-matmul
-  revisit pattern — consecutive grid steps hit the same output block, so it
-  stays VMEM-resident and no atomics or host-side combines are needed).
-* **Per-bucket** (reference): one launch per degree bucket, the bucket's
-  (R, E) ELL slab read as an arena of R/BR chunks of width E, one per
-  row-block — the paper's dynamic warp partitioning expressed structurally.
+uniform (BR, Ec) neighbour chunks (DESIGN.md §1): the
+:class:`~repro.graphs.ell.FusedELL` arena holds ALL degree buckets, and a
+scalar-prefetch metadata table routes each chunk's accumulation into its
+output row-block (grouped-matmul revisit pattern — consecutive grid steps
+hit the same output block, so it stays VMEM-resident and no atomics or
+host-side combines are needed).
 
 Kernel-body idioms, all in a form Mosaic lowers (DESIGN.md §1.4):
 
@@ -60,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.graphs.ell import ELLBucket, FusedELL, ROW_BLOCK, _round_up
+from repro.graphs.ell import FusedELL, _round_up
 from repro.obs.metrics import DEFAULT_REGISTRY as _METRICS
 
 # One MXU lane-width: D-tiling granularity for wide embeddings, and the
@@ -427,53 +423,6 @@ def _arena_spmm(block_of, start, nbr, w, x, n_rows: int, interpret):
     )(jnp.asarray(block_of), jnp.asarray(start), ids, wts, xp), interpret)
 
 
-def _bucket_arena(bucket: ELLBucket):
-    """A degree bucket's (R, E) slab as arena tables: R/BR chunks of width
-    E, chunk i opening row-block i."""
-    r, e = bucket.nbr.shape
-    br = min(ROW_BLOCK, r)
-    nb = r // br
-    return (jnp.arange(nb, dtype=jnp.int32), jnp.ones((nb,), jnp.int32),
-            jnp.reshape(bucket.nbr, (nb, br, e)),
-            jnp.reshape(bucket.w, (nb, br, e)))
-
-
-# ---------------------------------------------------------------------------
-# per-bucket executors (reference path): one launch per degree bucket
-# ---------------------------------------------------------------------------
-
-def drspmm_fwd_bucket(bucket: ELLBucket, x_vals: jax.Array, x_idx: jax.Array,
-                      dim: int, *, interpret: bool | None = None) -> jax.Array:
-    """Y_bucket (R, dim) for one degree bucket (rows still bucket-local)."""
-    r = bucket.nbr.shape[0]
-    return _arena_fwd(*_bucket_arena(bucket), x_vals, x_idx, dim, r,
-                      interpret).astype(x_vals.dtype)
-
-
-def drspmm_bwd_bucket(bucket: ELLBucket, gy: jax.Array, xi_rows: jax.Array,
-                      *, interpret: bool | None = None) -> jax.Array:
-    """dV_bucket (R, k) for one transposed-ELL bucket.
-
-    ``tnbr``/``tw`` come from the *transposed* ELL packing, so each source
-    row j is owned by exactly one grid cell — accumulation is a private VMEM
-    reduction, no atomics (DESIGN.md §2).  ``xi_rows`` is x_idx gathered at
-    this bucket's source rows, shape (R, k).
-    """
-    r = bucket.nbr.shape[0]
-    return _arena_bwd(*_bucket_arena(bucket), gy, xi_rows, r,
-                      interpret).astype(gy.dtype)
-
-
-def spmm_dense_bucket(bucket: ELLBucket, x: jax.Array,
-                      *, interpret: bool | None = None) -> jax.Array:
-    """Dense-operand SpMM for one bucket (baseline, cuSPARSE-analogue): the
-    same traversal with an (N, D) operand, so benchmarks can compare the CBSR
-    gather traffic (N·k) against the dense gather traffic (N·D)."""
-    r = bucket.nbr.shape[0]
-    return _arena_spmm(*_bucket_arena(bucket), x, r,
-                       interpret).astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
 # fused single-dispatch executors — ONE pallas_call for ALL buckets
 # ---------------------------------------------------------------------------
@@ -728,7 +677,7 @@ def _arena_dw_kernel(blk_ref, nbr_ref, x_hbm, gy_ref, out_ref, rows_ref, sem,
                      *slab, k: int, ec: int):
     """Per-slot sampled dot: out[0, 0, b·Ec + e] = Σ_t dY[row_b, idx[n, t]] ·
     vals[n, t] with n = nbr[b, e].  Same gather as the forward with the roles
-    of weight and value swapped (kernels/learnable.py); the scatter of slot
+    of weight and value swapped (DESIGN.md §8); the scatter of slot
     contributions into canonical w order happens OUTSIDE the kernel (one XLA
     scatter — TPUs have no fast in-kernel scatter)."""
     _gather_rows(x_hbm, nbr_ref, rows_ref, sem, slab, pl.program_id(0) == 0)

@@ -2,28 +2,25 @@
 
 ``drspmm`` is the public op: Y = A · dense(CBSR(x_vals, x_idx)), with a
 custom VJP that runs the sampled backward kernel (SSpMM) over the transposed
-ELL packing, exactly as Alg. 2 reuses the forward's CBSR indices.
+packing, exactly as Alg. 2 reuses the forward's CBSR indices.
 
-``backend`` selects the execution path:
-  * "pallas_fused" — ONE Pallas dispatch per edge-type direction: all degree
-                 buckets run in a single kernel over the FusedELL arena and
-                 the per-bucket ``y.at[rows].add`` combine collapses to one
-                 gather (DESIGN.md §1).  Default on TPU.
-  * "xla_fused" — the SAME fused arena layout executed in plain jnp
-                 (gather + one scatter / segment-sum, no per-bucket loop).
-                 Default on CPU, where Pallas only interprets: it keeps the
-                 fused packing's adaptive-chunk slot reduction and its
-                 single-combine structure at real XLA wall-clock.
-  * "pallas"   — the per-bucket Pallas kernels, one dispatch per degree
-                 bucket (interpret-mode on CPU, native on TPU); kept as the
-                 reference for the fused path;
-  * "xla"      — same bucketed math in pure jnp (gather/one-hot), the
-                 per-bucket reference at XLA wall-clock;
-  * "dense"    — fully dense oracle (kernels/ref.py), the cuSPARSE-analogue.
+Every op runs over the fused arena layout (:class:`FusedELL`, DESIGN.md §1)
+on one of two executors, named by ``backend``:
 
-Fused packings are derived lazily from the BucketedELL arguments via
-``fuse_bucketed`` (host-side, memoized per packing), so every caller of the
-bucketed API gets the single-dispatch path by flipping ``backend`` alone.
+  * "pallas_fused" — the Pallas arena kernels: ONE dispatch per edge-type
+                 direction, all degree buckets in a single kernel, and the
+                 combine a single gather.  Default on TPU.
+  * "xla_fused"  — the same arena tables walked in plain jnp (gather + one
+                 segment-sum).  Default on CPU, where Pallas only
+                 interprets.
+
+The oracle both are tested against is kernels/ref.py: plain dense math that
+no op here dispatches to.
+
+A concrete :class:`BucketedELL` argument is fused host-side on first use
+(``fuse_bucketed``, memoized per packing).  Inside a trace the ops take only
+pre-fused adjacencies or a :class:`RelationPlan`: fusing is host packing, so
+a traced ``BucketedELL`` raises ``TypeError``.
 
 ``drspmm_multi`` lifts the same contract one level: every edge-type
 direction of a hetero layer runs over a :class:`RelationPlan` super-arena
@@ -32,52 +29,48 @@ as ONE dispatch per direction-group — one forward, one transposed backward
 (DESIGN.md §14): relations the plan classified as dense-tier at pack time
 (nnz below the measured crossover) skip the chunk walk and run together as
 at most one extra batched dense matmul per direction; ``drspmm`` applies
-the same crossover to single tiny relations on the fused-family backends.
+the same crossover to single tiny relations.
 """
 
 from __future__ import annotations
 
-import functools
+import dataclasses
 import weakref
 from collections import OrderedDict, deque
-from typing import Literal
+from typing import Literal, get_args
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graphs.ell import (DENSE_TIER_AREA, DENSE_TIER_NNZ, BucketedELL,
-                              ELLBucket, FusedELL, RelationPlan, decode_eids,
-                              ell_to_coo, fuse_bucketed, fused_to_coo)
+from repro.graphs.ell import (DENSE_TIER_AREA, DENSE_TIER_NNZ, FusedELL,
+                              RelationPlan, ell_to_coo, fuse_bucketed,
+                              fused_to_coo)
 from repro.kernels import drspmm as _k
-from repro.kernels import learnable as _learn
-from repro.kernels import ref as _ref
 from repro.obs.metrics import DEFAULT_REGISTRY as _METRICS
 
-Backend = Literal["pallas_fused", "xla_fused", "pallas", "xla", "dense"]
-# The fused single-dispatch executor is the paper-faithful hot path on real
-# hardware; on CPU the Pallas kernels only run in interpret mode (not
-# wall-clock-representative), so the same fused arena layout executed in
-# plain XLA is the default there.
+Backend = Literal["pallas_fused", "xla_fused"]
+BACKENDS = get_args(Backend)
+# The Pallas kernels are the hot path on real hardware; on CPU they only run
+# in interpret mode (not wall-clock-representative), so the same arena
+# layout executed in plain XLA is the default there.
 DEFAULT_BACKEND: Backend = (
     "pallas_fused" if jax.default_backend() == "tpu" else "xla_fused")
 
 # Trace-time dispatch log: every executor issue appends a "family:kind" tag
-# while its op body runs (i.e. while TRACING under jit — compiled replays
-# don't re-run Python, so count deltas around an explicit trace such as
-# ``jax.make_jaxpr``).  Per-bucket issues log as "pallas:bucket_*" /
-# "xla:bucket_*", so a downgrade off the fused path shows.  This is how
-# tests and bench smoke assert the one-dispatch-per-direction-group
-# property for the xla family, where jaxpr ``pallas_call`` counting has
-# nothing to count.  Bounded: a long-lived serve loop retraces per (bucket,
-# device) compile and eviction return, and nothing outside tests ever drains
-# the log.
+# ("pallas:fwd", "xla:multi_bwd", ...) while its op body runs (i.e. while
+# TRACING under jit — compiled replays don't re-run Python, so count deltas
+# around an explicit trace such as ``jax.make_jaxpr``).  This is how tests
+# assert the one-dispatch-per-direction-group property for the xla family,
+# where jaxpr ``pallas_call`` counting has nothing to count.  Bounded: a
+# long-lived serve loop retraces per (bucket, device) compile and eviction
+# return, and nothing outside tests ever drains the log.
 FUSED_DISPATCH_LOG: "deque[str]" = deque(maxlen=4096)
 
 
 def _record_dispatch(tag: str) -> None:
     FUSED_DISPATCH_LOG.append(tag)
-    # Generalized per-backend dispatch counters (DESIGN.md §11): the same
+    # Generalized per-executor dispatch counters (DESIGN.md §11): the same
     # trace-time semantics as the log, but labeled, unbounded-total, and
     # exportable — ``ops.dispatch{family=...,kind=...}`` in the default
     # metrics registry.  The deque stays the test-facing drainable probe.
@@ -85,56 +78,33 @@ def _record_dispatch(tag: str) -> None:
     _METRICS.inc("ops.dispatch", family=family, kind=kind)
 
 
-def _fused_of(adj) -> FusedELL:
+def _check_backend(backend) -> None:
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; expected one of "
+                         f"{BACKENDS}")
+
+
+def _family(backend: Backend) -> str:
+    """Dispatch-tag family of an executor: "pallas" or "xla"."""
+    return backend.removesuffix("_fused")
+
+
+def _fused_of(adj, *, eids: bool = False) -> FusedELL:
+    """The arena of ``adj``: itself when pre-fused, else its memoized
+    host-side fusing (``eids``: the edge-id arena the learnable op needs).
+    Fusing needs concrete arrays, so a traced BucketedELL is refused."""
     if isinstance(adj, FusedELL):
+        if eids and adj.eid is None:
+            raise ValueError(
+                "the learnable op needs an eid arena "
+                "(fuse_bucketed(..., eids=True) / pack_fused_eid_pair)")
         return adj
-    return fuse_bucketed(adj)
-
-
-def _effective_backend(adj, backend: Backend) -> Backend:
-    """Fused packing is host-side preprocessing: it needs concrete arrays.
-    When the adjacency arrives as a *traced jit argument* (e.g. a step
-    function that takes the graph as a parameter), fall back to the
-    per-bucket path of the same executor family — numerically identical
-    (see tests/test_fused.py), just bucket-granular dispatch.  Callers who
-    want the fused path inside jit should close over the graph (it is
-    static per design) or pre-fuse with ``fuse_bucketed``.
-
-    A pre-fused adjacency (:class:`FusedELL`, e.g. a collated serve batch —
-    graphs/collate.py) has no bucket slabs to fall back to, so the
-    per-bucket/dense backend names are upgraded to the fused executor of the
-    matching family (numerically interchangeable, tests/test_fused.py).
-    Crucially this works **inside jit with the graph traced**: the arena is
-    already packed, so batches sharing a padded shape signature reuse one
-    compiled executable."""
-    if isinstance(adj, FusedELL):
-        if backend in ("pallas", "pallas_fused"):
-            return "pallas_fused"
-        return "xla_fused"
-    if backend in ("pallas_fused", "xla_fused"):
-        if any(isinstance(b.nbr, jax.core.Tracer) for b in adj.buckets):
-            return "pallas" if backend == "pallas_fused" else "xla"
-    return backend
-
-
-def _fwd_bucket_xla(bucket, x_vals, x_idx, dim):
-    """Bucketed CBSR aggregation in plain jnp (same math as the kernel)."""
-    v = jnp.take(x_vals, bucket.nbr, axis=0)          # (R, E, k)
-    c = jnp.take(x_idx, bucket.nbr, axis=0)           # (R, E, k)
-    vw = v * bucket.w[..., None]                      # weight each neighbor
-    r, e, k = v.shape
-    flat_rows = jnp.repeat(jnp.arange(r, dtype=jnp.int32)[:, None, None],
-                           e, axis=1)
-    out = jnp.zeros((r, dim), x_vals.dtype)
-    return out.at[jnp.broadcast_to(flat_rows, c.shape), c].add(vw)
-
-
-def _bwd_bucket_xla(bucket, gy, xi_rows):
-    g = jnp.take(gy, bucket.nbr, axis=0)              # (R, E, D)
-    sampled = jnp.take_along_axis(
-        g, jnp.broadcast_to(xi_rows[:, None, :], g.shape[:2] + xi_rows.shape[1:]),
-        axis=2)                                       # (R, E, k)
-    return jnp.sum(sampled * bucket.w[..., None], axis=1)
+    if any(isinstance(b.nbr, jax.core.Tracer) for b in adj.buckets):
+        raise TypeError(
+            "a BucketedELL cannot be fused inside a trace: fuse it "
+            "host-side (graphs.ell.fuse_bucketed) and pass the FusedELL, "
+            "or attach a RelationPlan")
+    return fuse_bucketed(adj, eids=eids)
 
 
 # ----- fused arena executed in plain XLA (CPU hot path; same layout the
@@ -196,64 +166,32 @@ def _spmm_fused_xla(f: FusedELL, x):
     return jnp.take(y, jnp.asarray(f.gather), axis=0)
 
 
-def _fwd_impl(adj: BucketedELL, x_vals, x_idx, dim: int, backend: Backend):
-    if backend == "dense":
-        return _ref.drspmm_fwd_ref(adj, x_vals, x_idx, dim)
-    if backend == "xla_fused":
-        _record_dispatch("xla:fwd")
-        return _fwd_fused_xla(_fused_of(adj), x_vals, x_idx, dim)
+def _fwd_impl(f: FusedELL, x_vals, x_idx, dim: int, backend: Backend):
+    _record_dispatch(f"{_family(backend)}:fwd")
     if backend == "pallas_fused":
-        _record_dispatch("pallas:fwd")
-        f = _fused_of(adj)
         ya = _k.drspmm_fwd_fused(f, x_vals, x_idx, dim)   # fp32 arena
         return jnp.take(ya, f.gather, axis=0).astype(x_vals.dtype)
-    y = jnp.zeros((adj.n_dst, dim), x_vals.dtype)
-    for b in adj.buckets:
-        _record_dispatch(f"{backend}:bucket_fwd")
-        if backend == "pallas":
-            yb = _k.drspmm_fwd_bucket(b, x_vals, x_idx, dim)
-        else:
-            yb = _fwd_bucket_xla(b, x_vals, x_idx, dim)
-        y = y.at[b.rows].add(yb)  # padded rows carry zero weights — inert
-    return y
+    return _fwd_fused_xla(f, x_vals, x_idx, dim)
 
 
-def _bwd_impl(adj_t: BucketedELL, gy, x_idx, backend: Backend):
-    if backend == "dense":
-        return _ref.drspmm_bwd_ref(adj_t, gy, x_idx)
-    n, k = x_idx.shape
-    if backend == "xla_fused":
-        _record_dispatch("xla:bwd")
-        return _bwd_fused_xla(_fused_of(adj_t), gy, x_idx)
+def _bwd_impl(ft: FusedELL, gy, x_idx, backend: Backend):
+    _record_dispatch(f"{_family(backend)}:bwd")
     if backend == "pallas_fused":
-        _record_dispatch("pallas:bwd")
-        ft = _fused_of(adj_t)
         xi_arena = jnp.take(x_idx, ft.rows, axis=0)   # (R_arena, k)
         ga = _k.drspmm_bwd_fused(ft, gy, xi_arena)    # fp32 arena
         return jnp.take(ga, ft.gather, axis=0).astype(gy.dtype)
-    gv = jnp.zeros((n, k), gy.dtype)
-    for b in adj_t.buckets:
-        _record_dispatch(f"{backend}:bucket_bwd")
-        xi_rows = jnp.take(x_idx, b.rows, axis=0)     # (R, k)
-        if backend == "pallas":
-            gb = _k.drspmm_bwd_bucket(b, gy, xi_rows)
-        else:
-            gb = _bwd_bucket_xla(b, gy, xi_rows)
-        gv = gv.at[b.rows].add(gb)
-    return gv
+    return _bwd_fused_xla(ft, gy, x_idx)
 
 
 # ----- dense fast-path tier for tiny single relations ----------------------
 #
-# The fused chunk-walk arena LOSES on tiny relations (BENCH_drspmm recorded
-# ``pin``/``pinned`` at nnz≈2k running 0.53–0.65x vs the per-bucket path):
-# below the measured crossover (graphs/ell.py::DENSE_TIER_NNZ) the whole
-# relation is ONE masked dense matmul — still a single dispatch, same
-# custom-vjp contract (sampled backward at x_idx).  Fused-family names only:
-# "pallas"/"xla" stay bucket-granular as the reference baselines the bench
-# compares against.  A collated arena (nnz == −1: padded filler, bucket-
-# stable shape signature) never reroutes — tier decisions for collation are
-# pinned at pack time by the plan (graphs/collate.py).
+# The chunk-walk arena LOSES on tiny relations (``pin``/``pinned`` at
+# nnz≈2k ran 0.53–0.65x of a per-bucket walk on CPU): below the measured
+# crossover (graphs/ell.py::DENSE_TIER_NNZ) the whole relation is ONE
+# masked dense matmul — still a single dispatch, same custom-vjp contract
+# (sampled backward at x_idx).  A collated arena (nnz == −1: padded filler,
+# bucket-stable shape signature) never reroutes — tier decisions for
+# collation are pinned at pack time by the plan (graphs/collate.py).
 
 _DENSE_MAT_CACHE: "dict[int, tuple]" = {}
 
@@ -274,12 +212,10 @@ def _dense_mat_of(adj) -> np.ndarray:
     return a
 
 
-def _dense_tier_single(adj, backend: Backend) -> bool:
-    """True when a single-relation fused-family call should take the
-    dense-tier fast path: concrete packing, known sub-threshold nnz, and a
-    dense table small enough to be worth materializing."""
-    if backend not in ("pallas_fused", "xla_fused"):
-        return False
+def _dense_tier_single(adj) -> bool:
+    """True when a single-relation call should take the dense-tier fast
+    path: concrete packing, known sub-threshold nnz, and a dense table small
+    enough to be worth materializing."""
     leaf = adj.nbr if isinstance(adj, FusedELL) else adj.buckets[0].nbr
     if isinstance(leaf, jax.core.Tracer):
         return False
@@ -289,7 +225,7 @@ def _dense_tier_single(adj, backend: Backend) -> bool:
 
 def _drspmm_dense_single(adj, adj_t, x_vals, x_idx, dim: int,
                          backend: Backend) -> jax.Array:
-    family = "pallas" if backend == "pallas_fused" else "xla"
+    family = _family(backend)
     a = jnp.asarray(_dense_mat_of(adj))
     at = jnp.asarray(_dense_mat_of(adj_t))
 
@@ -320,113 +256,80 @@ def _drspmm_dense_single(adj, adj_t, x_vals, x_idx, dim: int,
     return f(x_vals)
 
 
-def drspmm(adj: BucketedELL, adj_t: BucketedELL, x_vals: jax.Array,
-           x_idx: jax.Array, dim: int, *,
+def drspmm(adj, adj_t, x_vals: jax.Array, x_idx: jax.Array, dim: int, *,
            backend: Backend = DEFAULT_BACKEND) -> jax.Array:
-    """Differentiable DR-SpMM.  Gradient flows to ``x_vals`` only; the
-    adjacency and the CBSR indices are structural.
+    """Differentiable DR-SpMM over ``adj`` (A) and ``adj_t`` (Aᵀ), each a
+    :class:`FusedELL` or a concrete :class:`BucketedELL`.  Gradient flows
+    to ``x_vals`` only; the adjacency and the CBSR indices are structural.
 
-    Size-adaptive: on the fused-family backends a concrete relation whose
-    nnz sits below the measured dense crossover
-    (``graphs/ell.py::DENSE_TIER_NNZ``) routes to the dense-tier executor —
-    one masked dense matmul forward, one transposed matmul + SSpMM sampling
-    backward — instead of walking the arena (DESIGN.md §14)."""
-
-    backend = _effective_backend(adj, backend)
-    if _dense_tier_single(adj, backend):
+    Size-adaptive: a concrete relation whose nnz sits below the measured
+    dense crossover (``graphs/ell.py::DENSE_TIER_NNZ``) routes to the
+    dense-tier executor — one masked dense matmul forward, one transposed
+    matmul + SSpMM sampling backward — instead of walking the arena
+    (DESIGN.md §14)."""
+    _check_backend(backend)
+    if _dense_tier_single(adj):
         return _drspmm_dense_single(adj, adj_t, x_vals, x_idx, dim, backend)
 
+    # The arenas and the CBSR indices ride through the custom_vjp as
+    # primals, not closure constants: inside a checkpointed layer they are
+    # checkpoint-scope tracers, which a closure would leak into ``f_bwd``
+    # (the same reason as ``_multi_traced``).
     @jax.custom_vjp
-    def f(xv):
-        return _fwd_impl(adj, xv, x_idx, dim, backend)
+    def f(a, at, xv, xi):
+        return _fwd_impl(a, xv, xi, dim, backend)
 
-    def f_fwd(xv):
-        return _fwd_impl(adj, xv, x_idx, dim, backend), None
+    def f_fwd(a, at, xv, xi):
+        return f(a, at, xv, xi), (a, at, xi)
 
-    def f_bwd(_, gy):
-        return (_bwd_impl(adj_t, gy, x_idx, backend),)
+    def f_bwd(res, gy):
+        a, at, xi = res
+        return (_zero_cotangent(a), _zero_cotangent(at),
+                _bwd_impl(at, gy, xi, backend), _zero_cotangent(xi))
 
     f.defvjp(f_fwd, f_bwd)
-    return f(x_vals)
+    return f(_fused_of(adj), _fused_of(adj_t), x_vals, x_idx)
 
 
-def spmm(adj: BucketedELL, adj_t: BucketedELL, x: jax.Array, *,
+def spmm(adj, adj_t, x: jax.Array, *,
          backend: Backend = DEFAULT_BACKEND) -> jax.Array:
     """Dense-operand SpMM baseline with full (not sampled) backward."""
+    _check_backend(backend)
 
-    backend = _effective_backend(adj, backend)
-
+    # arenas as primals, as in :func:`drspmm`
     @jax.custom_vjp
-    def f(xd):
-        return _spmm_fwd(adj, xd, backend)
+    def f(a, at, xd):
+        return _spmm_fwd(a, xd, backend)
 
-    def f_fwd(xd):
-        return _spmm_fwd(adj, xd, backend), None
+    def f_fwd(a, at, xd):
+        return f(a, at, xd), (a, at)
 
-    def f_bwd(_, gy):
-        return (_spmm_fwd(adj_t, gy, backend),)
+    def f_bwd(res, gy):
+        a, at = res
+        return (_zero_cotangent(a), _zero_cotangent(at),
+                _spmm_fwd(at, gy, backend))
 
     f.defvjp(f_fwd, f_bwd)
-    return f(x)
+    return f(_fused_of(adj), _fused_of(adj_t), x)
 
 
-def _spmm_fwd(adj: BucketedELL, x, backend: Backend):
-    if backend == "dense":
-        return _ref.spmm_dense_ref(adj, x)
-    if backend == "xla_fused":
-        _record_dispatch("xla:spmm")
-        return _spmm_fused_xla(_fused_of(adj), x)
+def _spmm_fwd(adj, x, backend: Backend):
+    f = _fused_of(adj)
+    _record_dispatch(f"{_family(backend)}:spmm")
     if backend == "pallas_fused":
-        _record_dispatch("pallas:spmm")
-        f = _fused_of(adj)
         ya = _k.spmm_dense_fused(f, x)                # fp32 arena
         return jnp.take(ya, f.gather, axis=0).astype(x.dtype)
-    y = jnp.zeros((adj.n_dst, x.shape[1]), x.dtype)
-    for b in adj.buckets:
-        _record_dispatch(f"{backend}:bucket_spmm")
-        if backend == "pallas":
-            yb = _k.spmm_dense_bucket(b, x)
-        else:
-            rows = jnp.take(x, b.nbr, axis=0)         # (R, E, D)
-            yb = jnp.sum(rows * b.w[..., None], axis=1)
-        y = y.at[b.rows].add(yb)
-    return y
+    return _spmm_fused_xla(f, x)
 
 
 # ---------------------------------------------------------------------------
-# drspmm_learnable — differentiable per-edge weights through the same
-# 5-backend family (DESIGN.md §8).  The packing is an edge-ID structure
-# (pack_eid_slabs slabs or their fused eid arenas): the canonical weight
-# vector w (nnz,) is gathered into slab/arena layout at execution time, so
-# Y = A(w)·dense(CBSR(x)) has gradients in BOTH w and x_vals while keeping
-# the fixed-weight path's dispatch granularity per backend.
+# drspmm_learnable — differentiable per-edge weights over the same arena
+# (DESIGN.md §8).  The packing is an edge-ID arena (``fuse_bucketed(...,
+# eids=True)`` of pack_eid_slabs slabs, or pack_fused_eid_pair): the
+# canonical weight vector w (nnz,) is gathered into arena layout at
+# execution time, so Y = A(w)·dense(CBSR(x)) has gradients in BOTH w and
+# x_vals with the fixed-weight path's one dispatch per direction.
 # ---------------------------------------------------------------------------
-
-def _fused_eid_of(pack) -> FusedELL:
-    if isinstance(pack, FusedELL):
-        assert pack.eid is not None, (
-            "learnable fused backends need an eid arena "
-            "(fuse_bucketed(..., eids=True) / pack_fused_eid_pair)")
-        return pack
-    return fuse_bucketed(pack, eids=True)
-
-
-def _learnable_effective_backend(pack, backend: Backend) -> Backend:
-    """Same family-upgrade rules as :func:`_effective_backend`: a pre-fused
-    eid arena upgrades per-bucket names to the fused executor of the same
-    family (it has no slabs to loop over); traced bucketed slabs downgrade
-    fused names to the per-bucket path (fusing is host-side packing)."""
-    if isinstance(pack, FusedELL):
-        if backend in ("pallas", "pallas_fused"):
-            return "pallas_fused"
-        if backend == "dense":
-            return "dense"
-        return "xla_fused"
-    if backend in ("pallas_fused", "xla_fused"):
-        if any(isinstance(b.nbr, jax.core.Tracer) for b in pack.buckets):
-            return "pallas" if backend == "pallas_fused" else "xla"
-    return backend
-
 
 def _wpad(w_canon):
     """Append the inert slot padded eids (→ index nnz) gather from."""
@@ -437,87 +340,12 @@ def _safe_eids(eid, nnz: int):
     return jnp.where(jnp.asarray(eid) < 0, nnz, jnp.asarray(eid))
 
 
-# ----- dense oracle (autodiff carries both grads exactly) ------------------
+# ----- fused arena in plain XLA (CPU hot path): the fixed-weight walks over
+# ----- the arena's slot weights -------------------------------------------
 
-def _learnable_dense(pack, nnz: int, w, xv, xi, dim: int):
-    wp = _wpad(w)
-    a = jnp.zeros((pack.n_dst, pack.n_src), jnp.float32)
-    if isinstance(pack, FusedELL):
-        slot_rows = jnp.take(jnp.asarray(pack.rows), _arena_rows(pack),
-                             axis=0)                  # (C, BR) original rows
-        wa = wp[_safe_eids(pack.eid, nnz)]            # (C, BR, Ec)
-        rows3 = jnp.broadcast_to(slot_rows[:, :, None], wa.shape)
-        a = a.at[rows3, jnp.asarray(pack.nbr)].add(wa)
-    else:
-        for b in pack.buckets:
-            ids = decode_eids(b.w)
-            ws = wp[_safe_eids(ids, nnz)]             # (R, E)
-            rows2 = jnp.broadcast_to(b.rows[:, None], ws.shape)
-            a = a.at[rows2, b.nbr].add(ws)
-    n_src, k = xi.shape[0], xi.shape[1]
-    xd = jnp.zeros((n_src, dim), xv.dtype).at[
-        jnp.arange(n_src)[:, None], xi].add(xv)
-    return a @ xd
-
-
-# ----- per-bucket Pallas path: slab weights gathered in XLA, then the
-# ----- fixed-weight bucket kernels run on the (traced-weight) slabs --------
-
-def _fwd_learnable_pallas(slabs: BucketedELL, nnz, w, xv, xi, dim):
-    wp = _wpad(w)
-    y = jnp.zeros((slabs.n_dst, dim), xv.dtype)
-    for b in slabs.buckets:
-        ws = wp[_safe_eids(decode_eids(b.w), nnz)]    # (R, E)
-        yb = _k.drspmm_fwd_bucket(
-            ELLBucket(rows=b.rows, nbr=b.nbr, w=ws), xv, xi, dim)
-        y = y.at[b.rows].add(yb)
-    return y
-
-
-def _bwd_x_learnable_pallas(tslabs: BucketedELL, nnz, w, gy, xi):
-    wp = _wpad(w)
-    n, k = xi.shape
-    gv = jnp.zeros((n, k), gy.dtype)
-    for b in tslabs.buckets:
-        ws = wp[_safe_eids(decode_eids(b.w), nnz)]
-        xi_rows = jnp.take(xi, b.rows, axis=0)        # (R, k)
-        gb = _k.drspmm_bwd_bucket(
-            ELLBucket(rows=b.rows, nbr=b.nbr, w=ws), gy, xi_rows)
-        gv = gv.at[b.rows].add(gb)
-    return gv
-
-
-# ----- fused arena in plain XLA (CPU hot path) -----------------------------
-
-def _fwd_learnable_fused_xla(f: FusedELL, nnz, w, xv, xi, dim):
-    wa = _wpad(w)[_safe_eids(f.eid, nnz)]             # (C, BR, Ec)
-    nbr = jnp.asarray(f.nbr)
-    v = jnp.take(xv, nbr, axis=0)                     # (C, BR, Ec, k)
-    cols = jnp.take(xi, nbr, axis=0)
-    vw = v * wa[..., None]
-    rows = _arena_rows(f)                             # (C, BR)
-    y = jnp.zeros((f.n_arena_rows, dim), xv.dtype)
-    y = y.at[jnp.broadcast_to(rows[:, :, None, None], cols.shape),
-             cols].add(vw)
-    return jnp.take(y, jnp.asarray(f.gather), axis=0)
-
-
-def _bwd_x_learnable_fused_xla(ft: FusedELL, nnz, w, gy, xi):
-    twa = _wpad(w)[_safe_eids(ft.eid, nnz)]           # (C, BR, Ec)
-    tnbr = jnp.asarray(ft.nbr)
-    k = xi.shape[1]
-    g = jnp.take(gy, tnbr, axis=0)                    # (C, BR, Ec, D)
-    xi_arena = jnp.take(xi, jnp.asarray(ft.rows), axis=0)      # (R_arena, k)
-    xi_blocks = jnp.take(xi_arena, _arena_rows(ft), axis=0)    # (C, BR, k)
-    sampled = jnp.take_along_axis(
-        g, jnp.broadcast_to(xi_blocks[:, :, None, :], g.shape[:3] + (k,)),
-        axis=3)                                       # SSpMM sampling
-    contrib = jnp.sum(sampled * twa[..., None], axis=2)        # (C, BR, k)
-    n_blocks = ft.n_arena_rows // ft.row_block
-    dv = jax.ops.segment_sum(contrib, jnp.asarray(ft.block_of),
-                             num_segments=n_blocks).reshape(
-        ft.n_arena_rows, k)
-    return jnp.take(dv, jnp.asarray(ft.gather), axis=0)
+def _slot_weighted(f: FusedELL, nnz, w) -> FusedELL:
+    """``f`` with its weights the canonical ``w`` gathered per slot."""
+    return dataclasses.replace(f, w=_wpad(w)[_safe_eids(f.eid, nnz)])
 
 
 def _dw_contrib_to_canon(f: FusedELL, nnz, contrib):
@@ -541,47 +369,29 @@ def _dw_learnable_fused_xla(f: FusedELL, nnz, gy, xv, xi):
     return _dw_contrib_to_canon(f, nnz, contrib)
 
 
-# ----- backend dispatch ----------------------------------------------------
+# ----- executor dispatch ---------------------------------------------------
 
-def _learnable_fwd_impl(pack, nnz, w, xv, xi, dim, backend: Backend):
-    if backend == "xla_fused":
-        return _fwd_learnable_fused_xla(_fused_eid_of(pack), nnz, w, xv, xi,
-                                        dim)
+def _learnable_fwd_impl(f: FusedELL, nnz, w, xv, xi, dim, backend: Backend):
     if backend == "pallas_fused":
-        f = _fused_eid_of(pack)
         ya = _k.drspmm_fwd_learnable_fused(f, nnz, w, xv, xi, dim)
         return jnp.take(ya, f.gather, axis=0).astype(xv.dtype)
-    if backend == "pallas":
-        return _fwd_learnable_pallas(pack, nnz, w, xv, xi, dim)
-    return _learn._fwd_exact(pack, w, xv, xi, dim)    # "xla" reference
+    return _fwd_fused_xla(_slot_weighted(f, nnz, w), xv, xi, dim)
 
 
-def _learnable_dx_impl(tpack, nnz, w, gy, xi, backend: Backend):
-    if backend == "xla_fused":
-        return _bwd_x_learnable_fused_xla(_fused_eid_of(tpack), nnz, w, gy,
-                                          xi)
+def _learnable_dx_impl(ft: FusedELL, nnz, w, gy, xi, backend: Backend):
     if backend == "pallas_fused":
-        ft = _fused_eid_of(tpack)
         xi_arena = jnp.take(xi, jnp.asarray(ft.rows), axis=0)
         ga = _k.drspmm_bwd_learnable_fused(ft, nnz, w, gy, xi_arena)
         return jnp.take(ga, ft.gather, axis=0).astype(gy.dtype)
-    if backend == "pallas":
-        return _bwd_x_learnable_pallas(tpack, nnz, w, gy, xi)
-    return _learn._bwd_x(tpack, w, gy, xi)            # "xla" reference
+    return _bwd_fused_xla(_slot_weighted(ft, nnz, w), gy, xi)
 
 
-def _learnable_dw_impl(pack, nnz, gy, xv, xi, backend: Backend):
-    if backend == "xla_fused":
-        return _dw_learnable_fused_xla(_fused_eid_of(pack), nnz, gy, xv, xi)
+def _learnable_dw_impl(f: FusedELL, nnz, gy, xv, xi, backend: Backend):
     if backend == "pallas_fused":
-        f = _fused_eid_of(pack)
         gy_arena = jnp.take(gy, jnp.asarray(f.rows), axis=0)
         contrib = _k.drspmm_dw_learnable_fused(f, gy_arena, xv, xi)
         return _dw_contrib_to_canon(f, nnz, contrib)
-    # per-bucket sampled dot — the dw scatter into canonical order is an
-    # XLA scatter under every backend (TPUs have no fast in-kernel scatter),
-    # so "pallas" shares the bucketed reference reduction.
-    return _learn._bwd_w(pack, gy, xv, xi, nnz)
+    return _dw_learnable_fused_xla(f, nnz, gy, xv, xi)
 
 
 # The executor — custom-vjp wrapper + jit — is built ONCE per
@@ -603,37 +413,37 @@ _LEARNABLE_EXE_MAX = 64
 _LEARNABLE_TRACES: list = []
 
 
-def _learnable_executable(fwdp, bwdp, nnz: int, dim: int, backend: Backend):
+def _learnable_vjp(fwdp: FusedELL, bwdp: FusedELL, nnz: int, dim: int,
+                   backend: Backend, key):
+    """The custom-vjp op over one arena pair (``key`` tags its traces)."""
+
+    @jax.custom_vjp
+    def f(w, xv, xi):
+        _LEARNABLE_TRACES.append(key)
+        return _learnable_fwd_impl(fwdp, nnz, w, xv, xi, dim, backend)
+
+    def f_fwd(w, xv, xi):
+        return f(w, xv, xi), (w, xv, xi)
+
+    def f_bwd(res, gy):
+        w, xv, xi = res
+        gw = _learnable_dw_impl(fwdp, nnz, gy, xv, xi, backend)
+        gx = _learnable_dx_impl(bwdp, nnz, w, gy, xi, backend)
+        # xi is structural (integer): float0 cotangent
+        return gw, gx, np.zeros(xi.shape, jax.dtypes.float0)
+
+    f.defvjp(f_fwd, f_bwd)
+    return f
+
+
+def _learnable_executable(fwdp: FusedELL, bwdp: FusedELL, nnz: int,
+                          dim: int, backend: Backend):
     key = (id(fwdp), id(bwdp), nnz, dim, backend)
     hit = _LEARNABLE_EXE.get(key)
     if hit is not None and hit[0] is fwdp and hit[1] is bwdp:
         _LEARNABLE_EXE.move_to_end(key)
         return hit[2]
-
-    if backend == "dense":
-        def f_dense(w, xv, xi):
-            _LEARNABLE_TRACES.append(key)
-            return _learnable_dense(fwdp, nnz, w, xv, xi, dim)
-        exe = jax.jit(f_dense)                        # autodiff = exact oracle
-    else:
-        @jax.custom_vjp
-        def f(w, xv, xi):
-            _LEARNABLE_TRACES.append(key)
-            return _learnable_fwd_impl(fwdp, nnz, w, xv, xi, dim, backend)
-
-        def f_fwd(w, xv, xi):
-            return f(w, xv, xi), (w, xv, xi)
-
-        def f_bwd(res, gy):
-            w, xv, xi = res
-            gw = _learnable_dw_impl(fwdp, nnz, gy, xv, xi, backend)
-            gx = _learnable_dx_impl(bwdp, nnz, w, gy, xi, backend)
-            # xi is structural (integer): float0 cotangent
-            return gw, gx, np.zeros(xi.shape, jax.dtypes.float0)
-
-        f.defvjp(f_fwd, f_bwd)
-        exe = jax.jit(f)
-
+    exe = jax.jit(_learnable_vjp(fwdp, bwdp, nnz, dim, backend, key))
     _LEARNABLE_EXE[key] = (fwdp, bwdp, exe)
     _LEARNABLE_EXE.move_to_end(key)
     while len(_LEARNABLE_EXE) > _LEARNABLE_EXE_MAX:
@@ -647,20 +457,26 @@ def drspmm_learnable(fwd, bwd, nnz: int, w_canon: jax.Array,
     """Y = A(w)·dense(CBSR(x)), differentiable in BOTH ``w_canon`` (nnz,)
     and ``x_vals`` (N, k).
 
-    ``fwd``/``bwd`` are the forward/transposed edge-ID packings: bucketed
-    eid slabs (:func:`~repro.graphs.ell.pack_eid_slabs`) or pre-fused eid
-    arenas (:func:`~repro.graphs.ell.pack_fused_eid_pair`, collated
-    batches).  On the fused backends this is ONE dispatch per direction —
-    the weight gather w[eid] happens inside the kernel/arena computation —
-    and dw is the sampled dot over the same arena plus one scatter to
-    canonical order.  Gradient parity across all five backends:
+    ``fwd``/``bwd`` are the forward/transposed edge-ID packings: pre-fused
+    eid arenas (:func:`~repro.graphs.ell.pack_fused_eid_pair`, collated
+    batches) or concrete bucketed eid slabs
+    (:func:`~repro.graphs.ell.pack_eid_slabs`), fused here on first use.
+    ONE dispatch per direction — the weight gather w[eid] happens inside
+    the arena computation — and dw is the sampled dot over the same arena
+    plus one scatter to canonical order.  A concrete arena pair runs a
+    memoized jitted executor; traced arenas trace inline.  Parity of both
+    executors with the dense oracle (kernels/ref.py):
     tests/test_learnable_edges.py.
     """
-    backend = _learnable_effective_backend(fwd, backend)
-    if backend in ("pallas_fused", "xla_fused"):
-        fwd, bwd = _fused_eid_of(fwd), _fused_eid_of(bwd)
-    return _learnable_executable(fwd, bwd, nnz, dim, backend)(
-        w_canon, x_vals, x_idx)
+    _check_backend(backend)
+    fwd, bwd = _fused_of(fwd, eids=True), _fused_of(bwd, eids=True)
+    if isinstance(fwd.nbr, jax.core.Tracer):
+        # traced arenas (a collated batch as a jit argument): trace inline,
+        # the outer jit owns the caching
+        op = _learnable_vjp(fwd, bwd, nnz, dim, backend, None)
+    else:
+        op = _learnable_executable(fwd, bwd, nnz, dim, backend)
+    return op(w_canon, x_vals, x_idx)
 
 
 # ---------------------------------------------------------------------------
@@ -671,21 +487,6 @@ def drspmm_learnable(fwd, bwd, nnz: int, w_canon: jax.Array,
 # (DESIGN.md §9).
 # ---------------------------------------------------------------------------
 
-def _multi_effective_backend(backend: Backend) -> Backend:
-    """Same family rules as :func:`_effective_backend`: a RelationPlan is
-    always pre-fused (super-arenas have no bucket slabs to loop over), so
-    per-bucket names upgrade to the fused executor of the matching family;
-    ``dense`` keeps the oracle.  The traced-downgrade counterpart lives in
-    :func:`drspmm_multi` itself: a plan whose leaves are jit tracers skips
-    the id-keyed executor cache and traces inline (the outer jit owns the
-    caching), since id-keying traced pytrees would be meaningless."""
-    if backend in ("pallas", "pallas_fused"):
-        return "pallas_fused"
-    if backend == "dense":
-        return "dense"
-    return "xla_fused"
-
-
 def _multi_concat(plan: RelationPlan, vals, idxs):
     """Stack per-type CBSR operands into the plan's type-concat slab,
     padding k up to the group max (padded value columns are zero, so they
@@ -695,7 +496,7 @@ def _multi_concat(plan: RelationPlan, vals, idxs):
     Values and indices travel together as one (n_t, 2, k) stack per type —
     f32 values bitcast to int32 — so the assembly is ONE pad + ONE
     concatenate instead of a separate pad/concat pair per operand (the
-    forward-path overhead BENCH_drspmm attributed to the type-concat
+    forward-path overhead a CPU profile attributed to the type-concat
     gather).  The shared container is int32, NOT float32: small column
     indices bitcast to f32 are denormals, and the jit partitioner is free
     to flush those to zero when this concat fuses with a shard_map reshard
@@ -869,75 +670,18 @@ def _hybrid_bwd(plan: RelationPlan, gy_cat, xi, backend: Backend):
     return dx_cat, dv_dense
 
 
-def _super_dense_mat(f: FusedELL):
-    """Dense matrix of a (super-)arena built from its own tables — works
-    with traced leaves, unlike the host-side ``to_dense``."""
-    slot_rows = jnp.take(jnp.asarray(f.rows), _arena_rows(f), axis=0)
-    nbr = jnp.asarray(f.nbr)
-    a = jnp.zeros((f.n_dst, f.n_src), jnp.float32)
-    return a.at[jnp.broadcast_to(slot_rows[:, :, None], nbr.shape),
-                nbr].add(jnp.asarray(f.w))
-
-
-def _plan_dense_mat(plan: RelationPlan):
-    """Full (n_out_total, n_src_total) block matrix across BOTH tiers,
-    built from the plan's own tables — works with traced leaves, unlike the
-    host-side :meth:`RelationPlan.to_dense`."""
-    a = jnp.zeros((plan.n_out_total, plan.n_src_total), jnp.float32)
-    if plan.has_arena:
-        fa = _super_dense_mat(plan.fwd)
-        for s in plan.arena_segments:
-            a = a.at[s.out_off:s.out_off + s.n_dst].set(
-                fa[s.arena_out_off:s.arena_out_off + s.n_dst])
-    if plan.has_dense:
-        df = jnp.asarray(plan.dense_fwd, jnp.float32)
-        for s in plan.dense_segments:
-            a = a.at[s.out_off:s.out_off + s.n_dst].set(
-                df[s.dense_off:s.dense_off + s.n_dst])
-    return a
-
-
 def _build_multi(plan: RelationPlan, dim: int, backend: Backend,
-                 trace_key=None):
+                 trace_key):
     """Custom-vjp callable over (vals_tuple, idxs_tuple): at most one fused
     arena dispatch plus one batched dense-tier dispatch per direction —
     O(1) per layer, not O(relations) — with the type-concat ``xi`` saved as
     a forward residual so the backward never re-runs the concat."""
 
-    def probe():
-        if trace_key is not None:
-            _MULTI_TRACES.append(trace_key)
-
-    if backend == "dense":
-        def impl(vals, idxs):
-            probe()
-            xv, xi, _ = _multi_concat(plan, vals, idxs)
-            n = xv.shape[0]
-            xd = jnp.zeros((n, dim), xv.dtype).at[
-                jnp.arange(n)[:, None], xi].add(xv)
-            return _split_out(plan, _plan_dense_mat(plan) @ xd), xi
-
-        def bwd_impl(xi, idxs, gys):
-            # full-coordinate transposed oracle: summing every relation's
-            # Aᵀ·gy into the type-concat rows FIRST and sampling once is
-            # exact — take_along_axis at a type's shared xi is linear.
-            gy_cat = jnp.concatenate(list(gys))
-            dx_full = _plan_dense_mat(plan).T @ gy_cat    # (n_src_total, D)
-            dv = jnp.take_along_axis(dx_full, xi, axis=1)
-            return tuple(
-                dv[int(o):int(o) + int(sz)][:, :int(i.shape[1])]
-                for o, sz, i in zip(plan.src_off, plan.src_sizes, idxs))
-    else:
-        def impl(vals, idxs):
-            probe()
-            xv, xi, _ = _multi_concat(plan, vals, idxs)
-            y_cat = _hybrid_fwd(plan, xv, xi, dim, backend)
-            return _split_out(plan, y_cat), xi
-
-        def bwd_impl(xi, idxs, gys):
-            gy_cat = jnp.concatenate(list(gys))
-            dx_cat, dv_dense = _hybrid_bwd(plan, gy_cat, xi, backend)
-            return _dx_cat_to_types(plan, dx_cat, dv_dense, idxs)
+    def impl(vals, idxs):
+        _MULTI_TRACES.append(trace_key)
+        xv, xi, _ = _multi_concat(plan, vals, idxs)
+        y_cat = _hybrid_fwd(plan, xv, xi, dim, backend)
+        return _split_out(plan, y_cat), xi
 
     @jax.custom_vjp
     def f(vals, idxs):
@@ -949,7 +693,9 @@ def _build_multi(plan: RelationPlan, dim: int, backend: Backend,
 
     def f_bwd(res, gys):
         xi, idxs = res
-        return (bwd_impl(xi, idxs, gys),
+        gy_cat = jnp.concatenate(list(gys))
+        dx_cat, dv_dense = _hybrid_bwd(plan, gy_cat, xi, backend)
+        return (_dx_cat_to_types(plan, dx_cat, dv_dense, idxs),
                 tuple(np.zeros(np.shape(i), jax.dtypes.float0)
                       for i in idxs))
 
@@ -957,15 +703,16 @@ def _build_multi(plan: RelationPlan, dim: int, backend: Backend,
     return f
 
 
-def _zero_plan_cotangent(plan):
-    """Symbolic-zero cotangent pytree for a plan passed as a custom-vjp
-    primal: float0 for the integer tables, dense zeros for the float w
-    arenas (custom_vjp requires real-dtype cotangents for float leaves)."""
+def _zero_cotangent(tree):
+    """Symbolic-zero cotangent pytree for a structural custom-vjp primal
+    (a plan, an arena, CBSR indices): float0 for the integer tables, dense
+    zeros for the float w arenas (custom_vjp requires real-dtype
+    cotangents for float leaves)."""
     def z(x):
         if jnp.issubdtype(jnp.result_type(x), jnp.floating):
             return jnp.zeros_like(x)
         return np.zeros(np.shape(x), jax.dtypes.float0)
-    return jax.tree.map(z, plan)
+    return jax.tree.map(z, tree)
 
 
 def _multi_traced(plan: RelationPlan, vals, idxs, dim: int, backend: Backend):
@@ -1001,7 +748,7 @@ def _multi_traced(plan: RelationPlan, vals, idxs, dim: int, backend: Backend):
         plan, xi, idxs = res
         gy_cat = jnp.concatenate(list(gys))
         dx_cat, dv_dense = _hybrid_bwd(plan, gy_cat, xi, backend)
-        return (_zero_plan_cotangent(plan),
+        return (_zero_cotangent(plan),
                 _dx_cat_to_types(plan, dx_cat, dv_dense, idxs),
                 tuple(np.zeros(np.shape(i), jax.dtypes.float0)
                       for i in idxs))
@@ -1053,31 +800,21 @@ def drspmm_multi(plan: RelationPlan, cbsr, dim: int, *,
     (summed across the relations that consume the type); ``idx`` is
     structural (float0 cotangent).
 
-    Backend rules mirror ``drspmm``/``drspmm_learnable``: plans are always
-    pre-fused, so per-bucket names upgrade to the fused family
-    (``pallas``→``pallas_fused``, ``xla``→``xla_fused``); ``dense`` is the
-    autograd-free oracle with the Alg.-2 sampled backward.  A concrete plan
-    routes through the id-keyed LRU executor cache
-    (no retrace on repeat calls); a TRACED plan — e.g. a collated serve
-    batch whose graph is a jit argument, or any plan seen inside a
+    A concrete plan routes through the id-keyed LRU executor cache (no
+    retrace on repeat calls); a TRACED plan — e.g. a collated serve batch
+    whose graph is a jit argument, or any plan seen inside a
     ``jax.checkpoint`` body — is executed inline with the plan threaded as
     a custom-vjp primal (``_multi_traced``: remat-safe, plan saved once as
-    an aliased residual) and cached by the outer jit.  Parity across all
-    five names: tests/test_relation_plan.py.
+    an aliased residual) and cached by the outer jit.  Parity with the
+    serial per-relation path: tests/test_relation_plan.py.
     """
-    eff = _multi_effective_backend(backend)
+    _check_backend(backend)
     vals = tuple(cbsr[t][0] for t in plan.src_types)
     idxs = tuple(cbsr[t][1] for t in plan.src_types)
     if isinstance(plan.fwd.nbr, jax.core.Tracer):
-        if eff == "dense":
-            # the oracle closure is traced inline; the outer jit owns the
-            # cache (the oracle is not remat-threaded like _multi_traced —
-            # checkpointed layers always use the fused families)
-            ys = _build_multi(plan, dim, eff)(vals, idxs)
-        else:
-            ys = _multi_traced(plan, vals, idxs, dim, eff)
+        ys = _multi_traced(plan, vals, idxs, dim, backend)
     else:
-        ys = _multi_executable(plan, dim, eff)(vals, idxs)
+        ys = _multi_executable(plan, dim, backend)(vals, idxs)
     return {s.etype: y for s, y in zip(plan.segments, ys)}
 
 
@@ -1209,7 +946,7 @@ def _gen_arena(plan: RelationPlan, m_cat, t_rel, backend: Backend):
     def f_bwd(res, gy):
         plan, m_cat, t_rel, y, lse = res
         dm, dt = _gen_arena_bwd(plan, m_cat, t_rel, y, lse, gy, backend)
-        return _zero_plan_cotangent(plan), dm, dt
+        return _zero_cotangent(plan), dm, dt
 
     f.defvjp(f_fwd, f_bwd)
     return f(plan, m_cat, t_rel)
@@ -1255,10 +992,8 @@ def softmax_aggr_multi(plan: RelationPlan, x_by_type, t_by_relation, *,
     ``t``.  Edge weights in the plan only mark edges (non-zero); their
     values are not used.  Arena-tier relations run as one ``gen_aggr_fwd``
     and one ``gen_aggr_bwd`` per call on ``pallas_fused`` (f32, per-channel
-    running max), and as the same arena walk in XLA on ``xla_fused``; the
-    other backend names follow ``drspmm_multi``'s family rules."""
-    eff = "pallas_fused" if _multi_effective_backend(backend) \
-        == "pallas_fused" else "xla_fused"
+    running max), and as the same arena walk in XLA on ``xla_fused``."""
+    _check_backend(backend)
     m = {t: jax.nn.relu(x_by_type[t].astype(jnp.float32)) + GEN_EPS
          for t in plan.src_types}
     out = {}
@@ -1266,7 +1001,7 @@ def softmax_aggr_multi(plan: RelationPlan, x_by_type, t_by_relation, *,
         m_cat = jnp.concatenate([m[t] for t in plan.src_types])
         t_rel = jnp.stack([jnp.asarray(t_by_relation[s.etype], jnp.float32)
                            for s in plan.arena_segments])
-        y = _gen_arena(plan, m_cat, t_rel, eff)
+        y = _gen_arena(plan, m_cat, t_rel, backend)
         for s in plan.arena_segments:
             out[s.etype] = y[s.arena_out_off:s.arena_out_off + s.n_dst]
     for s in plan.dense_segments:
@@ -1286,15 +1021,6 @@ def softmax_aggr_multi(plan: RelationPlan, x_by_type, t_by_relation, *,
 # halo exchange per direction.  Each device holds only its local arenas +
 # owned operand slabs; the §1/§5 per-shard contraction is unchanged.
 # ---------------------------------------------------------------------------
-
-def _sharded_effective_backend(backend: Backend) -> Backend:
-    """The sharded path only has the fused per-shard executors (local
-    arenas are always pre-fused; the dense oracle lives host-side as
-    ``plan_shard.reference_forward``), so every name maps to the fused
-    executor of its family."""
-    return "pallas_fused" if backend in ("pallas", "pallas_fused") \
-        else "xla_fused"
-
 
 def _local_fused(tabs, n_dst: int, n_src: int, row_block: int,
                  chunk: int) -> FusedELL:
@@ -1379,7 +1105,7 @@ def _build_multi_sharded(splan, dim: int, backend: Backend, trace_key=None):
                 splan.fwd_start, splan.fwd_rows, splan.fwd_gather)
     bwd_tabs = (splan.bwd_nbr, splan.bwd_w, splan.bwd_block_of,
                 splan.bwd_start, splan.bwd_rows, splan.bwd_gather)
-    family = "pallas" if backend == "pallas_fused" else "xla"
+    family = _family(backend)
 
     def _pad_rows(a, total):
         return jnp.pad(a, ((0, total - a.shape[0]), (0, 0)))
@@ -1451,11 +1177,11 @@ def drspmm_multi_sharded(splan, cbsr, dim: int, *,
     trainer step taking the graph as a jit argument) traces inline and the
     outer jit owns the caching.
     """
-    eff = _sharded_effective_backend(backend)
+    _check_backend(backend)
     vals = tuple(cbsr[t][0] for t in splan.src_types)
     idxs = tuple(cbsr[t][1] for t in splan.src_types)
     if isinstance(splan.fwd_nbr, jax.core.Tracer):
-        ys = _build_multi_sharded(splan, dim, eff)(vals, idxs)
+        ys = _build_multi_sharded(splan, dim, backend)(vals, idxs)
     else:
-        ys = _sharded_executable(splan, dim, eff)(vals, idxs)
+        ys = _sharded_executable(splan, dim, backend)(vals, idxs)
     return {s.etype: y for s, y in zip(splan.segments, ys)}

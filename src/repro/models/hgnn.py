@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.core.drelu import drelu
 from repro.core.hetero_mp import (HeteroLayerParams, HeteroMPConfig,
                                   _plan_for, hetero_conv, init_hetero_layer)
-from repro.graphs.circuit import CircuitGraph
+from repro.graphs.circuit import CircuitGraph, with_fused_edges
 from repro.graphs.ell import BucketedELL, ell_to_coo, pack_fused_eid_pair
 from repro.kernels import ops
 from repro.models.backbone import (BackboneSpec, apply_stack, init_stack,
@@ -80,6 +80,12 @@ def _hetero_body(cfg: HeteroMPConfig):
     return body
 
 
+def _concrete_bucketed(graph: CircuitGraph) -> bool:
+    adj = graph.edges["near"].adj
+    return (isinstance(adj, BucketedELL)
+            and not isinstance(adj.buckets[0].nbr, jax.core.Tracer))
+
+
 def drcircuitgnn_forward(params: DRCircuitGNNParams, graph: CircuitGraph,
                          cfg: HeteroMPConfig,
                          spec: Optional[BackboneSpec] = None) -> jax.Array:
@@ -94,6 +100,11 @@ def drcircuitgnn_forward(params: DRCircuitGNNParams, graph: CircuitGraph,
     h_net = graph.x_net @ params.in_net
     # layer-invariant hoist: ONE plan resolution per stack application
     plan = _plan_for(graph, cfg, h_cell.shape[-1])
+    if plan is None and _concrete_bucketed(graph):
+        # The serial path runs inside the stack, where remat's checkpoint
+        # traces the graph and the ops take no BucketedELL: fuse host-side
+        # here (memoized per packing), as the trainer's no-plan step does.
+        graph = with_fused_edges(graph)
     if spec.remat and isinstance(plan, ShardedRelationPlan):
         # The mesh-sharded executor (DESIGN.md §12) needs its plan
         # pre-placed with a NamedSharding, which a checkpoint-traced primal
@@ -247,8 +258,9 @@ def learnable_edge_packing(adj: BucketedELL):
 def _homo_body(kind: str, adj, adj_t, backend: ops.Backend):
     """One homogeneous backbone layer (relu included).  ``adj``/``adj_t``
     are closed over — the homo baselines run on concrete (host-packed)
-    graphs, and the gat/gat_edge kinds need the host-side
-    :func:`learnable_edge_packing` anyway."""
+    graphs, which the ops fuse host-side on first use, and the
+    gat/gat_edge kinds need the host-side :func:`learnable_edge_packing`
+    (fused eid arenas) anyway."""
     def body(lw, h, _const):
         if kind == "sage":
             w_nbr, w_self = lw

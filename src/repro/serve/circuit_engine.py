@@ -568,7 +568,7 @@ class CircuitServeEngine:
                     layout = self._layouts.get(key)  # LRU touch; may evict
                     lock = self._buckets.setdefault(key, _BucketState()).lock
                 with lock:
-                    batch = collate_graphs(graphs, fused=True, quantize=True,
+                    batch = collate_graphs(graphs, quantize=True,
                                            node_bits=self.node_bits,
                                            arena_bits=self.arena_bits,
                                            chunk=self.chunk, layout=layout,
@@ -635,6 +635,12 @@ class CircuitServeEngine:
         return reqs, batch, out, version, dev_idx, t_disp
 
     def _complete(self, inflight):
+        self._commit(inflight, *self._await(inflight))
+
+    def _await(self, inflight):
+        """Device barrier and output guard of a dispatched batch; returns
+        its predictions and the recorder time they arrived.  Raises for the
+        containment ladder; commits nothing (:meth:`_commit`)."""
         reqs, batch, out, version, dev_idx, t_disp = inflight
         try:
             preds = np.asarray(out)                   # device barrier
@@ -666,6 +672,12 @@ class CircuitServeEngine:
                 f"non-finite predictions for request(s) {rids} "
                 f"({counts} bad cells of "
                 f"{[m.n_cell for _, m in bad]}) on ring slot {dev_idx}")
+        return preds, (self._rec.now() if self._rec.enabled else 0.0)
+
+    def _commit(self, inflight, preds, t_ready: float) -> None:
+        """Publish a completed batch's member results (``t_done`` stamped
+        here, so commits made in dispatch order finish in it)."""
+        reqs, batch, out, version, dev_idx, t_disp = inflight
         now = time.perf_counter()
         with self._done:
             committed = []
@@ -698,7 +710,7 @@ class CircuitServeEngine:
             # vs healing re-dispatch), which B/E pairs cannot express
             self._rec.complete(
                 f"device/{dev_idx}", "batch", t_disp,
-                self._rec.now() - t_disp, requests=len(committed),
+                t_ready - t_disp, requests=len(committed),
                 batch=len(reqs), params_version=version)
 
     def _evict_bucket(self, key: tuple, layout) -> None:
@@ -912,7 +924,8 @@ class CircuitServeEngine:
         max_wait_s = self.max_wait_ms * 1e-3
         n_dev = len(self.ring)
         prep: Deque = deque()       # (Future of _prepare, reqs, t0, dev)
-        inflight: Deque = deque()   # (Future of _complete, reqs, t0, dev)
+        inflight: Deque = deque()   # (Future of _await, reqs, t0, dev,
+        #                             dispatched entry)
 
         def overdue(t_start: float) -> bool:
             return (self.watchdog_s is not None
@@ -942,19 +955,24 @@ class CircuitServeEngine:
             except Exception as e:
                 heal_async(reqs_p, e)
                 return
-            cfut = pool.submit(self._complete, entry)
+            # the pool thread only awaits the device; this thread commits
+            # in dispatch order (reap_head), so a later batch whose await
+            # returns first cannot finish before an earlier one
+            cfut = pool.submit(self._await, entry)
             cfut.add_done_callback(self._notify_work)
-            inflight.append((cfut, reqs_p, t_start, entry[4]))
+            inflight.append((cfut, reqs_p, t_start, entry[4], entry))
 
         def reap_head():
-            cfut, reqs_c, t_start, dev_idx = inflight.popleft()
+            cfut, reqs_c, t_start, dev_idx, entry = inflight.popleft()
             if cfut.done():
                 exc = cfut.exception()
                 if exc is not None:
                     heal_async(reqs_c, exc)
+                else:
+                    self._commit(entry, *cfut.result())
             else:
-                # overdue and still running: abandon the attempt (the
-                # `final` flags void its late commit) and time it out
+                # overdue and still running: abandon the attempt (its
+                # result is never committed) and time it out
                 self._on_watchdog(reqs_c, dev_idx)
 
         with self._lock:
@@ -966,7 +984,7 @@ class CircuitServeEngine:
             # already-queued requests and returns).  _stop resets on exit,
             # so a later serve_forever() starts fresh.
         t0 = time.perf_counter()
-        # +2 workers: a wedged _complete occupying a worker past its
+        # +2 workers: a wedged _await occupying a worker past its
         # watchdog must not starve the packing lookahead
         pool = ThreadPoolExecutor(
             max_workers=max(self.n_pack_threads, n_dev) + 2)

@@ -18,7 +18,8 @@ import numpy as np
 from repro.core.hetero_mp import HeteroMPConfig, plan_applicable
 from repro.fault.inject import FaultInjector
 from repro.fault.monitor import StepMonitor
-from repro.graphs.circuit import CircuitGraph, relation_plan_of
+from repro.graphs.circuit import (CircuitGraph, relation_plan_of,
+                                  with_fused_edges)
 from repro.graphs.collate import collate_graphs
 from repro.kernels import ops
 from repro.models import deepgen
@@ -181,8 +182,8 @@ class CircuitTrainer:
         ``device.memory_stats()``, and an accelerator that does not report
         it raises.  Only the CPU backend, which has no memory stats, falls
         back to a live-buffer estimate (Σ nbytes over ``jax.live_arrays()``).
-        The deterministic compiled-peak measure (``memory_analysis()``)
-        lives in benchmarks/bench_backbone.py."""
+        The benchmark's ``device.peak_hbm_mb`` reads the same
+        ``peak_bytes_in_use`` after its window (bench/drivers/train.py)."""
         dev = jax.devices()[0]
         if dev.platform == "cpu":
             return int(sum(x.nbytes for x in jax.live_arrays()))
@@ -311,34 +312,39 @@ class CircuitTrainer:
         return float(np.average(losses, weights=weights)), total, True
 
     def _planned(self, g: CircuitGraph) -> CircuitGraph:
-        """``g`` with its RelationPlan attached and device-resident, cached
-        per graph — the jitted step takes the graph as a traced argument,
-        so the plan must ride along as pytree leaves (host packing is
-        impossible inside the trace); caching the ``device_put`` avoids
-        re-uploading the plan's host arrays every step.  The jit cache is
-        keyed by shapes, so equal-shaped graphs still share one executable.
+        """``g`` in the form the jitted step takes, device-resident and
+        cached per graph: with its RelationPlan attached, or — where the
+        plan path does not apply (the serial per-relation path) — with its
+        edge packings fused.  The step takes the graph as a traced
+        argument, and host packing is impossible inside the trace; caching
+        the ``device_put`` avoids re-uploading the host arrays every step.
+        The jit cache is keyed by shapes, so equal-shaped graphs still
+        share one executable.
         """
-        if self.cfg.model == "drcircuitgnn" and \
-                not plan_applicable(self.mp_cfg, self.cfg.hidden):
-            return g
         key = id(g)
         hit = self._plan_cache.get(key)
         if hit is not None and hit[0] is g:
             return hit[1]
-        if self.cfg.n_shards > 1:
-            # giant-graph step: the partitioned plan's stacked tables are
-            # device_put PRE-SHARDED over the ("shard",) mesh, so each
-            # device ever holds only its arena slices and the jitted step's
-            # shard_map consumes them without resharding
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            from repro.graphs.circuit import sharded_plan_of
-            from repro.sharding.specs import shard_mesh
-            plan = sharded_plan_of(g, self.cfg.n_shards)
-            where = NamedSharding(shard_mesh(self.cfg.n_shards), P("shard"))
+        if self.cfg.model == "drcircuitgnn" and \
+                not plan_applicable(self.mp_cfg, self.cfg.hidden):
+            with span("train.plan_upload", self._rec):
+                pg = jax.device_put(with_fused_edges(g))
         else:
-            plan, where = relation_plan_of(g), None
-        with span("train.plan_upload", self._rec):
-            pg = dataclasses.replace(g, plan=jax.device_put(plan, where))
+            if self.cfg.n_shards > 1:
+                # giant-graph step: the partitioned plan's stacked tables
+                # are device_put PRE-SHARDED over the ("shard",) mesh, so
+                # each device ever holds only its arena slices and the
+                # jitted step's shard_map consumes them without resharding
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                from repro.graphs.circuit import sharded_plan_of
+                from repro.sharding.specs import shard_mesh
+                plan = sharded_plan_of(g, self.cfg.n_shards)
+                where = NamedSharding(shard_mesh(self.cfg.n_shards),
+                                      P("shard"))
+            else:
+                plan, where = relation_plan_of(g), None
+            with span("train.plan_upload", self._rec):
+                pg = dataclasses.replace(g, plan=jax.device_put(plan, where))
         self._c_plan_uploads.inc()
         self._plan_cache[key] = (g, pg)
         return pg

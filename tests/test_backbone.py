@@ -3,6 +3,8 @@ remat-vs-not numeric parity, executor-cache hygiene under recompute, the
 per-layer CBSR hoist, init RNG parity with the pre-backbone code, and the
 serve engine's multi-tenant head registry."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +89,49 @@ def test_remat_trained_params_parity(graph):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
     assert stats[True]["peak_memory_bytes"] > 0
+
+
+SERIAL_CFGS = {
+    "no_plan": dict(use_plan=False),
+    "dense_baseline": dict(use_drelu=False),
+    "k_at_hidden": dict(k_cell=32, k_net=32),
+}
+
+
+@pytest.mark.parametrize("serial", sorted(SERIAL_CFGS))
+def test_remat_parity_serial_path(graph, serial):
+    """The serial per-relation path under remat: the checkpoint traces the
+    concrete graph, so the forward fuses its packings before the stack.
+    Loss and grads agree with the plain stack on the same config."""
+    cfg = dataclasses.replace(CFG, **SERIAL_CFGS[serial])
+    params = _params(graph, 3)
+    outs = {}
+    for remat in (False, True):
+        spec = BackboneSpec(depth=3, hidden=32, remat=remat)
+        outs[remat] = jax.value_and_grad(
+            lambda p: loss_fn(p, graph, cfg, spec))(params)
+    assert np.isclose(float(outs[True][0]), float(outs[False][0]), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(outs[True][1]),
+                    jax.tree.leaves(outs[False][1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("serial", ["no_plan", "dense_baseline"])
+def test_remat_trainer_evaluate_serial_path(graph, serial):
+    """``evaluate`` runs the un-jitted forward on a concrete graph; with
+    remat on a serial-path config it must match the remat-free trainer."""
+    metrics = {}
+    for remat in (False, True):
+        cfg = CircuitTrainConfig(hidden=32, k_cell=8, k_net=8, n_layers=2,
+                                 remat=remat, **SERIAL_CFGS[serial])
+        tr = CircuitTrainer(cfg, graph.x_cell.shape[1],
+                            graph.x_net.shape[1])
+        metrics[remat] = tr.evaluate([graph])
+    assert metrics[True].keys() == metrics[False].keys()
+    for k in metrics[False]:
+        assert np.isclose(metrics[True][k], metrics[False][k],
+                          rtol=1e-4, atol=1e-6), k
 
 
 # ----------------------------------------------------------- wiring

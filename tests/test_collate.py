@@ -1,9 +1,11 @@
 """Block-diagonal collation (graphs/collate.py).
 
 A collated batch must be numerically indistinguishable from its members run
-one at a time: forward and gradients match per-graph results under every
-backend, quantization padding contributes exactly zero to member outputs,
-and the member offsets tile the merged node spaces without overlap.
+one at a time: forward and gradients match per-graph results under both
+executors and across the batch packings (all-arena plan, mixed dense-tier
+plan, quantized with a filler member), quantization padding contributes
+exactly zero to member outputs, and the member offsets tile the merged node
+spaces without overlap.
 """
 
 import jax
@@ -27,7 +29,14 @@ from repro.models.hgnn import (batched_loss_fn, drcircuitgnn_forward,
 settings.register_profile("fast", max_examples=20, deadline=None)
 settings.load_profile("fast")
 
-BACKENDS = ("pallas_fused", "xla_fused", "pallas", "xla", "dense")
+BACKENDS = ("pallas_fused", "xla_fused")
+# batch packings: exact-size collation with every plan relation on the
+# arena tier; exact size with ``near`` on the arena tier and ``pin`` /
+# ``pinned`` on the dense tier; quantized with a filler replica of the last
+# member (tiers as the batch's sizes route them)
+PACKINGS = ("arena", "mixed", "filler")
+_TIERS = {"arena": {"near": "arena", "pin": "arena", "pinned": "arena"},
+          "mixed": {"near": "arena", "pin": "dense", "pinned": "dense"}}
 
 
 def _graph(n_cell, n_net, seed):
@@ -50,30 +59,46 @@ def _cfg(backend):
     return HeteroMPConfig(hidden=32, k_cell=8, k_net=8, backend=backend)
 
 
+def _collated(members, packing):
+    if packing == "filler":
+        return collate_graphs(members + [members[-1]], n_real=len(members))
+    batch = collate_graphs(
+        members, quantize=False,
+        layout=BucketLayout(plan_tier=dict(_TIERS[packing])))
+    assert {s.etype: s.tier for s in batch.plan.segments} == _TIERS[packing]
+    return batch
+
+
 def _assert_close(actual, ref, msg):
     atol = 1e-5 * max(1.0, float(np.abs(ref).max()) if ref.size else 1.0)
     np.testing.assert_allclose(actual, ref, atol=atol, rtol=1e-5,
                                err_msg=msg)
 
 
+@pytest.mark.parametrize("packing", PACKINGS)
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_batched_forward_matches_member_loop(members, params, backend):
-    """Exact (unquantized, bucketed) collation: every backend sees the same
-    block-diagonal graph and must reproduce the per-member forwards."""
+def test_batched_forward_matches_member_loop(members, params, backend,
+                                             packing):
+    """The collated block-diagonal graph reproduces the per-member
+    forwards under both executors, whatever the batch's packing."""
     cfg = _cfg(backend)
-    batch = collate_graphs(members, fused=False, quantize=False)
+    batch = _collated(members, packing)
     parts = batch.split_cell(drcircuitgnn_forward(params, batch.graph, cfg))
+    assert len(parts) == len(members)
     for i, (g, p) in enumerate(zip(members, parts)):
         ref = np.asarray(drcircuitgnn_forward(params, g, cfg))
-        _assert_close(np.asarray(p), ref, f"member {i} fwd {backend}")
+        _assert_close(np.asarray(p), ref,
+                      f"member {i} fwd {backend}/{packing}")
 
 
+@pytest.mark.parametrize("packing", PACKINGS)
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_batched_gradients_match_member_loop(members, params, backend):
+def test_batched_gradients_match_member_loop(members, params, backend,
+                                             packing):
     """∇ of the weighted batched loss == ∇ of the mean of per-graph mean-MSE
     losses — the property that makes train_epoch(batch_size=B) a drop-in."""
     cfg = _cfg(backend)
-    batch = collate_graphs(members, fused=False, quantize=False)
+    batch = _collated(members, packing)
     g_b = jax.grad(batched_loss_fn)(params, batch.graph, batch.cell_weight,
                                     cfg)
     g_ref = None
@@ -86,7 +111,7 @@ def test_batched_gradients_match_member_loop(members, params, backend):
             jax.tree_util.tree_leaves_with_path(g_b),
             jax.tree_util.tree_leaves_with_path(g_ref)):
         _assert_close(np.asarray(a), np.asarray(r),
-                      f"grad {jax.tree_util.keystr(pa)} {backend}")
+                      f"grad {jax.tree_util.keystr(pa)} {backend}/{packing}")
 
 
 @pytest.mark.parametrize("backend", ["xla_fused", "pallas_fused"])
@@ -96,8 +121,8 @@ def test_quantization_padding_is_invariant(members, params, backend):
     both fused executors — the Pallas kernel must tolerate the padding
     chunks extending the sentinel block's run."""
     cfg = _cfg(backend)
-    exact = collate_graphs(members, fused=False, quantize=False)
-    quant = collate_graphs(members, fused=True, quantize=True)
+    exact = collate_graphs(members, quantize=False)
+    quant = collate_graphs(members, quantize=True)
     assert quant.graph.n_cell >= exact.graph.n_cell
     p_exact = exact.split_cell(drcircuitgnn_forward(params, exact.graph, cfg))
     p_quant = quant.split_cell(drcircuitgnn_forward(params, quant.graph, cfg))
@@ -116,11 +141,11 @@ def test_quantization_padding_is_invariant(members, params, backend):
 
 
 def test_fused_collation_runs_fused_inside_jit(members, params):
-    """The whole point of pre-fused arenas: the batched forward keeps the
-    fused executor even when the graph is a traced jit argument (no
-    per-bucket fallback, no recompile for an equal-signature batch)."""
+    """The whole point of pre-fused arenas: the batched forward runs even
+    when the graph is a traced jit argument (no recompile for an
+    equal-signature batch)."""
     cfg = _cfg("xla_fused")
-    batch = collate_graphs(members, fused=True, quantize=True)
+    batch = collate_graphs(members, quantize=True)
 
     fwd = jax.jit(lambda p, g: drcircuitgnn_forward(p, g, cfg))
     y1 = fwd(params, batch.graph)
@@ -131,7 +156,7 @@ def test_fused_collation_runs_fused_inside_jit(members, params):
         # hit the compiled executable
         other = collate_graphs([_graph(62, 31, 7), _graph(99, 56, 8),
                                 _graph(36, 20, 9)],
-                               fused=True, quantize=True)
+                               quantize=True)
         if other.signature == batch.signature:
             fwd(params, other.graph)
             assert fwd._cache_size() == 1
@@ -201,7 +226,7 @@ def test_collate_offsets_roundtrip(args):
     rng = np.random.default_rng(seed)
     members = [_graph(int(rng.integers(12, 48)), int(rng.integers(6, 24)),
                       int(rng.integers(0, 2 ** 31))) for _ in range(n_members)]
-    batch = collate_graphs(members, fused=False, quantize=quantize)
+    batch = collate_graphs(members, quantize=quantize)
     g = batch.graph
     off = {"cell": [m.cell_off for m in batch.members],
            "net": [m.net_off for m in batch.members]}

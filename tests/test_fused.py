@@ -1,8 +1,8 @@
-"""Parity + packing tests for the fused single-dispatch DR-SpMM executor.
+"""Parity + packing tests for the fused single-dispatch DR-SpMM executors.
 
-The fused arena path ("pallas_fused") must be numerically interchangeable
-with the per-bucket Pallas path, the bucketed XLA path, and the dense oracle
-— forward and gradient — across the degree distributions that stress the
+Both executors of the fused arena ("pallas_fused", "xla_fused") must match
+the dense oracle (kernels/ref.py) — forward and gradient, on the arena tier
+and on the dense tier — across the degree distributions that stress the
 packing: empty buckets, single evil rows, all-rows-one-bucket, non-divisible
 row counts, and the empty matrix.
 """
@@ -19,14 +19,17 @@ except ImportError:                      # bare container: seeded fallback
 
 from repro.core.cbsr import cbsr_from_dense
 from repro.core.drelu import drelu
-from repro.graphs.ell import (fuse_bucketed, pack_ell, pack_ell_pair,
-                              pack_fused, ROW_BLOCK)
-from repro.kernels import ops
+from _dispatch import dispatch_count
+from repro.graphs.ell import (DENSE_TIER_NNZ, fuse_bucketed, pack_ell,
+                              pack_ell_pair, pack_fused, ROW_BLOCK)
+from repro.kernels import ops, ref
 
 settings.register_profile("fast", max_examples=25, deadline=None)
 settings.load_profile("fast")
 
-BACKENDS = ("pallas_fused", "xla_fused", "pallas", "xla")
+BACKENDS = ("pallas_fused", "xla_fused")
+# the op's dense-tier crossover, and -1: every relation on the arena tier
+TIERS = {"dense": DENSE_TIER_NNZ, "arena": -1}
 
 
 def _assert_close(actual, ref, msg):
@@ -70,7 +73,7 @@ def _coo(name, rng):
 
 @pytest.mark.parametrize("dist", ["empty", "evil_row", "one_bucket", "mixed"])
 @pytest.mark.parametrize("dim", [64, 256])
-def test_drspmm_backend_parity(dist, dim):
+def test_drspmm_backend_parity(dist, dim, monkeypatch):
     rng = np.random.default_rng(hash(dist) % 2 ** 31)
     dst, src, w, n_dst, n_src = _coo(dist, rng)
     adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
@@ -78,17 +81,18 @@ def test_drspmm_backend_parity(dist, dim):
     x = rng.normal(size=(n_src, dim)).astype(np.float32)
     c = cbsr_from_dense(drelu(jnp.asarray(x), k), k)
 
-    y_ref = np.asarray(ops.drspmm(adj, adj_t, c.values, c.idx, dim,
-                                  backend="dense"))
-    g_ref = np.asarray(jax.grad(lambda v: jnp.sum(ops.drspmm(
-        adj, adj_t, v, c.idx, dim, backend="dense") ** 2))(c.values))
-    for be in BACKENDS:
-        y = np.asarray(ops.drspmm(adj, adj_t, c.values, c.idx, dim,
-                                  backend=be))
-        _assert_close(y, y_ref, f"fwd {be}/{dist}/d{dim}")
-        g = np.asarray(jax.grad(lambda v: jnp.sum(ops.drspmm(
-            adj, adj_t, v, c.idx, dim, backend=be) ** 2))(c.values))
-        _assert_close(g, g_ref, f"grad {be}/{dist}/d{dim}")
+    y_ref = np.asarray(ref.drspmm_fwd_ref(adj, c.values, c.idx, dim))
+    g_ref = np.asarray(jax.grad(lambda v: jnp.sum(ref.drspmm_fwd_ref(
+        adj, v, c.idx, dim) ** 2))(c.values))
+    for tier, thr in TIERS.items():
+        monkeypatch.setattr(ops, "DENSE_TIER_NNZ", thr)
+        for be in BACKENDS:
+            y = np.asarray(ops.drspmm(adj, adj_t, c.values, c.idx, dim,
+                                      backend=be))
+            _assert_close(y, y_ref, f"fwd {be}/{tier}/{dist}/d{dim}")
+            g = np.asarray(jax.grad(lambda v: jnp.sum(ops.drspmm(
+                adj, adj_t, v, c.idx, dim, backend=be) ** 2))(c.values))
+            _assert_close(g, g_ref, f"grad {be}/{tier}/{dist}/d{dim}")
 
 
 @pytest.mark.parametrize("dist", ["empty", "evil_row", "one_bucket", "mixed"])
@@ -97,9 +101,9 @@ def test_spmm_backend_parity(dist):
     dst, src, w, n_dst, n_src = _coo(dist, rng)
     adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
     x = jnp.asarray(rng.normal(size=(n_src, 64)).astype(np.float32))
-    y_ref = np.asarray(ops.spmm(adj, adj_t, x, backend="dense"))
-    g_ref = np.asarray(jax.grad(lambda v: jnp.sum(ops.spmm(
-        adj, adj_t, v, backend="dense") ** 2))(x))
+    y_ref = np.asarray(ref.spmm_dense_ref(adj, x))
+    g_ref = np.asarray(jax.grad(lambda v: jnp.sum(ref.spmm_dense_ref(
+        adj, v) ** 2))(x))
     for be in BACKENDS:
         y = np.asarray(ops.spmm(adj, adj_t, x, backend=be))
         _assert_close(y, y_ref, f"{be}/{dist}")
@@ -108,24 +112,26 @@ def test_spmm_backend_parity(dist):
         _assert_close(g, g_ref, f"grad {be}/{dist}")
 
 
-def test_fused_is_one_dispatch_per_direction():
-    """The fused forward traces to exactly ONE pallas_call; per-bucket
-    traces to one per bucket."""
+def test_fused_is_one_dispatch_per_direction(monkeypatch):
+    """The forward traces to exactly ONE pallas_call and forward+backward
+    to two, though the packing spans several degree buckets — on the arena
+    tier and on the dense tier alike."""
     rng = np.random.default_rng(3)
     dst, src, w, n_dst, n_src = _coo("mixed", rng)
     adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
+    assert len(adj.buckets) >= 2
     k, dim = 8, 64
     x = rng.normal(size=(n_src, dim)).astype(np.float32)
     c = cbsr_from_dense(drelu(jnp.asarray(x), k), k)
 
-    from benchmarks.bench_drspmm import dispatch_count
+    def fwd(v):
+        return ops.drspmm(adj, adj_t, v, c.idx, dim, backend="pallas_fused")
 
-    def n_calls(backend):
-        return dispatch_count(lambda v: ops.drspmm(
-            adj, adj_t, v, c.idx, dim, backend=backend), c.values)
-
-    assert n_calls("pallas_fused") == 1
-    assert n_calls("pallas") == len(adj.buckets) >= 2
+    for thr in TIERS.values():
+        monkeypatch.setattr(ops, "DENSE_TIER_NNZ", thr)
+        assert dispatch_count(fwd, c.values) == 1
+        assert dispatch_count(jax.grad(lambda v: jnp.sum(fwd(v) ** 2)),
+                              c.values) == 2
 
 
 # ------------------------ packing round-trip ---------------------------
@@ -156,22 +162,30 @@ def test_pack_fused_roundtrip(args):
     assert fused.nnz == adj.nnz == int((w != 0).sum())
 
 
-def test_fused_backend_with_traced_graph_falls_back():
+@pytest.mark.parametrize("op", ["spmm", "drspmm"])
+def test_fused_backend_with_traced_graph_falls_back(op):
     """A jitted step that takes the graph as an ARGUMENT (traced pytree)
-    cannot host-pack the fused arena; the op must fall back to the
-    per-bucket path of the same family instead of crashing."""
+    cannot host-pack the fused arena: a traced BucketedELL is refused with
+    a TypeError that says to fuse host-side, and the pre-fused arenas of
+    the same graph run inside the jit."""
     rng = np.random.default_rng(0)
     dst, src, w, n_dst, n_src = _coo("mixed", rng)
     adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
     x = jnp.asarray(rng.normal(size=(n_src, 32)).astype(np.float32))
+    c = cbsr_from_dense(drelu(x, 8), 8)
+    if op == "spmm":
+        call = lambda a, at: ops.spmm(a, at, x, backend="xla_fused")
+        y_ref = ref.spmm_dense_ref(adj, x)
+    else:
+        call = lambda a, at: ops.drspmm(a, at, c.values, c.idx, 32,
+                                        backend="xla_fused")
+        y_ref = ref.drspmm_fwd_ref(adj, c.values, c.idx, 32)
+    step = jax.jit(call)
 
-    @jax.jit
-    def step(a, at, v):
-        return ops.spmm(a, at, v, backend="xla_fused")
-
-    y = np.asarray(step(adj, adj_t, x))
-    y_ref = np.asarray(ops.spmm(adj, adj_t, x, backend="dense"))
-    _assert_close(y, y_ref, "traced-graph fallback")
+    with pytest.raises(TypeError, match="fuse_bucketed"):
+        step(adj, adj_t)
+    y = np.asarray(step(fuse_bucketed(adj), fuse_bucketed(adj_t)))
+    _assert_close(y, np.asarray(y_ref), f"traced fused arenas {op}")
 
 
 def test_fuse_bucketed_memoized():
@@ -190,3 +204,18 @@ def test_nnz_is_static_and_cheap():
     # static field ⇒ part of the pytree aux data, not a device array
     leaves, treedef = jax.tree_util.tree_flatten(adj)
     assert all(not isinstance(l, int) for l in leaves)
+
+
+@pytest.mark.parametrize("name", ["pallas", "xla", "dense"])
+def test_unknown_backend_is_refused(name):
+    """Only the two executors are backend names; any other is refused at
+    the op boundary, not mapped onto one of them."""
+    rng = np.random.default_rng(0)
+    dst, src, w, n_dst, n_src = _coo("mixed", rng)
+    adj, adj_t = pack_ell_pair(dst, src, w, n_dst, n_src)
+    x = jnp.asarray(rng.normal(size=(n_src, 16)).astype(np.float32))
+    c = cbsr_from_dense(drelu(x, 4), 4)
+    with pytest.raises(ValueError, match="unknown backend"):
+        ops.spmm(adj, adj_t, x, backend=name)
+    with pytest.raises(ValueError, match="unknown backend"):
+        ops.drspmm(adj, adj_t, c.values, c.idx, 16, backend=name)

@@ -1,5 +1,5 @@
-"""Hetero message-passing backend parity: topk vs pallas D-ReLU; pallas vs
-xla SpMM inside the full layer."""
+"""Hetero message-passing backend parity: topk vs pallas D-ReLU; the
+pallas_fused vs xla_fused executors inside the full layer."""
 
 import jax
 import jax.numpy as jnp
@@ -34,13 +34,18 @@ def test_pallas_drelu_backend_matches_topk(setup):
 
 
 def test_pallas_spmm_backend_in_layer(setup):
+    """On the plan path and on the serial per-relation path."""
     g, params, xc, xn = setup
-    a = HeteroMPConfig(hidden=16, k_cell=4, k_net=4, backend="xla")
-    b = HeteroMPConfig(hidden=16, k_cell=4, k_net=4, backend="pallas")
-    yca, _ = hetero_conv(params, g, xc, xn, a)
-    ycb, _ = hetero_conv(params, g, xc, xn, b)
-    np.testing.assert_allclose(np.asarray(yca), np.asarray(ycb),
-                               rtol=1e-5, atol=1e-5)
+    for use_plan in (True, False):
+        a = HeteroMPConfig(hidden=16, k_cell=4, k_net=4, backend="xla_fused",
+                           use_plan=use_plan)
+        b = HeteroMPConfig(hidden=16, k_cell=4, k_net=4,
+                           backend="pallas_fused", use_plan=use_plan)
+        for ya, yb in zip(hetero_conv(params, g, xc, xn, a),
+                          hetero_conv(params, g, xc, xn, b)):
+            np.testing.assert_allclose(np.asarray(ya), np.asarray(yb),
+                                       rtol=1e-5, atol=1e-5,
+                                       err_msg=f"use_plan={use_plan}")
 
 
 def test_pallas_drelu_gradients_flow(setup):

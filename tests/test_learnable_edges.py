@@ -1,8 +1,11 @@
-"""Learnable edge weights through DR-SpMM vs dense oracle (fwd + both grads).
+"""Learnable edge weights through DR-SpMM vs the dense oracle
+(kernels/ref.py; fwd + both grads).
 
-Covers the fused single-dispatch path (DESIGN.md §8): 5-backend parity,
-padded eid-slot (−1) inertness, fused eid packing round-trip, executor
-cache hits, and collated (member-offset) eid arenas.
+Covers the fused single-dispatch path (DESIGN.md §8): parity of both
+executors over bucketed eid slabs, pre-fused eid arenas and padded
+(collation-form) arenas, the traced-argument rule, padded eid-slot (−1)
+inertness, fused eid packing round-trip, executor cache hits, and collated
+(member-offset) eid arenas.
 """
 
 import jax
@@ -17,14 +20,17 @@ except ImportError:                      # bare container: seeded fallback
 
 from repro.core.cbsr import cbsr_from_dense
 from repro.graphs.ell import (fuse_bucketed, pack_eid_slabs,
-                              pack_fused_eid_pair)
-from repro.kernels import ops
-from repro.kernels.learnable import drspmm_learnable
+                              pack_fused_eid_pair, pad_fused_arena)
+from repro.kernels import ops, ref
 
 settings.register_profile("fast_learnable", max_examples=25, deadline=None)
 settings.load_profile("fast_learnable")
 
-BACKENDS = ("pallas_fused", "xla_fused", "pallas", "xla", "dense")
+BACKENDS = ("pallas_fused", "xla_fused")
+# the packings the op takes: bucketed eid slabs (fused on first use), eid
+# arenas packed straight from the COO, and those arenas padded with inert
+# chunks and rows as collation pads them
+PACKINGS = ("slabs", "arena", "padded")
 
 
 def setup(seed=0, n_dst=23, n_src=31, nnz_target=200, d=16, k=4):
@@ -42,17 +48,15 @@ def setup(seed=0, n_dst=23, n_src=31, nnz_target=200, d=16, k=4):
     a_rows, a_cols = dst[canon], src[canon]
 
     def dense_y(wv, xv):
-        a = jnp.zeros((n_dst, n_src)).at[a_rows, a_cols].add(wv)
-        xd = jnp.zeros((n_src, d)).at[
-            jnp.arange(n_src)[:, None], c.idx].add(xv)
-        return a @ xd
+        return ref.drspmm_learnable_ref(a_rows, a_cols, n_dst, n_src, wv,
+                                        xv, c.idx, d)
 
     return fwd, bwd, nnz, w, c, d, dense_y
 
 
 def test_forward_matches_dense():
     fwd, bwd, nnz, w, c, d, dense_y = setup()
-    y = drspmm_learnable(fwd, bwd, nnz, w, c.values, c.idx, d)
+    y = ops.drspmm_learnable(fwd, bwd, nnz, w, c.values, c.idx, d)
     np.testing.assert_allclose(np.asarray(y), np.asarray(dense_y(w, c.values)),
                                rtol=1e-5, atol=1e-5)
 
@@ -62,7 +66,7 @@ def test_gradients_match_dense():
 
     def loss(wv, xv):
         return jnp.sum(jnp.sin(
-            drspmm_learnable(fwd, bwd, nnz, wv, xv, c.idx, d)))
+            ops.drspmm_learnable(fwd, bwd, nnz, wv, xv, c.idx, d)))
 
     def loss_ref(wv, xv):
         return jnp.sum(jnp.sin(dense_y(wv, xv)))
@@ -81,7 +85,7 @@ def test_weights_actually_learn():
     target = dense_y(w * 0.3, c.values)
 
     def loss(wv):
-        y = drspmm_learnable(fwd, bwd, nnz, wv, c.values, c.idx, d)
+        y = ops.drspmm_learnable(fwd, bwd, nnz, wv, c.values, c.idx, d)
         return jnp.mean((y - target) ** 2)
 
     l0 = float(loss(w))
@@ -90,18 +94,22 @@ def test_weights_actually_learn():
     assert l1 < l0
 
 
-# ------------------- fused path: 5-backend parity ----------------------
+# ------------------- fused path: executor × packing parity -------------
 
-def setup_mixed(seed=7, n_dst=41, n_src=37, d=16, k=4):
+def _mixed_coo(rng, n_dst, n_src):
     """Heavy-tailed degrees (evil row + sparse bulk) so the packing spans
     several buckets and the arenas carry real −1 padding."""
-    rng = np.random.default_rng(seed)
     deg = rng.integers(1, 30, n_dst)
     deg[0] = n_src - 1                    # evil row
     dst = np.repeat(np.arange(n_dst), deg)
     src = rng.integers(0, n_src, dst.size)
     pairs = np.unique(np.stack([dst, src], 1), axis=0)
-    dst, src = pairs[:, 0], pairs[:, 1]
+    return pairs[:, 0], pairs[:, 1]
+
+
+def setup_mixed(seed=7, n_dst=41, n_src=37, d=16, k=4):
+    rng = np.random.default_rng(seed)
+    dst, src = _mixed_coo(rng, n_dst, n_src)
     fwd, bwd, order, nnz = pack_eid_slabs(dst, src, n_dst, n_src)
     w = jnp.asarray(rng.normal(size=nnz).astype(np.float32))
     x = rng.normal(size=(n_src, d)).astype(np.float32)
@@ -110,23 +118,37 @@ def setup_mixed(seed=7, n_dst=41, n_src=37, d=16, k=4):
     a_rows, a_cols = dst[canon], src[canon]
 
     def dense_y(wv, xv):
-        a = jnp.zeros((n_dst, n_src)).at[a_rows, a_cols].add(wv)
-        xd = jnp.zeros((n_src, d)).at[
-            jnp.arange(n_src)[:, None], c.idx].add(xv)
-        return a @ xd
+        return ref.drspmm_learnable_ref(a_rows, a_cols, n_dst, n_src, wv,
+                                        xv, c.idx, d)
 
     return fwd, bwd, nnz, w, c, d, dense_y
 
 
+def _packing(kind, seed=7, n_dst=41, n_src=37):
+    """setup_mixed()'s graph with its (fwd, bwd) eid packing of ``kind``."""
+    fwd, bwd, nnz, w, c, d, dense_y = setup_mixed(seed, n_dst, n_src)
+    if kind != "slabs":
+        dst, src = _mixed_coo(np.random.default_rng(seed), n_dst, n_src)
+        fwd, bwd, _order, nnz_a = pack_fused_eid_pair(dst, src, n_dst,
+                                                      n_src)
+        assert nnz_a == nnz
+    if kind == "padded":
+        fwd, bwd = (pad_fused_arena(f, f.n_chunks + 3,
+                                    f.n_arena_rows + f.row_block)
+                    for f in (fwd, bwd))
+    return fwd, bwd, nnz, w, c, d, dense_y
+
+
+@pytest.mark.parametrize("packing", PACKINGS)
 @pytest.mark.parametrize("backend,gather_path", [
     pytest.param(b, p, id=b if p is None else f"{b}-{p}")
     for b in BACKENDS
     for p in (("vmem", "dma") if b.startswith("pallas") else (None,))],
     indirect=["gather_path"])
-def test_backend_parity_fwd_and_grads(backend, gather_path):
-    """Every backend matches the dense oracle: forward, dw, and dx; the
-    Pallas ones on both gather paths of the arena kernels."""
-    fwd, bwd, nnz, w, c, d, dense_y = setup_mixed()
+def test_backend_parity_fwd_and_grads(backend, gather_path, packing):
+    """Both executors match the dense oracle on every packing: forward, dw,
+    and dx; the Pallas one on both gather paths of the arena kernels."""
+    fwd, bwd, nnz, w, c, d, dense_y = _packing(packing)
 
     def loss(wv, xv):
         return jnp.sum(jnp.sin(ops.drspmm_learnable(
@@ -152,17 +174,28 @@ def test_backend_parity_fwd_and_grads(backend, gather_path):
 
 
 def test_prefused_arenas_upgrade_bucket_backends():
-    """Pre-fused eid arenas (the collated-batch form) run under every
-    backend name via the family-upgrade rules."""
+    """Inside a jit that takes the packing as an argument, pre-fused eid
+    arenas (the collated-batch form) run under both executors, forward and
+    dw; traced bucketed slabs are refused — fusing is host packing."""
     fwd, bwd, nnz, w, c, d, dense_y = setup_mixed(seed=11)
-    # rebuild the fused pair straight from the slabs
     ff, fb = fuse_bucketed(fwd, eids=True), fuse_bucketed(bwd, eids=True)
     y_ref = np.asarray(dense_y(w, c.values))
-    for be in ("xla", "pallas", "xla_fused", "pallas_fused", "dense"):
-        y = ops.drspmm_learnable(ff, fb, nnz, w, c.values, c.idx, d,
-                                 backend=be)
+    gw_ref = np.asarray(jax.grad(
+        lambda wv: jnp.sum(jnp.sin(dense_y(wv, c.values))))(w))
+    for be in BACKENDS:
+        def loss(wv, a, at):
+            return jnp.sum(jnp.sin(ops.drspmm_learnable(
+                a, at, nnz, wv, c.values, c.idx, d, backend=be)))
+
+        y = jax.jit(lambda a, at: ops.drspmm_learnable(
+            a, at, nnz, w, c.values, c.idx, d, backend=be))(ff, fb)
         np.testing.assert_allclose(np.asarray(y), y_ref, rtol=1e-4,
-                                   atol=1e-4, err_msg=f"prefused {be}")
+                                   atol=1e-4, err_msg=f"traced arenas {be}")
+        gw = jax.jit(jax.grad(loss))(w, ff, fb)
+        np.testing.assert_allclose(np.asarray(gw), gw_ref, rtol=1e-4,
+                                   atol=1e-4, err_msg=f"traced dw {be}")
+        with pytest.raises(TypeError, match="fuse_bucketed"):
+            jax.jit(lambda wv, a, at: loss(wv, a, at))(w, fwd, bwd)
 
 
 # ------------------- padded eid-slot inertness -------------------------
@@ -242,7 +275,7 @@ def test_no_retrace_on_second_call():
     jit caching (same class of bug tests/test_parallel_cache.py guards in
     core/parallel.py)."""
     fwd, bwd, nnz, w, c, d, dense_y = setup_mixed(seed=17)
-    for be in ("xla", "xla_fused"):
+    for be in BACKENDS:
         ops.drspmm_learnable(fwd, bwd, nnz, w, c.values, c.idx, d,
                              backend=be)                   # warm (trace 1)
         n0 = len(ops._LEARNABLE_TRACES)
@@ -257,10 +290,12 @@ def test_no_retrace_on_second_call():
 
 def test_executable_identity_is_cached():
     fwd, bwd, nnz, w, c, d, _ = setup_mixed(seed=19)
-    e1 = ops._learnable_executable(fwd, bwd, nnz, d, "xla")
-    e2 = ops._learnable_executable(fwd, bwd, nnz, d, "xla")
+    ff, fb = fuse_bucketed(fwd, eids=True), fuse_bucketed(bwd, eids=True)
+    e1 = ops._learnable_executable(ff, fb, nnz, d, "xla_fused")
+    e2 = ops._learnable_executable(ff, fb, nnz, d, "xla_fused")
     assert e1 is e2
-    assert ops._learnable_executable(fwd, bwd, nnz, d, "xla_fused") is not e1
+    assert ops._learnable_executable(ff, fb, nnz, d, "pallas_fused") \
+        is not e1
 
 
 # ------------------- collated (member-offset) eid arenas ---------------
@@ -312,7 +347,7 @@ def test_collated_eids_match_per_member():
         def member(w0):
             return ops.drspmm_learnable(fwd, bwd, m_nnz, w0,
                                         jnp.asarray(xv), jnp.asarray(xi),
-                                        d, backend="xla")
+                                        d, backend="xla_fused")
         y_m = member(jnp.asarray(wv))
         np.testing.assert_allclose(
             np.asarray(y_b[m.cell_off:m.cell_off + m.n_cell]),

@@ -2,8 +2,9 @@
 
 The plan path — one super-arena dispatch per direction-group covering every
 edge-type direction of a hetero layer — must be numerically interchangeable
-with the serial per-direction reference loop across all five backends,
-forward and gradient; its relation segments must round-trip exactly onto
+with the serial per-direction reference loop and the dense oracle under both
+executors, forward and gradient, whatever tiers the plan routes its
+relations to; its relation segments must round-trip exactly onto
 the member relations' matrices; collation padding and fillers must stay
 inert through the plan; and the cached custom-vjp executor must never
 retrace on repeat calls.
@@ -29,13 +30,19 @@ from repro.graphs.circuit import EDGE_SCHEMA, relation_plan_of, with_plan
 from repro.graphs.collate import BucketLayout, collate_graphs
 from repro.graphs.ell import build_relation_plan, pack_ell_pair
 from repro.graphs.generator import generate_partition, pack_graph_parallel
-from repro.kernels import ops
+from _dispatch import dispatch_count
+from repro.kernels import ops, ref
 from repro.models.hgnn import drcircuitgnn_forward, init_drcircuitgnn
 
 settings.register_profile("fast", max_examples=15, deadline=None)
 settings.load_profile("fast")
 
-BACKENDS = ("pallas_fused", "xla_fused", "pallas", "xla", "dense")
+BACKENDS = ("pallas_fused", "xla_fused")
+# plan crossovers over op_setup's relations (near ≈ 4·57 edges, pin/pinned
+# ≈ 2·57): every relation on the arena tier; near on the arena tier and
+# pin/pinned on the dense tier; the default crossover, which puts these
+# small relations all on the dense tier
+THRESHOLDS = {"arena": -1, "mixed": 150, "default": None}
 
 
 def _assert_close(actual, ref, msg):
@@ -77,7 +84,12 @@ def op_setup():
     rng = np.random.default_rng(3)
     n_cell, n_net, dim = 57, 29, 64
     rels = _mixed_relations(rng, n_cell, n_net)
-    plan = build_relation_plan(rels, {"cell": n_cell, "net": n_net})
+    plans = {tier: build_relation_plan(rels, {"cell": n_cell, "net": n_net},
+                                       dense_threshold=thr)
+             for tier, thr in THRESHOLDS.items()}
+    assert not plans["arena"].has_dense
+    assert plans["mixed"].has_arena and plans["mixed"].has_dense
+    assert not plans["default"].has_arena
     k_cell, k_net = 8, 6
     cc = cbsr_from_dense(drelu(jnp.asarray(
         rng.normal(size=(n_cell, dim)).astype(np.float32)), k_cell), k_cell)
@@ -88,7 +100,7 @@ def op_setup():
                                  {"cell": n_cell, "net": n_net}[r[1]])
              for r in rels}
     src_of = {r[0]: r[1] for r in rels}
-    return plan, rels, packs, src_of, cc, cn, dim
+    return plans, rels, packs, src_of, cc, cn, dim
 
 
 def _serial_ref(packs, src_of, cc, cn, dim, vc, vn):
@@ -96,23 +108,25 @@ def _serial_ref(packs, src_of, cc, cn, dim, vc, vn):
     for et, (adj, adj_t) in packs.items():
         c = cc if src_of[et] == "cell" else cn
         v = vc if src_of[et] == "cell" else vn
-        out[et] = ops.drspmm(adj, adj_t, v, c.idx, dim, backend="dense")
+        out[et] = ref.drspmm_fwd_ref(adj, v, c.idx, dim)
     return out
 
 
+@pytest.mark.parametrize("tiers", list(THRESHOLDS))
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_multi_matches_serial_per_relation(op_setup, backend):
-    """drspmm_multi == one serial drspmm per relation, fwd + grads in both
-    source types, under every backend name (per-bucket names upgrade to the
-    fused family — plans are always pre-fused)."""
-    plan, rels, packs, src_of, cc, cn, dim = op_setup
+def test_multi_matches_serial_per_relation(op_setup, backend, tiers):
+    """drspmm_multi == the dense oracle of each relation, fwd + grads in
+    both source types, under both executors and whatever tiers the plan
+    routes the relations to."""
+    plans, rels, packs, src_of, cc, cn, dim = op_setup
+    plan = plans[tiers]
     refs = _serial_ref(packs, src_of, cc, cn, dim, cc.values, cn.values)
     ys = ops.drspmm_multi(plan, {"cell": (cc.values, cc.idx),
                                  "net": (cn.values, cn.idx)}, dim,
                           backend=backend)
     for et in packs:
         _assert_close(np.asarray(ys[et]), np.asarray(refs[et]),
-                      f"fwd {backend}/{et}")
+                      f"fwd {backend}/{tiers}/{et}")
 
     def loss_multi(vc, vn):
         ys = ops.drspmm_multi(plan, {"cell": (vc, cc.idx),
@@ -127,14 +141,16 @@ def test_multi_matches_serial_per_relation(op_setup, backend):
     g = jax.grad(loss_multi, argnums=(0, 1))(cc.values, cn.values)
     g_ref = jax.grad(loss_serial, argnums=(0, 1))(cc.values, cn.values)
     for a, r, nm in zip(g, g_ref, ("cell", "net")):
-        _assert_close(np.asarray(a), np.asarray(r), f"grad {backend}/{nm}")
+        _assert_close(np.asarray(a), np.asarray(r),
+                      f"grad {backend}/{tiers}/{nm}")
 
 
 def test_no_retrace_on_second_multi_call(op_setup):
     """The plan executor is built (and traced) once per (plan, dim,
     backend) — mirrors test_no_retrace_on_second_call for the learnable
     op."""
-    plan, rels, packs, src_of, cc, cn, dim = op_setup
+    plans, rels, packs, src_of, cc, cn, dim = op_setup
+    plan = plans["default"]
     cbsr = {"cell": (cc.values, cc.idx), "net": (cn.values, cn.idx)}
     for be in ("xla_fused", "pallas_fused"):
         ops.drspmm_multi(plan, cbsr, dim, backend=be)   # warm (trace 1)
@@ -202,8 +218,6 @@ def test_one_dispatch_per_direction_group():
     cfg_p = HeteroMPConfig(hidden=32, k_cell=8, k_net=8,
                            backend="pallas_fused", use_plan=True)
     cfg_s = dataclasses.replace(cfg_p, use_plan=False)
-
-    from benchmarks.bench_drspmm import dispatch_count
 
     def fwd(cfg):
         return lambda xc: hetero_conv(lp, g, xc, x_net, cfg)[0]
@@ -452,10 +466,10 @@ def test_collated_plan_padding_is_inert(members, model_params, backend):
     cfg = HeteroMPConfig(hidden=32, k_cell=8, k_net=8, backend=backend,
                          use_plan=True)
     cfg_ref = dataclasses.replace(cfg, use_plan=False)
-    exact = collate_graphs(members, fused=False, quantize=False)
-    quant = collate_graphs(members, fused=True, quantize=True)
+    exact = collate_graphs(members, quantize=False, with_plan=False)
+    quant = collate_graphs(members, quantize=True)
     assert quant.graph.plan is not None
-    assert exact.graph.plan is None      # unfused collation stays plan-free
+    assert exact.graph.plan is None
 
     fwd = jax.jit(lambda p, g: drcircuitgnn_forward(p, g, cfg))
     p_ref = exact.split_cell(

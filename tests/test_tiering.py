@@ -4,10 +4,11 @@ Sub-crossover relations leave the super-arena chunk walk for a batched
 masked dense matmul; these tests pin the routing rule and the hybrid
 executor around the crossover itself:
 
-* 5-backend fwd+grad parity of the hybrid ``drspmm_multi`` against the
-  serial per-relation reference on plans that STRADDLE the threshold
-  (mixed arena + dense tiers in one direction-group), including the
-  default-constant crossover on a relation genuinely above it;
+* fwd+grad parity of the hybrid ``drspmm_multi`` (both executors) against
+  the dense per-relation oracle on plans that STRADDLE the threshold
+  (mixed arena + dense tiers in one direction-group) and on single-tier
+  plans of the same relations, including the default-constant crossover
+  on a relation genuinely above it;
 * tier routing is a function of (nnz, table area) alone — invariant under
   degree-preserving edge/node permutations (hypothesis property);
 * exact threshold boundary: nnz == cutoff lands dense, cutoff + 1 lands
@@ -38,13 +39,13 @@ from repro.graphs.collate import collate_graphs
 from repro.graphs.ell import DENSE_TIER_NNZ, build_relation_plan, \
     pack_ell_pair
 from repro.graphs.generator import generate_partition, pack_graph_parallel
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.models.hgnn import drcircuitgnn_forward, init_drcircuitgnn
 
 settings.register_profile("fast", max_examples=15, deadline=None)
 settings.load_profile("fast")
 
-BACKENDS = ("pallas_fused", "xla_fused", "pallas", "xla", "dense")
+BACKENDS = ("pallas_fused", "xla_fused")
 
 
 def _assert_close(actual, ref, msg):
@@ -93,7 +94,7 @@ def _serial_refs(rels, sizes, cc, cn, dim, vc, vn):
         adj, adj_t = pack_ell_pair(dst, src, w, sizes[d_t], sizes[s_t])
         c = cc if s_t == "cell" else cn
         v = vc if s_t == "cell" else vn
-        out[et] = ops.drspmm(adj, adj_t, v, c.idx, dim, backend="dense")
+        out[et] = ref.drspmm_fwd_ref(adj, v, c.idx, dim)
     return out
 
 
@@ -125,28 +126,38 @@ def _check_parity(plan, rels, sizes, cc, cn, dim, backend, tag):
 
 # ------------------- hybrid parity across the crossover -----------------
 
+# crossovers over the straddle relations (near ≈ 4·57 edges, pin/pinned
+# ≈ 2·57): every relation on the arena tier, near on the arena tier and
+# pin/pinned on the dense tier, every relation on the dense tier
+THRESHOLDS = {"arena": -1, "mixed": 150, "dense": 10_000}
+
+
 @pytest.fixture(scope="module")
 def straddle_setup():
-    """A plan whose relations straddle an overridden crossover: `near`
-    lands on the arena tier, `pin`/`pinned` on the dense tier."""
+    """Relations that straddle an overridden crossover, and their plan at
+    each of ``THRESHOLDS``."""
     rng = np.random.default_rng(3)
     n_cell, n_net, dim = 57, 29, 64
     rels = _mixed_relations(rng, n_cell, n_net)
     sizes = {"cell": n_cell, "net": n_net}
-    plan = build_relation_plan(rels, sizes, dense_threshold=150)
-    assert plan.segment("near").tier == "arena"
-    assert plan.segment("pin").tier == "dense"
-    assert plan.has_arena and plan.has_dense
+    plans = {tier: build_relation_plan(rels, sizes, dense_threshold=thr)
+             for tier, thr in THRESHOLDS.items()}
+    assert plans["mixed"].segment("near").tier == "arena"
+    assert plans["mixed"].segment("pin").tier == "dense"
+    assert plans["mixed"].has_arena and plans["mixed"].has_dense
+    assert not plans["arena"].has_dense and not plans["dense"].has_arena
     cc, cn = _cbsr_pair(rng, n_cell, n_net, dim)
-    return plan, rels, sizes, cc, cn, dim
+    return plans, rels, sizes, cc, cn, dim
 
 
+@pytest.mark.parametrize("tiers", list(THRESHOLDS))
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_hybrid_multi_matches_serial(straddle_setup, backend):
-    """Mixed-tier drspmm_multi == one serial drspmm per relation, fwd +
-    grads in both source types, under every backend name."""
-    plan, rels, sizes, cc, cn, dim = straddle_setup
-    _check_parity(plan, rels, sizes, cc, cn, dim, backend, "straddle")
+def test_hybrid_multi_matches_serial(straddle_setup, backend, tiers):
+    """drspmm_multi == the dense oracle of each relation, fwd + grads in
+    both source types, under both executors, on the mixed-tier plan and
+    on the single-tier plans of the same relations."""
+    plans, rels, sizes, cc, cn, dim = straddle_setup
+    _check_parity(plans[tiers], rels, sizes, cc, cn, dim, backend, tiers)
 
 
 def test_default_crossover_straddle_parity():
